@@ -16,8 +16,10 @@ the same shape, scale noted in the output:
 Every number is a real `Engine.query_bytes` (parse -> execute -> JSON
 response bytes, i.e. the full serving path through the native emitter)
 wall time, post-warmup, best-of-N. Run: python bench_baseline.py
-[--platform cpu|tpu]. Prints one JSON line per config plus a markdown
-table ready for BASELINE.md.
+[--platform cpu|tpu] (`cpu` = the engine's host path with jax held to
+the CPU; otherwise the device path on the environment's device). Every
+row names the device jax actually reports. Prints one JSON line per
+config plus a markdown table ready for BASELINE.md.
 """
 
 from __future__ import annotations
@@ -230,15 +232,23 @@ def config4(threshold, n=1 << 18, avg=24.0):
 
 
 def main():
-    platform = "cpu"
+    # `--platform cpu` asks for the engine's HOST path (and holds jax to
+    # the CPU); anything else asks for the device path on whatever device
+    # the environment gives. Rows are labelled with the device jax
+    # actually reports, never with what was asked for.
+    asked = "cpu"
     if "--platform" in sys.argv:
-        platform = sys.argv[sys.argv.index("--platform") + 1]
-    if platform == "cpu":
-        import jax
+        asked = sys.argv[sys.argv.index("--platform") + 1]
+    import jax
+    if asked == "cpu":
         jax.config.update("jax_platforms", "cpu")
         threshold = 1 << 62          # engine host path
     else:
         threshold = 512              # large frontiers on device
+    devs = jax.devices()
+    label = {"platform": devs[0].platform,
+             "device_kind": devs[0].device_kind, "n_devices": len(devs),
+             "engine_path": "host" if asked == "cpu" else "device"}
 
     rows = []
     rows += config1_2(threshold)
@@ -246,16 +256,18 @@ def main():
     rows += config3_5(threshold)
     rows.sort(key=lambda r: str(r["config"]))
     for r in rows:
-        r["platform"] = platform
+        r.update(label)
         print(json.dumps(r), flush=True)
-    print("\n| # | Config | p50 | edges/sec | Platform |")
+    where = (f"{label['platform']} ({label['device_kind']} x"
+             f"{label['n_devices']}), {label['engine_path']} path")
+    print("\n| # | Config | p50 | edges/sec | Device |")
     print("|---|---|---|---|---|")
     for r in rows:
         eps = f"{r['edges_per_sec']:,}" if r.get("edges_per_sec") else "—"
         lat = (f"{r['p50_ms']} ms" if "p50_ms" in r
                else f"{r['batch_wall_ms']} ms wall")
         print(f"| {r['config']} | {r['desc']} | {lat} | "
-              f"{eps} | {platform} |")
+              f"{eps} | {where} |")
 
 
 if __name__ == "__main__":

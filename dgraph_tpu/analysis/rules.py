@@ -24,9 +24,10 @@ R6 jit-purity            no `.item()`/`.tolist()`/numpy host ops or
 R7 shard-map-compat      `shard_map` resolves ONLY through
                          utils/jaxcompat.py — direct `jax.shard_map` /
                          `jax.experimental.shard_map` references
-                         elsewhere re-pin the mesh layer to one jax
-                         version (the exact regression that parked the
-                         whole parallel/ layer in the failure set).
+                         elsewhere scatter the one API the mesh layer
+                         rides (a jax move once parked the whole
+                         parallel/ layer in the failure set); with the
+                         choke point, the next move is a one-file change.
 R8 atomic-write          durable files under store/ (and
                          server/backup.py) land via tmp + fsync +
                          os.replace — a bare `open(..., "w"/"wb")`
@@ -441,10 +442,10 @@ class ShardMapCompat(Rule):
     name = "shard-map-compat"
     doc = ("`shard_map` has moved across jax releases "
            "(jax.experimental.shard_map.shard_map with check_rep → "
-           "jax.shard_map with check_vma); utils/jaxcompat.py resolves "
-           "it ONCE per process and is the only file allowed to touch "
-           "either spelling — everywhere else imports the shim, so a "
-           "jax upgrade can't silently re-park the mesh layer")
+           "jax.shard_map with check_vma); utils/jaxcompat.py is the "
+           "only file allowed to touch either spelling — everywhere "
+           "else imports it from there, so the next jax move is a "
+           "one-file change")
 
     SHIM = "dgraph_tpu/utils/jaxcompat.py"
 

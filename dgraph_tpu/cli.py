@@ -116,6 +116,12 @@ def cmd_alpha(args) -> int:
         log.info("encryption-at-rest enabled (strict=%s)",
                  cfg.encryption_strict)
 
+    # the persistent compile cache (utils/jaxcompat.py): where
+    # JAX_COMPILATION_CACHE_DIR says, else <checkout>/.jax_cache — a
+    # restarted server finds what the last one compiled
+    from dgraph_tpu.utils.jaxcompat import enable_compile_cache
+    cache_dir = enable_compile_cache()
+
     mesh = None
     if cfg.mesh_devices:
         # SPMD serving: the query engine runs its hops sharded over the
@@ -131,6 +137,15 @@ def cmd_alpha(args) -> int:
         mesh = make_mesh(None if cfg.mesh_devices < 0
                          else cfg.mesh_devices)
         log.info("device mesh: %d devices", mesh.devices.size)
+
+    # the device this server answers from, as jax reports it — read at
+    # boot so a chip that was not found fails HERE, and never shows up
+    # later as a quietly slower server (same labels as build_info)
+    from dgraph_tpu.server.fleet import build_labels
+    dev = build_labels()
+    log.info("device: platform=%s device_kind=%s devices=%s jax=%s "
+             "compile_cache=%s", dev["backend"], dev["device_kind"],
+             dev["devices"], dev["jax"], cache_dir)
 
     # checkpoint + WAL replay boot: every commit that reached disk before
     # a crash is recovered (reference: badger open + raft WAL restore)
@@ -325,6 +340,14 @@ def cmd_alpha(args) -> int:
     serve_background(http_server)
     log.info("alpha up: grpc=%d http=%d", grpc_port,
              http_server.server_address[1])
+    # SIGTERM is how a supervisor (and chip_smoke.py) stops a server;
+    # it takes the same clean-exit path as ^C — drain, final checkpoint
+    import signal
+
+    def _on_sigterm(_signum, _frame):
+        raise KeyboardInterrupt
+
+    signal.signal(signal.SIGTERM, _on_sigterm)
     try:
         grpc_server.wait_for_termination()
     except KeyboardInterrupt:

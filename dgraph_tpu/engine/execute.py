@@ -69,6 +69,16 @@ def _bucket(n: int, lo: int = 64) -> int:
     return b
 
 
+def pad_host(a: np.ndarray, size: int) -> np.ndarray:
+    """Host twin of `ops.pad_to` for MESH launches: a host array is a
+    first-hop upload; `ops.pad_to`'s result is committed to one device
+    and would be re-laid across the mesh before the launch — the silent
+    copy `mesh_hop_resharded_total` counts."""
+    out = np.full(size, ops.SENTINEL32, np.int32)
+    out[:len(a)] = a
+    return out
+
+
 def csr_rows(rel, frontier: np.ndarray):
     """Host CSR row gather for a frontier → (neighbors, seg, edge_pos).
     The one shared implementation of the per-uid posting walk (reference:
@@ -279,7 +289,7 @@ class Executor:
         if len(frontier) > self.ring_threshold:
             return self._expand_mesh_ring(pred, reverse, frontier)
         srel = self.store.sharded_rel(pred, reverse, self.mesh)
-        fr = ops.pad_to(frontier, _bucket(len(frontier)))
+        fr = pad_host(frontier, _bucket(len(frontier)))
         deg = self.store.rel(pred, reverse).degree(frontier)
         edge_cap = self._shard_edge_cap(srel, frontier, deg)
         from dgraph_tpu.parallel.mesh import host_np
@@ -819,11 +829,15 @@ class Executor:
             allowed = self.filter_set(sg.filters)
             if allowed is None:
                 return None
-            allowed_d = ops.pad_to(allowed, _bucket(max(len(allowed), 1)))
         else:
-            allowed_d = ops.pad_to(EMPTY, 1)
+            allowed = EMPTY
+        # host pads for a mesh launch (see pad_host), device pads for
+        # the single-device program
+        pad = pad_host if self.mesh is not None else ops.pad_to
+        allowed_d = pad(allowed, _bucket(max(len(allowed), 1))
+                        if use_allowed else 1)
         first = sg.first if sg.first else NO_LIMIT
-        fr = ops.pad_to(frontier, _bucket(len(frontier)))
+        fr = pad(frontier, _bucket(len(frontier)))
         deg = rel.degree(frontier)
         if self.mesh is not None:
             return self._fused_level_mesh(sg, frontier, fr, deg, allowed_d,

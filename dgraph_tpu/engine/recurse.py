@@ -172,19 +172,11 @@ def _chain_recurse(ex, root, data: RecurseData, depth: int) -> None:
     frontier's values, for rendering) and feeds the same device arrays
     back in. Semantics are identical to _fused_recurse (visit-once,
     first-visit-tree), pinned by tests against it and the host loop."""
-    from dgraph_tpu.engine.execute import _bucket
+    from dgraph_tpu.engine.execute import _bucket, pad_host
     from dgraph_tpu.ops.uidalgebra import SENTINEL32
     from dgraph_tpu.parallel.dhop import chain_hop
     from dgraph_tpu.parallel.mesh import host_np, reshard_guard
     from dgraph_tpu.utils import costprofile, tracing
-
-    def pad_host(a: np.ndarray, size: int) -> np.ndarray:
-        # host-side sentinel pad: the chain's SEED is an expected
-        # upload; a device-side pad would read as a reshard to the
-        # guard (ops.pad_to lands on the default device)
-        out = np.full(size, SENTINEL32, np.int32)
-        out[:len(a)] = a
-        return out
 
     from dgraph_tpu.utils.metrics import METRICS
     METRICS.inc("mesh_route_total", route="chain")

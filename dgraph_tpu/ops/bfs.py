@@ -334,13 +334,15 @@ def prepare_parts(dev: DeviceEll, W: int):
     path uses the blocks as-is (the gather-OR chain never materialises a
     [rows, K, W] intermediate); under DGRAPH_TPU_PALLAS=1 dense blocks
     and the tile matrix are row-padded for the Pallas DMA-ring hop
-    (ops/pallas_hop.py) instead."""
+    (ops/pallas_hop.py) instead — at the widths the compiled kernel
+    moves (whole 128-word rows); narrower masks keep the XLA hop."""
     import os
     use_pallas = os.environ.get("DGRAPH_TPU_PALLAS", "") == "1"
     if use_pallas:
         # import only under the flag: the default XLA path must not
         # couple to the experimental pallas namespace
-        from dgraph_tpu.ops.pallas_hop import BLOCK_ROWS
+        from dgraph_tpu.ops.pallas_hop import BLOCK_ROWS, LANE_WORDS
+        use_pallas = W % LANE_WORDS == 0
 
     def pad_rows(e):
         n_b = e.shape[0]
@@ -370,8 +372,10 @@ def prepare_parts(dev: DeviceEll, W: int):
 
 # Sticky fail-safe: the first bucket_hop_pallas that fails to trace or
 # compile flips this and every pallas bucket (this one included) falls
-# back to the XLA gather hop — an untested Mosaic compile must degrade
-# a perf experiment, never burn the serving path (or a chip window).
+# back to the XLA gather hop — a failed Mosaic compile must degrade a
+# perf experiment, never take the serving path down. Counted
+# (`pallas_fallback_total`, `pallas_degraded`): chip_smoke.py requires
+# both to stay zero.
 _pallas_failed = False
 
 
@@ -463,7 +467,15 @@ def _count_mask(mask, outdeg_pad, n, W, word_bits):
     """Per-lane out-degree mass of a packed mask: unpack lane bits and
     matvec on the MXU (f32 exact while each lane's TOTAL stays under
     2^24 — the per-run analog of the old per-hop bound; int32 out).
-    Blocked over node rows so the unpack never materialises n·B floats."""
+    Blocked over node rows so the unpack never materialises n·B floats.
+
+    Exactness on the chip: a TPU may run a float32 `@` in bfloat16
+    passes, and an out-degree above 256 is not a bfloat16 integer. On
+    the installed jax/libtpu this vector-matrix product measured EXACT
+    at default precision (v5e, out-degrees to 858, 64 to 4096 lanes —
+    PR 21), so no precision is forced here; chip_smoke.py's kernels
+    phase holds these counters to numpy's integers on every run, and
+    `precision=lax.Precision.HIGHEST` is the repair if it ever fails."""
     n_pad = outdeg_pad.shape[0]
     nblk = n_pad // COUNT_BLK
     fpad = jnp.concatenate(
@@ -662,7 +674,7 @@ def ell_recurse(g: EllGraph, mask0, depth: int, count_edges: bool = True):
     word_bits = 64 if np.asarray(mask0).dtype == np.uint64 else 32
     if word_bits == 64:
         assert jax.config.jax_enable_x64, \
-            "uint64 lane words need x64 (jax.experimental.enable_x64)"
+            "uint64 lane words need x64 (with jax.enable_x64(True): ...)"
     dev = device_ell(g)
     fn = make_ell_recurse(dev, g.outdeg, g.n, mask0.shape[1],
                           count_edges, word_bits)

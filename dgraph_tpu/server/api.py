@@ -819,7 +819,13 @@ class Alpha:
                     except (dl.DeadlineExceeded, dl.Cancelled):
                         raise  # the whole request's budget died
                     except Exception:  # noqa: BLE001 — optimization only
-                        xlog.get("alpha").debug(
+                        # counted and loud: a lane kernel that fails to
+                        # compile on the device must not hide behind a
+                        # correct per-query answer (chip_smoke.py holds
+                        # this counter to zero)
+                        METRICS.inc("batch_group_fallback_total",
+                                    stage="group")
+                        xlog.get("alpha").warning(
                             "batch group failed; per-query fallback",
                             exc_info=True)
                         out = None
@@ -832,8 +838,9 @@ class Alpha:
             except (dl.DeadlineExceeded, dl.Cancelled):
                 raise
             except Exception:  # noqa: BLE001 — batch is an optimization
-                xlog.get("alpha").debug("batch plan failed; per-query "
-                                        "fallback", exc_info=True)
+                METRICS.inc("batch_group_fallback_total", stage="plan")
+                xlog.get("alpha").warning("batch plan failed; per-query "
+                                          "fallback", exc_info=True)
                 leftover = list(range(len(dqls)))
             # per-query fallback with per-query error isolation: one bad
             # query yields an error OBJECT in its slot, never a failed

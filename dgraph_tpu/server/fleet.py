@@ -22,9 +22,9 @@ utils/tracing.attach + server/task.py):
   `instance` label per series. A dark or breaker-open peer degrades to
   an entry in `errors` — the snapshot is partial, never a 500.
 
-* identity metrics — `build_info{version=,jax=,backend=}` and
-  `process_uptime_s` (monotonic clock per R3), refreshed on every
-  exposition render so scrapes and bundles always carry them.
+* identity metrics — `build_info{version=,jax=,backend=,device_kind=,
+  devices=}` and `process_uptime_s` (monotonic clock per R3), refreshed
+  on every exposition render so scrapes and bundles always carry them.
 """
 
 from __future__ import annotations
@@ -42,20 +42,18 @@ _BUILD: dict | None = None
 
 def build_labels() -> dict:
     """The build_info identity labels, resolved once: package version,
-    jax version, and the jax backend platform. Resolution failures
-    (no jax, device init refused) degrade to "none" — identity metrics
-    must never take a process down."""
+    jax version, and the device jax serves from, as jax reports it
+    (`jax.devices()[0].platform` / `.device_kind`, `len(jax.devices())`).
+    A device that cannot be reached is NOT degraded to a placeholder:
+    jax's own error propagates, the way the first query's would."""
     global _BUILD
     if _BUILD is None:
-        jax_version = backend = "none"
-        try:
-            import jax
-            jax_version = jax.__version__
-            backend = jax.default_backend()
-        except Exception:  # noqa: BLE001 — identity is best-effort
-            pass
-        _BUILD = {"version": __version__, "jax": jax_version,
-                  "backend": backend}
+        import jax
+        devs = jax.devices()
+        _BUILD = {"version": __version__, "jax": jax.__version__,
+                  "backend": devs[0].platform,
+                  "device_kind": devs[0].device_kind,
+                  "devices": str(len(devs))}
     return _BUILD
 
 
@@ -65,9 +63,26 @@ def refresh_identity_metrics() -> None:
     `process_uptime_s` is live, not a boot-time constant."""
     b = build_labels()
     METRICS.set_gauge("build_info", 1.0, version=b["version"],
-                      jax=b["jax"], backend=b["backend"])
+                      jax=b["jax"], backend=b["backend"],
+                      device_kind=b["device_kind"], devices=b["devices"])
     METRICS.set_gauge("process_uptime_s",
                       round(dl.monotonic_s() - _START_MONO, 3))
+
+
+def device_memory() -> list:
+    """Per-device allocator stats for /debug/memory, as the backend
+    reports them (`Device.memory_stats()`; a backend that reports none
+    — the CPU — leaves the byte fields null)."""
+    import jax
+    out = []
+    for d in jax.local_devices():
+        st = d.memory_stats() or {}
+        out.append({"id": d.id, "platform": d.platform,
+                    "device_kind": d.device_kind,
+                    "bytes_in_use": st.get("bytes_in_use"),
+                    "peak_bytes_in_use": st.get("peak_bytes_in_use"),
+                    "bytes_limit": st.get("bytes_limit")})
+    return out
 
 
 def node_snapshot(alpha) -> dict:

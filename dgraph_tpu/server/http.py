@@ -412,8 +412,13 @@ def make_http_server(alpha: Alpha, addr: str = "127.0.0.1",
             # evictions, OOM evict-retry counters, sticky-degraded
             # shapes — the surface the acceptance test reads after an
             # injected allocation fault
+            from dgraph_tpu.server import fleet
             from dgraph_tpu.utils import memgov
-            self._send(200, memgov.GOVERNOR.status())
+            # `devices`: what each device itself reports holding —
+            # peak HBM, and whether a mesh's bytes really sit on every
+            # chip rather than all on the first
+            self._send(200, {**memgov.GOVERNOR.status(),
+                             "devices": fleet.device_memory()})
 
         def _dbg_timeseries(self):
             # retained metrics history (utils/timeseries.py): the

@@ -1,9 +1,10 @@
 """Flight recorder: always-on black box + predicted-cost watchdog.
 
 The telemetry stack (tracing, cost profiles, metrics, the debug HTTP
-surface) answers any question an operator thinks to ASK — but the
-chip-window scenario is the opposite: a silent stall with nobody
-watching to hit `POST /debug/profile` at the right moment. This module
+surface) answers any question an operator thinks to ASK — but a run
+that cannot be watched (a sealed chip machine, a night-time stall) is
+the opposite: a silent stall with nobody there to hit
+`POST /debug/profile` at the right moment. This module
 is the unattended half:
 
 * **Flight ring** — a bounded, lock-disciplined event ring that

@@ -1,58 +1,51 @@
-"""Version-compat resolvers for the jax APIs the mesh layer rides.
+"""The jax surface this repo rides, for the ONE installation it supports
+(jax 0.9.x: `jax.shard_map(check_vma=)`, `jax.enable_x64`,
+`jax.errors.JaxRuntimeError`).
 
-`shard_map` is the one API the whole `parallel/` package is built on,
-and it has moved twice across jax releases: it started life as
-`jax.experimental.shard_map.shard_map` (with a `check_rep` kwarg),
-then graduated to `jax.shard_map` (renaming the kwarg to `check_vma`).
-The jax build this repo pins (0.4.x) only ships the experimental
-spelling, while the code is written against the graduated one — so
-every import of this module resolves ONE callable, whichever spelling
-the running jax provides, and translates the kwarg.
+Two choke points live here:
 
-This file is the ONLY place allowed to touch either spelling directly:
-graftlint rule R7 (`shard-map-compat`, analysis/rules.py) makes a
-direct `jax.shard_map` / `jax.experimental.shard_map` reference
-anywhere else a finding, so the mesh layer cannot silently regress the
-next time jax moves the API.
+* `shard_map` — the API the whole `parallel/` package is built on.
+  This file is the ONLY place allowed to touch `jax.shard_map`
+  directly: graftlint rule R7 (`shard-map-compat`, analysis/rules.py)
+  makes a direct reference anywhere else a finding, so the next jax
+  move is a one-file change.
+
+* `enable_compile_cache` — the ONLY place that sets
+  `jax_compilation_cache_dir`. Every process that compiles (the alpha
+  server, the bench children, chip_smoke.py's JAX children) calls it
+  before first use, so a restarted server and a second benchmark run
+  find what the first one compiled.
 """
 
 from __future__ import annotations
 
-import inspect
+import os
 
 import jax
 
-__all__ = ["shard_map", "SHARD_MAP_ORIGIN"]
+__all__ = ["shard_map", "enable_compile_cache"]
 
-
-def _resolve():
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        origin = "jax.shard_map"
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-        origin = "jax.experimental.shard_map.shard_map"
-    try:
-        params = frozenset(inspect.signature(impl).parameters)
-    except (TypeError, ValueError):  # C-accelerated / wrapped callables
-        params = frozenset()
-    return impl, origin, params
-
-
-_IMPL, SHARD_MAP_ORIGIN, _PARAMS = _resolve()
+# <checkout>/.jax_cache (git-ignored). A fixed path on purpose: the
+# directory is part of the cache key's lookup, so a temp name, pid or
+# timestamp would never hit.
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` resolved across jax versions.
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
-    Callers use the graduated signature (`check_vma`); on builds that
-    only have the experimental API the flag is forwarded as its old
-    name `check_rep` (same meaning: per-output replication checking).
-    """
-    if "check_vma" in _PARAMS:
-        return _IMPL(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=check_vma)
-    if "check_rep" in _PARAMS:
-        return _IMPL(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
-    return _IMPL(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its
+    directory. With `JAX_COMPILATION_CACHE_DIR` in the environment
+    nothing is set in code — jax reads the variable itself and the
+    environment places the cache; otherwise it lives at
+    `<checkout>/.jax_cache`."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
+    return _DEFAULT_CACHE_DIR

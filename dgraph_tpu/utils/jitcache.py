@@ -1,13 +1,13 @@
 """Host-side jit compile-cache accounting.
 
 XLA retraces/recompiles whenever a kernel launch's STATIC configuration
-(bucketed shapes, caps, depth) changes; the r5 bench stall showed that a
-wedged chip and a multi-second compile are indistinguishable without
-telemetry. Each kernel call site wraps its launch in `jit_call(kernel,
-key)` where `key` is exactly the static tuple that forces a distinct
-program — first sight of a key counts as a compile (timed: the first
-invocation traces + compiles synchronously before dispatch), repeats
-count as cache hits.
+(bucketed shapes, caps, depth) changes, and a device that hangs and a
+multi-second compile are indistinguishable without telemetry. Each
+kernel call site wraps its launch in `jit_call(kernel, key)` where
+`key` is exactly the static tuple that forces a distinct program —
+first sight of a key counts as a compile (timed: the first invocation
+traces + compiles synchronously before dispatch), repeats count as
+cache hits.
 
 The timing is an upper bound on compile cost (it includes the first
 dispatch), which is the honest observable without reaching into XLA

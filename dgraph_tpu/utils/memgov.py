@@ -14,7 +14,7 @@ under the low watermark.
 
 On top of the budgets sits OOM-safe execution. Launch sites wrap their
 device dispatch in `oom_retry(site, shape, fn)`: an XLA allocation
-failure (`RESOURCE_EXHAUSTED` / `XlaRuntimeError` out-of-memory, or an
+failure (`RESOURCE_EXHAUSTED` / `JaxRuntimeError` out-of-memory, or an
 injected `AllocFault`) triggers a synchronous evict-to-low-watermark and
 ONE retry; a second failure sticky-degrades that (site, shape) to the
 caller's host/staged route — bit-identical results, the process never
@@ -101,11 +101,12 @@ class OomDegraded(RuntimeError):
 def is_alloc_failure(exc: BaseException) -> bool:
     """Classify an exception as a device allocation failure: the
     injected `AllocFault`, python `MemoryError`, or an XLA runtime
-    error whose text carries the canonical out-of-memory markers.
-    Matched on type name + message so jax never has to be imported."""
+    error (`jax.errors.JaxRuntimeError` on the installed jax) whose text
+    carries the canonical out-of-memory markers. Matched on type name +
+    message so jax never has to be imported."""
     if isinstance(exc, (AllocFault, MemoryError)):
         return True
-    if type(exc).__name__ != "XlaRuntimeError":
+    if type(exc).__name__ != "JaxRuntimeError":
         return False
     text = str(exc).lower()
     return ("resource_exhausted" in text or "resource exhausted" in text

@@ -3,7 +3,7 @@
 ROADMAP carried "live span push to a collector (export is
 shutdown/pull-shaped today)" since PR 4 — `--trace_export` writes
 OTLP/JSON at shutdown and `/debug/traces` serves pulls, but nothing
-STREAMS, so the chip window's telemetry is only attributable
+STREAMS, so a serving run's telemetry is only attributable
 post-mortem. This closes it: a `TelemetryPusher` subscribes to the
 span registry (tracing.add_sink) and the cost-record stream
 (costprofile.add_sink), buffers bounded, and a background thread POSTs

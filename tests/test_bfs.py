@@ -230,7 +230,6 @@ class TestSegmentCsr:
         """uint64 lane words (the x64 bench path) produce bit-identical
         traversals to the uint32 default."""
         import jax
-        from jax.experimental import enable_x64
 
         from dgraph_tpu.ops.bfs import (build_ell, device_ell,
                                         make_ell_count, make_ell_recurse,
@@ -242,7 +241,7 @@ class TestSegmentCsr:
         g = build_ell(rel.indptr, rel.indices)
         m32 = pack_seed_masks(g, seeds, word_bits=32)
         _l, seen32, edges32 = ell_recurse_local(g, m32, 3)
-        with enable_x64():
+        with jax.enable_x64(True):
             m64 = pack_seed_masks(g, seeds, word_bits=64)
             dev = device_ell(g)
             fn = make_ell_recurse(dev, g.outdeg, g.n, m64.shape[1],
