@@ -193,12 +193,11 @@ def cmd_alpha(args) -> int:
     if cfg.slow_query_ms:
         log.info("slow-query log armed at %d ms", cfg.slow_query_ms)
     if cfg.trace_dir:
-        # device-timeline capture: spans marked device=True also write
-        # jax.profiler traces (Perfetto) under this dir; POST
-        # /debug/profile starts/stops on-demand captures under it too
+        # the default directory of on-demand device-timeline captures
+        # (POST /debug/profile start/stop, tracing.profile_start)
         from dgraph_tpu.utils import tracing
         tracing.enable_device_trace(cfg.trace_dir)
-        log.info("device trace capture armed: %s", cfg.trace_dir)
+        log.info("device trace capture dir: %s", cfg.trace_dir)
     pusher = None
     if cfg.telemetry_push_url:
         # live span + cost-record streaming to an external collector
@@ -755,8 +754,8 @@ def main(argv=None) -> int:
                         "their trace id (0 = off); spans stay "
                         "retrievable at /debug/traces?trace_id=")
     p.add_argument("--trace_dir", default=None,
-                   help="arm jax.profiler device-trace capture "
-                        "(Perfetto) for device-fenced spans")
+                   help="default directory of POST /debug/profile's "
+                        "jax.profiler captures (Perfetto-loadable)")
     p.add_argument("--trace_export", default=None,
                    help="on shutdown, write the span registry as "
                         "OTLP/JSON to this path (collector-ready)")

@@ -101,7 +101,8 @@ class Engine:
         ex = Executor(self.store, device_threshold=self.device_threshold,
                       mesh=self.mesh)
         results: dict[int, LevelNode] = {}
-        with tracing.span("engine.query", blocks=len(blocks)):
+        with tracing.span("engine.query", phase=True,
+                          blocks=len(blocks)):
             for i in execution_order(blocks):
                 results[i] = ex.run_block(blocks[i])
         roots = [results[i] for i in range(len(blocks))]  # textual order out

@@ -22,8 +22,6 @@ snapshot.
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from dgraph_tpu.engine.execute import Executor, LevelNode
@@ -347,8 +345,8 @@ def plan_batch_groups_cached(store, dqls: list):
         return cached
     METRICS.inc("plan_cache_misses_total", cache="batch")
     costprofile.note("plan_cache_hit", 0)
-    t_plan = time.perf_counter()
-    with tracing.span("batch.plan", queries=len(dqls)):
+    with tracing.span("batch.plan", phase=True,
+                      queries=len(dqls)) as sp:
         parsed = {}
         for i, q in enumerate(dqls):
             try:
@@ -365,13 +363,11 @@ def plan_batch_groups_cached(store, dqls: list):
     # store under the POST-planning fingerprint: planning may auto-create
     # default schema entries for unknown predicates, which would
     # otherwise shift the lookup key once and miss forever
-    costprofile.add("plan_us",
-                    int((time.perf_counter() - t_plan) * 1e6))
+    costprofile.add("plan_us", sp.dur_us)
     sch = store.schema
     sch.__dict__.pop("_plan_fp", None)
     _plan_memo.put((_schema_fingerprint(store), tuple(dqls)),
-                   (plans, leftover),
-                   rebuild_us=(time.perf_counter() - t_plan) * 1e6)
+                   (plans, leftover), rebuild_us=sp.dur_us)
     memgov.GOVERNOR.maybe_evict("host")
     return plans, leftover
 
@@ -419,10 +415,9 @@ def run_batch(store, plan, device_threshold: int) -> list:
     _note_kernel_features(plan.attr, "recurse", B, B - len(seeds),
                           plan.depth, len(plan.blocks))
     costprofile.note_max("bucket_mix", len(g.parts))
-    t_exec = time.perf_counter()
     with tracing.span("batch.recurse_kernel", attr=plan.attr,
                       depth=plan.depth, queries=len(plan.blocks),
-                      lanes=B, padded_lanes=B - len(seeds)):
+                      lanes=B, padded_lanes=B - len(seeds)) as sp:
         fn = _recurse_for(store, plan.attr, plan.reverse, mask0.shape[1])
         lkey = (plan.attr, plan.reverse, int(mask0.shape[1]),
                 plan.depth, g.n)
@@ -442,7 +437,7 @@ def run_batch(store, plan, device_threshold: int) -> list:
             "bfs.ell_recurse", lkey, _launch)
         hops = np.asarray(hops)      # [depth, n+1, W] fresh masks
     # launch count + dispatch gap are recorded by jit_call itself
-    exec_us = (time.perf_counter() - t_exec) * 1e6
+    exec_us = sp.dur_us
     costprofile.add_kernel("recurse", execute_us=exec_us)
     costprofile.add_tablet_cost(plan.attr, exec_us)
     # gather-traffic model per hop (the bench's HBM model): index reads
@@ -580,123 +575,154 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
     tests/test_batch.py against LDBC IC13/IC14 shapes."""
     import jax
 
-    g = _ell_for(store, plan.attr, plan.reverse)
-    if g is None:
-        return None
-    rrel = store.rel(plan.attr, not plan.reverse)
-    if rrel.nnz == 0:
-        return None
-    n = g.n
-    B = len(plan.queries)
+    from dgraph_tpu.engine.varorder import execution_order
 
-    src = store.rank_of(np.asarray(plan.src_uids, np.int64))
-    dst = store.rank_of(np.asarray(plan.dst_uids, np.int64))
-    lanes = _lane_count(B)
-    W = lanes // 32
-
-    # lanes needing a kernel at all: known endpoints, src != dst
-    active = [q for q in range(B)
-              if src[q] >= 0 and dst[q] >= 0 and src[q] != dst[q]]
     levels: list[np.ndarray] = []      # [n+1, W] per hop, permuted space
+    with tracing.span("batch.seed", phase=True,
+                      queries=len(plan.queries)) as sp:
+        g = _ell_for(store, plan.attr, plan.reverse)
+        if g is None:
+            return None
+        rrel = store.rel(plan.attr, not plan.reverse)
+        if rrel.nnz == 0:
+            return None
+        n = g.n
+        B = len(plan.queries)
+
+        src = store.rank_of(np.asarray(plan.src_uids, np.int64))
+        dst = store.rank_of(np.asarray(plan.dst_uids, np.int64))
+        lanes = _lane_count(B)
+        W = lanes // 32
+
+        # lanes needing a kernel at all: known endpoints, src != dst
+        active = [q for q in range(B)
+                  if src[q] >= 0 and dst[q] >= 0 and src[q] != dst[q]]
+        sp.attrs.update(lanes=lanes, active=len(active))
+        if active:
+            mask0 = np.zeros((n + 1, W), np.uint32)
+            for q in active:
+                r = g.new_of_old[int(src[q])]
+                mask0[r, q // 32] |= np.uint32(1 << (q % 32))
+            deadline.checkpoint("kernel")
+            METRICS.inc("kernel_group_launches_total", family="shortest")
+            METRICS.inc("kernel_group_queries_total", float(B),
+                        family="shortest")
+            METRICS.inc("kernel_padded_lanes_total", float(lanes - B),
+                        family="shortest")
+            _note_kernel_features(plan.attr, "shortest", lanes, lanes - B,
+                                  plan.depth, B)
+            costprofile.note_max("bucket_mix", len(g.parts))
+            step = _step_for(store, plan.attr, plan.reverse, W,
+                             plan.first_visit)
+            skey = (plan.attr, plan.reverse, W, plan.first_visit, n)
+            if memgov.GOVERNOR.is_degraded("bfs.ell_step", skey):
+                # sticky OOM degrade: the per-query path serves this shape
+                raise memgov.OomDegraded("bfs.ell_step", str(skey))
+            unresolved = {q: None for q in active}   # lanes still open
+            dst_rows = {q: int(g.new_of_old[int(dst[q])]) for q in active}
+            frontier = jax.device_put(mask0)
+            seen = jax.device_put(mask0)
     if active:
-        mask0 = np.zeros((n + 1, W), np.uint32)
-        for q in active:
-            r = g.new_of_old[int(src[q])]
-            mask0[r, q // 32] |= np.uint32(1 << (q % 32))
-        deadline.checkpoint("kernel")
-        METRICS.inc("kernel_group_launches_total", family="shortest")
-        METRICS.inc("kernel_group_queries_total", float(B),
-                    family="shortest")
-        METRICS.inc("kernel_padded_lanes_total", float(lanes - B),
-                    family="shortest")
-        _note_kernel_features(plan.attr, "shortest", lanes, lanes - B,
-                              plan.depth, B)
-        costprofile.note_max("bucket_mix", len(g.parts))
-        t_exec = time.perf_counter()
-        step = _step_for(store, plan.attr, plan.reverse, W,
-                         plan.first_visit)
-        skey = (plan.attr, plan.reverse, W, plan.first_visit, n)
-        if memgov.GOVERNOR.is_degraded("bfs.ell_step", skey):
-            # sticky OOM degrade: the per-query path serves this shape
-            raise memgov.OomDegraded("bfs.ell_step", str(skey))
-        unresolved = {q: None for q in active}   # q → found level (bfs)
-        dst_rows = {q: int(g.new_of_old[int(dst[q])]) for q in active}
-        frontier = jax.device_put(mask0)
-        seen = jax.device_put(mask0)
         with tracing.span("batch.shortest_kernel", attr=plan.attr,
                           depth=plan.depth, queries=B, lanes=lanes,
                           padded_lanes=lanes - B,
-                          first_visit=plan.first_visit):
+                          first_visit=plan.first_visit) as ksp:
             done = 0
             while done < plan.depth and unresolved:
                 # budget gate per stage: each launch is one
                 # uninterruptible dispatch of SHORTEST_STAGE hops
                 deadline.checkpoint("kernel")
                 chunk = min(SHORTEST_STAGE, plan.depth - done)
-                try:
-                    memgov.check_alloc_fault("bfs.ell_step")
-                    with jit_call("bfs.ell_step",
-                                  (plan.attr, plan.reverse, W, chunk,
-                                   plan.first_visit, n)):
-                        frontier, seen, hops = step(frontier, seen,
-                                                    chunk)
-                except Exception as e:
-                    if not memgov.is_alloc_failure(e):
-                        raise
-                    # the carries are DONATED: a failed dispatch leaves
-                    # no valid buffers to retry with, so this site
-                    # degrades in one step — evict for the next caller,
-                    # sticky-mark the shape, per-query path serves
-                    memgov.GOVERNOR.note_oom("bfs.ell_step", str(skey))
-                    memgov.GOVERNOR.degrade("bfs.ell_step", skey)
-                    raise memgov.OomDegraded("bfs.ell_step",
-                                             str(skey)) from e
-                hops_np = np.asarray(hops)
-                # each staged dispatch is one launch: jit_call counts
-                # it and bills the host gap between stages
-                for h in range(chunk):
-                    lvl = hops_np[h]
-                    levels.append(lvl)
-                    alive = np.bitwise_or.reduce(lvl[:n], axis=0)
-                    for q in list(unresolved):
-                        wq, bq = q // 32, np.uint32(1 << (q % 32))
-                        if plan.first_visit and \
-                                (lvl[dst_rows[q], wq] & bq):
-                            unresolved.pop(q)   # found: walk back later
-                            continue
-                        if not (alive[wq] & bq):
-                            unresolved.pop(q)   # frontier exhausted
+                with tracing.span("batch.device_wait", phase=True,
+                                  hops=chunk):
+                    try:
+                        memgov.check_alloc_fault("bfs.ell_step")
+                        # each staged dispatch is one launch: jit_call
+                        # counts it and bills the host gap between stages
+                        with jit_call("bfs.ell_step",
+                                      (plan.attr, plan.reverse, W, chunk,
+                                       plan.first_visit, n)):
+                            frontier, seen, hops = step(frontier, seen,
+                                                        chunk)
+                        # the dispatch returns at once: the span ends
+                        # when the device has the hops ready
+                        jax.block_until_ready(hops)
+                    except Exception as e:
+                        if not memgov.is_alloc_failure(e):
+                            raise
+                        # the carries are DONATED: a failed dispatch
+                        # leaves no valid buffers to retry with, so this
+                        # site degrades in one step — evict for the next
+                        # caller, sticky-mark the shape, per-query path
+                        # serves
+                        memgov.GOVERNOR.note_oom("bfs.ell_step",
+                                                 str(skey))
+                        memgov.GOVERNOR.degrade("bfs.ell_step", skey)
+                        raise memgov.OomDegraded("bfs.ell_step",
+                                                 str(skey)) from e
+                with tracing.span("batch.fetch", phase=True) as sp:
+                    hops_np = np.asarray(hops)
+                    sp.attrs["bytes"] = hops_np.nbytes
+                with tracing.span("batch.scan", phase=True,
+                                  hops=chunk) as sp:
+                    # hops a perfect early exit would have run: up to
+                    # the one that closed the last open lane
+                    used = chunk
+                    for h in range(chunk):
+                        lvl = hops_np[h]
+                        levels.append(lvl)
+                        alive = np.bitwise_or.reduce(lvl[:n], axis=0)
+                        for q in list(unresolved):
+                            wq, bq = q // 32, np.uint32(1 << (q % 32))
+                            if plan.first_visit and \
+                                    (lvl[dst_rows[q], wq] & bq):
+                                unresolved.pop(q)   # found: walk back later
+                                continue
+                            if not (alive[wq] & bq):
+                                unresolved.pop(q)   # frontier exhausted
+                        if not unresolved:
+                            used = h + 1
+                            break
+                    sp.attrs["hops_used"] = used
+                METRICS.inc("kernel_hops_run_total", float(chunk),
+                            family="shortest")
+                METRICS.inc("kernel_hops_used_total", float(used),
+                            family="shortest")
                 done += chunk
-        exec_us = (time.perf_counter() - t_exec) * 1e6
-        costprofile.add_kernel("shortest", execute_us=exec_us)
-        costprofile.add_tablet_cost(plan.attr, exec_us)
+        costprofile.add_kernel("shortest", execute_us=ksp.dur_us)
+        costprofile.add_tablet_cost(plan.attr, ksp.dur_us)
         costprofile.add("bytes_gathered",
                         done * g.padded_edges * (4 + W * 4))
 
-    out = []
-    for q in range(B):
-        blocks = plan.queries[q]
-        data = _shortest_path_data(store, plan, g, rrel, levels,
-                                   int(src[q]), int(dst[q]), q)
-        ex = Executor(store, device_threshold=device_threshold)
-        from dgraph_tpu.engine.varorder import execution_order
-        results: dict[int, LevelNode] = {}
-        try:
-            order = execution_order(blocks)
-        except ValueError:
-            return None
-        for bi in order:
-            sg = blocks[bi]
-            if bi == plan.block_idx[q]:
-                node = LevelNode(sg=sg, nodes=data.nodes,
-                                 path_data=data)
-                if sg.var_name:
-                    ex.uid_vars[sg.var_name] = data.nodes
-                results[bi] = node
-            else:
-                results[bi] = ex.run_block(sg)
-        out.append(to_json(ex, [results[i]
-                                for i in range(len(blocks))]))
+    # two passes over the queries, one span each: a span a query would
+    # make the trace grow with the batch
+    with tracing.span("batch.walk_back", phase=True, queries=B):
+        datas = [_shortest_path_data(store, plan, g, rrel, levels,
+                                     int(src[q]), int(dst[q]), q)
+                 for q in range(B)]
+    with tracing.span("batch.render", phase=True, queries=B):
+        out = []
+        for q in range(B):
+            blocks = plan.queries[q]
+            data = datas[q]
+            ex = Executor(store, device_threshold=device_threshold)
+            results: dict[int, LevelNode] = {}
+            try:
+                order = execution_order(blocks)
+            except ValueError:
+                return None
+            for bi in order:
+                sg = blocks[bi]
+                if bi == plan.block_idx[q]:
+                    node = LevelNode(sg=sg, nodes=data.nodes,
+                                     path_data=data)
+                    if sg.var_name:
+                        ex.uid_vars[sg.var_name] = data.nodes
+                    results[bi] = node
+                else:
+                    results[bi] = ex.run_block(sg)
+            out.append(to_json(ex, [results[i]
+                                    for i in range(len(blocks))]))
     return out
 
 
@@ -907,13 +933,12 @@ def _ell_for(store, attr: str, reverse: bool):
                 cache[key] = None
             else:
                 _note_ell_cache(hit=False)
-                t_build = time.perf_counter()
-                with tracing.span("batch.build_ell", pred=attr,
-                                  reverse=reverse):
+                with tracing.span("batch.build_ell", phase=True,
+                                  pred=attr, reverse=reverse) as sp:
                     g = build_ell(rel.indptr, rel.indices)
-                build_us = (time.perf_counter() - t_build) * 1e6
-                costprofile.add("build_us", int(build_us))
-                costprofile.add_tablet_cost(attr, build_us)
+                    sp.attrs["edges"] = int(g.nnz)
+                costprofile.add("build_us", sp.dur_us)
+                costprofile.add_tablet_cost(attr, sp.dur_us)
                 cache[key] = g
                 # segment-CSR padding waste: padded slots / real edges
                 METRICS.set_gauge("ell_padding_ratio",
@@ -942,7 +967,13 @@ def _dev_for(store, attr: str, reverse: bool):
                                  cascade=_drop_dependent_fns)
         dkey = (attr, reverse)
         if dkey not in devs:
-            devs[dkey] = device_ell(g)
+            import jax
+            with tracing.span("batch.upload_ell", phase=True, pred=attr,
+                              reverse=reverse) as sp:
+                dev = devs[dkey] = device_ell(g)
+                # device_put returns before the copy has ended
+                jax.block_until_ready(vars(dev))
+                sp.attrs["bytes"] = memgov.estimate_nbytes(dev)
         out = g, devs[dkey]
     memgov.GOVERNOR.maybe_evict("device")
     return out

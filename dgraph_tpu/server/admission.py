@@ -394,7 +394,10 @@ class AdmissionController:
             yield
             return
         ln = self.lanes[lane]
-        ln.acquire(ctx, cost_us=cost_us)
+        # the whole token acquisition, queued or not (`admission.wait`
+        # opens inside, only when the request queues)
+        with tracing.span("admission.admit", phase=True, lane=lane):
+            ln.acquire(ctx, cost_us=cost_us)
         self._tls.holding = True
         t0 = time.perf_counter()
         try:

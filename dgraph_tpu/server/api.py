@@ -564,6 +564,20 @@ class Alpha:
             store = self.acl.readable_view(acl_user, store)
         return store
 
+    @contextlib.contextmanager
+    def _read_view(self, read_ts: int | None, acl_user: str | None):
+        """The store view a read request runs on, registered as an
+        in-flight read for as long as the caller holds it. Getting there
+        (read ts, the partition-safe chain gate, the MVCC snapshot and
+        its routing/ACL wrappers) is the `mvcc.read_view` phase."""
+        with contextlib.ExitStack() as held:
+            with tracing.span("mvcc.read_view", phase=True) as sp:
+                ts = held.enter_context(self._reading(read_ts))
+                self._verify_read_chains(ts)
+                store = self._query_view(ts, acl_user)
+                sp.attrs["ts"] = ts
+            yield store
+
     def _verify_read_chains(self, ts: int) -> None:
         """Partition-safe read gate (reference: a raft follower never
         serves a log state that did not exist). Before a read at `ts` is
@@ -749,9 +763,7 @@ class Alpha:
         and raise a retryable `DeadlineExceeded` within one level/BFS
         iteration of the budget."""
         with self._request("read", deadline_ms, query_text=dql):
-            with self._reading(read_ts) as ts:
-                self._verify_read_chains(ts)
-                store = self._query_view(ts, acl_user)
+            with self._read_view(read_ts, acl_user) as store:
                 out = Engine(store,
                              device_threshold=self.device_threshold,
                              mesh=self.mesh).query(dql, variables)
@@ -766,9 +778,7 @@ class Alpha:
         (engine/emit.py), never a Python object tree (reference:
         outputnode.go ToJson writes bytes straight into the response)."""
         with self._request("read", deadline_ms, query_text=dql):
-            with self._reading(read_ts) as ts:
-                self._verify_read_chains(ts)
-                store = self._query_view(ts, acl_user)
+            with self._read_view(read_ts, acl_user) as store:
                 raw = Engine(store,
                              device_threshold=self.device_threshold,
                              mesh=self.mesh).query_bytes(dql, variables)
@@ -790,9 +800,7 @@ class Alpha:
         # shape; repeated dashboard batches hit the same prior)
         with self._request("read", deadline_ms,
                            query_text="\x1e".join(dqls)), \
-                self._reading(read_ts) as ts:
-            self._verify_read_chains(ts)
-            store = self._query_view(ts, acl_user)
+                self._read_view(read_ts, acl_user) as store:
             from dgraph_tpu.utils import logging as xlog
             results: list = [None] * len(dqls)
             leftover = list(range(len(dqls)))

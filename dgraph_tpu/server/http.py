@@ -563,22 +563,30 @@ def make_http_server(alpha: Alpha, addr: str = "127.0.0.1",
             """Slow-query log (reference: the query log at --v=3 /
             slow-query tooling): queries past --slow_query_ms log with
             their trace id so the spans can be pulled from
-            /debug/traces after the fact; the structured entry also
+            /debug/traces after the fact, and with the phase table of
+            the request (span name → ms); the structured entry also
             lands in the /debug/slow_queries ring, filterable by
             ?trace_id= (one-hop correlation to the span tree)."""
             thresh_ms = getattr(alpha, "slow_query_ms", 0) or 0
             if thresh_ms <= 0 or us < thresh_ms * 1000:
                 return
             METRICS.inc("slow_queries_total")
+            # the request's spans that have closed so far, by name, in
+            # ms: a stall names its phase in the log itself
+            by_name: dict = {}
+            for s in tracing.trace_spans(trace_id):
+                by_name[s.name] = by_name.get(s.name, 0) + s.dur_us
+            phases = {k: round(v / 1000.0, 1) for k, v in by_name.items()}
             xlog.get("http").warning(
                 "slow query: %.1f ms (threshold %s ms) trace_id=%s "
-                "query=%.200s", us / 1000.0, thresh_ms, trace_id,
-                " ".join(q.split()))
+                "query=%.200s phases_ms=%s", us / 1000.0, thresh_ms,
+                trace_id, " ".join(q.split()), json.dumps(phases))
             with _SLOW_LOCK:
                 _SLOW_LOG.append({
                     "trace_id": trace_id, "us": int(us),
                     "threshold_ms": thresh_ms,
                     "query": " ".join(q.split())[:200],
+                    "phases_ms": phases,
                     "mono_s": dl.monotonic_s()})
 
         def _explain_doc(self, trace_id: str) -> dict:
@@ -753,33 +761,31 @@ def make_http_server(alpha: Alpha, addr: str = "127.0.0.1",
             # back as an X-Trace-Id response header either way
             inbound_tid = self.headers.get("X-Trace-Id") or None
             if self.path.startswith("/query/batch"):
-                req = json.loads(self._body().decode())
                 with tracing.trace("http.query_batch",
                                    trace_id=inbound_tid,
-                                   queries=len(req["queries"])) as tid:
+                                   endpoint="query_batch") as tid:
+                    with tracing.span("http.decode", phase=True) as sp:
+                        body = self._body()
+                        req = json.loads(body.decode())
+                        sp.attrs.update(bytes=len(body),
+                                        queries=len(req["queries"]))
                     outs = alpha.query_batch(req["queries"],
                                              acl_user=acl_user,
                                              deadline_ms=deadline_ms)
-                us = int((time.perf_counter() - t0) * 1e6)
-                METRICS.observe("query_latency_us", us,
-                                endpoint="query_batch")
-                self._slow_query_check(us, tid,
-                                       f"<batch of "
-                                       f"{len(req['queries'])}>")
-                self._send_bytes(
-                    200,
-                    json.dumps({"data": outs,
-                                "extensions": {"trace_id": tid}}
-                               ).encode(),
-                    headers={"X-Trace-Id": tid})
+                    us = int((time.perf_counter() - t0) * 1e6)
+                    METRICS.observe("query_latency_us", us,
+                                    endpoint="query_batch")
+                    self._slow_query_check(us, tid,
+                                           f"<batch of "
+                                           f"{len(req['queries'])}>")
+                    with tracing.span("http.encode", phase=True) as sp:
+                        data = json.dumps(
+                            {"data": outs,
+                             "extensions": {"trace_id": tid}}).encode()
+                        sp.attrs["bytes"] = len(data)
+                        self._send_bytes(200, data,
+                                         headers={"X-Trace-Id": tid})
             elif self.path.startswith("/query"):
-                body = self._body().decode()
-                if "application/json" in (
-                        self.headers.get("Content-Type") or ""):
-                    req = json.loads(body)
-                    q, variables = req["query"], req.get("variables")
-                else:
-                    q, variables = body, None
                 # ?explain=true (or an X-Explain request header):
                 # echo the request's cost-Recorder breakdown — route
                 # per hop, kernel launches, launch-gap µs, cache hit
@@ -788,28 +794,41 @@ def make_http_server(alpha: Alpha, addr: str = "127.0.0.1",
                 explain = ("explain=true" in self.path.partition("?")[2]
                            or (self.headers.get("X-Explain") or ""
                                ).lower() in ("1", "true"))
-                with tracing.trace("http.query",
-                                   trace_id=inbound_tid) as tid:
+                with tracing.trace("http.query", trace_id=inbound_tid,
+                                   endpoint="query") as tid:
+                    with tracing.span("http.decode", phase=True) as sp:
+                        body = self._body()
+                        sp.attrs["bytes"] = len(body)
+                        if "application/json" in (
+                                self.headers.get("Content-Type") or ""):
+                            req = json.loads(body.decode())
+                            q, variables = (req["query"],
+                                            req.get("variables"))
+                        else:
+                            q, variables = body.decode(), None
                     raw = alpha.query_raw(q, variables,
                                           acl_user=acl_user,
                                           deadline_ms=deadline_ms)
-                us = int((time.perf_counter() - t0) * 1e6)
-                METRICS.observe("query_latency_us", us,
-                                endpoint="query")
-                self._slow_query_check(us, tid, q)
-                # splice the emitter's bytes into the envelope — the
-                # response body is never re-parsed server-side
-                env = (b'{"data":' + raw +
-                       b',"extensions":{"server_latency":'
-                       b'{"total_us":%d},"trace_id":"%s"'
-                       % (us, tid.encode()))
-                headers = {"X-Trace-Id": tid}
-                if explain:
-                    env += (b',"explain":'
-                            + json.dumps(self._explain_doc(tid),
-                                         default=str).encode())
-                    headers["X-Explain"] = "true"
-                self._send_bytes(200, env + b'}}', headers=headers)
+                    us = int((time.perf_counter() - t0) * 1e6)
+                    METRICS.observe("query_latency_us", us,
+                                    endpoint="query")
+                    self._slow_query_check(us, tid, q)
+                    with tracing.span("http.encode", phase=True) as sp:
+                        # splice the emitter's bytes into the envelope —
+                        # the response body is never re-parsed server-side
+                        env = (b'{"data":' + raw +
+                               b',"extensions":{"server_latency":'
+                               b'{"total_us":%d},"trace_id":"%s"'
+                               % (us, tid.encode()))
+                        headers = {"X-Trace-Id": tid}
+                        if explain:
+                            env += (b',"explain":'
+                                    + json.dumps(self._explain_doc(tid),
+                                                 default=str).encode())
+                            headers["X-Explain"] = "true"
+                        sp.attrs["bytes"] = len(env) + 2
+                        self._send_bytes(200, env + b'}}',
+                                         headers=headers)
             elif self.path.startswith("/mutate"):
                 ctype = self.headers.get("Content-Type") or ""
                 body = self._body().decode()

@@ -110,7 +110,7 @@ class AlphaConfig:
                                     # predicted cost) sheds ahead of the
                                     # queue filling; False restores the
                                     # reactive-only admission path
-    trace_dir: str = ""           # arm jax.profiler device-trace capture
+    trace_dir: str = ""           # default dir of /debug/profile captures
     log_level: str = "info"
 
 

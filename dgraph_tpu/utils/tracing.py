@@ -4,9 +4,15 @@ Reference parity: OpenCensus spans around each `ProcessTaskOverNetwork`
 leg with Jaeger export (SURVEY §5). TPU equivalent: lightweight in-process
 spans (queryable ring buffer + per-trace index, served by
 `/debug/traces` and — as Chrome trace-event JSON, Perfetto-loadable —
-`/debug/events`) and `jax.profiler` trace capture for device timelines
-when a trace directory is set. Spans fence device work with
-`jax.effects_barrier` so timings are honest.
+`/debug/events`) and on-demand `jax.profiler` captures (`profile_start`/
+`profile_stop`, POST /debug/profile; `--trace_dir` is their default
+directory). A span is the one clock of the served path, with three
+outlets: the ring, `phase_us{span=,endpoint=}` for the spans flagged as
+phases of a request (what Prometheus and the benchmark read), and —
+while a capture runs — a `jax.profiler.TraceAnnotation`, so the program's
+spans lie in the `.xplane.pb` on the device trace's clock and name its
+idle gaps. A span times the host: one that must cover device work waits
+for it inside (`block_until_ready`, as `batch.device_wait` does).
 
 Identity model: every span gets a process-unique integer `span_id`;
 nesting is a thread-local STACK of span ids, so concurrent (or nested)
@@ -18,7 +24,9 @@ trace_id "" and only live in the ring buffer.
 
 `set_enabled(False)` turns span recording into a near-no-op (one flag
 check) — the observability layer must never become the regression
-(tier-1 guards the query-path overhead at <5%).
+(tier-1 guards the query-path overhead at <5%). The cost-profile fields
+fed from spans (`plan_us`, `execute_us`, `build_us`) read 0 while
+disarmed.
 """
 
 from __future__ import annotations
@@ -33,6 +41,7 @@ from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 
 from dgraph_tpu.utils import locks
+from dgraph_tpu.utils.metrics import METRICS
 
 _TRACE_DIR: str | None = None
 _BUF: deque = deque(maxlen=4096)
@@ -92,7 +101,7 @@ def enabled() -> bool:
 
 
 def enable_device_trace(trace_dir: str) -> None:
-    """Arm jax.profiler capture for the next `span(..., device=True)`."""
+    """Set `profile_start`'s default capture directory (`--trace_dir`)."""
     global _TRACE_DIR
     _TRACE_DIR = trace_dir
 
@@ -103,6 +112,7 @@ def enable_device_trace(trace_dir: str) -> None:
 # /debug/profile concurrently can never corrupt a capture.
 _PROFILE_LOCK = locks.make_lock("tracing.profile")
 _PROFILE_DIR: str | None = None
+PROFILE_STOP_MARKER = "tracing.profile.stop_trace"
 
 
 def profile_start(trace_dir: str | None = None) -> str:
@@ -110,7 +120,6 @@ def profile_start(trace_dir: str | None = None) -> str:
     the dir `enable_device_trace`/`--trace_dir` armed). Raises when no
     dir is configured or a capture is already running (single-flight).
     Returns the capture dir."""
-    from dgraph_tpu.utils.metrics import METRICS
     global _PROFILE_DIR
     d = trace_dir or _TRACE_DIR
     if not d:
@@ -122,7 +131,13 @@ def profile_start(trace_dir: str | None = None) -> str:
                 f"a device profile is already capturing under "
                 f"{_PROFILE_DIR} — stop it first (single-flight)")
         import jax
-        jax.profiler.start_trace(d)
+        # no Python tracer: its frames were 20 MB for 4 s, slowed a batch
+        # by 0.12 s and made the stop hold the interpreter 0.4-1.6 s
+        # (PERF.md, PR 23). XLA's own host events stay (host tracer level
+        # at its default), and the program's spans annotate themselves.
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(d, profiler_options=opts)
         _PROFILE_DIR = d
         METRICS.inc("device_profile_captures_total", outcome="started")
         return d
@@ -132,7 +147,6 @@ def profile_stop() -> str:
     """Stop the running capture and return its dir; the XLA-level
     timeline lands under `<dir>/plugins/profile/` (Perfetto/
     TensorBoard-loadable)."""
-    from dgraph_tpu.utils.metrics import METRICS
     global _PROFILE_DIR
     with _PROFILE_LOCK:
         if _PROFILE_DIR is None:
@@ -140,6 +154,11 @@ def profile_stop() -> str:
         d, _PROFILE_DIR = _PROFILE_DIR, None
         import jax
         try:
+            # the capture's last host event: a reducer ends the traced
+            # window where the profiler's own teardown begins (the
+            # benchmark's `trace_reduce.STOP_EVENT` matches the suffix)
+            with jax.profiler.TraceAnnotation(PROFILE_STOP_MARKER):
+                pass
             jax.profiler.stop_trace()
         except Exception:
             METRICS.inc("device_profile_captures_total",
@@ -181,7 +200,6 @@ def attach(trace_id: str, parent_id: int = 0):
     if not trace_id:
         yield
         return
-    from dgraph_tpu.utils.metrics import METRICS
     METRICS.inc("trace_propagated_total")
     prev = getattr(_TLS, "trace_id", "")
     _TLS.trace_id = trace_id
@@ -202,32 +220,42 @@ def attach(trace_id: str, parent_id: int = 0):
 
 
 @contextlib.contextmanager
-def trace(name: str = "request", trace_id: str | None = None, **attrs):
+def trace(name: str = "request", trace_id: str | None = None,
+          endpoint: str = "", **attrs):
     """Establish a trace context: every span opened on this thread while
     inside (the root `name` span included) is indexed under the yielded
     trace id — the id the serving path echoes to clients and
-    `/debug/traces?trace_id=` resolves."""
+    `/debug/traces?trace_id=` resolves. `endpoint` names the request's
+    entry point for the phases inside (`phase_us{endpoint=}`)."""
     tid = trace_id or new_trace_id()
-    prev = getattr(_TLS, "trace_id", "")
-    _TLS.trace_id = tid
+    prev = getattr(_TLS, "trace_id", ""), getattr(_TLS, "endpoint", "")
+    _TLS.trace_id, _TLS.endpoint = tid, endpoint
     try:
         with span(name, **attrs):
             yield tid
     finally:
-        _TLS.trace_id = prev
+        _TLS.trace_id, _TLS.endpoint = prev
 
 
 @contextlib.contextmanager
-def span(name: str, device: bool = False, **attrs):
+def span(name: str, phase: bool = False, **attrs):
     """Time a region; nests via a thread-local stack of span IDS (names
     never participate in parent tracking — same-name spans, nested or
     concurrent, stay distinct). Yields the Span so callers can attach
-    attrs discovered mid-region (edge counts, chosen code path).
+    attrs discovered mid-region (edge counts, chosen code path); its
+    `dur_us` is set when the region closes.
 
-    `device=True` additionally wraps the region in a jax.profiler trace
-    (if armed) and blocks on async dispatch before closing the span.
+    `phase=True` marks one of the served path's named phases: on close
+    the span also feeds `phase_us{span=<name>, endpoint=<the enclosing
+    trace()'s endpoint, "" outside a request>}`. Flag only spans opened
+    a constant number of times per request, under names fixed in code:
+    the label sets must stay under the registry's cap.
+
+    While a profiler capture runs, every span is also entered as a
+    `jax.profiler.TraceAnnotation`; with none running that costs one
+    module-global load.
     """
-    if not _ENABLED and not device:
+    if not _ENABLED:
         yield _NULL_SPAN
         return
     sid = next(_IDS)
@@ -242,23 +270,22 @@ def span(name: str, device: bool = False, **attrs):
              start_us=int(time.time() * 1e6),
              tid=threading.get_ident(), pid=_PID, attrs=attrs)
     stack.append(sid)
-    t0 = time.perf_counter()
-    prof = None
-    if device and _TRACE_DIR is not None:
+    ann = None
+    if _PROFILE_DIR is not None:
         import jax
-        prof = jax.profiler.trace(_TRACE_DIR)
-        prof.__enter__()
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
+    t0 = time.perf_counter()
     try:
         yield s
     finally:
-        if device:
-            import jax
-            # fence pending async work so dur_us covers real execution
-            jax.effects_barrier()
-        if prof is not None:
-            prof.__exit__(None, None, None)
-        stack.pop()
         s.dur_us = int((time.perf_counter() - t0) * 1e6)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        stack.pop()
+        if phase:
+            METRICS.observe("phase_us", s.dur_us, span=name,
+                            endpoint=getattr(_TLS, "endpoint", ""))
         propagated = getattr(_TLS, "attach_depth", 0) > 0
         with _LOCK:
             _STAT["spans"] += 1

@@ -6,6 +6,7 @@ server + ThreadingHTTPServer in-process.
 """
 
 import json
+import time
 import urllib.request
 
 import pytest
@@ -124,11 +125,18 @@ def test_trace_id_echo_and_debug_surface(alpha):
     tid = out["extensions"]["trace_id"]
     assert tid and out["data"]["q"][0]["name"] == "alice"
 
-    with urllib.request.urlopen(
-            base + f"/debug/traces?trace_id={tid}") as r:
-        spans = json.loads(r.read())["spans"]
-    names = {s["name"] for s in spans}
+    # the root closes once the response is on the wire (it covers
+    # `http.encode`): a client can ask before it has
+    for _ in range(400):
+        with urllib.request.urlopen(
+                base + f"/debug/traces?trace_id={tid}") as r:
+            spans = json.loads(r.read())["spans"]
+        names = {s["name"] for s in spans}
+        if "http.query" in names:
+            break
+        time.sleep(0.005)
     assert "http.query" in names           # request root
+    assert {"http.decode", "mvcc.read_view", "http.encode"} <= names
     assert "engine.query" in names         # engine level
     assert "engine.block" in names
     # op level: the staged path's level/expand spans, or the whole-

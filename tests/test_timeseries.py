@@ -458,7 +458,13 @@ def test_breach_exemplar_and_debug_surfaces_live(alpha, tmp_path):
         assert evs and evs[-1]["window"] == "fast"
         exemplar = evs[-1]["trace_id"]
         assert exemplar in tids
-        spans = _get(base + f"/debug/traces?trace_id={exemplar}")["spans"]
+        # a request's root closes once its response is on the wire
+        for _ in range(400):
+            spans = _get(base + f"/debug/traces?trace_id={exemplar}"
+                         )["spans"]
+            if any(s["name"] == "http.query" for s in spans):
+                break
+            time.sleep(0.005)
         assert spans and {s["name"] for s in spans} >= {"http.query"}
         assert all(s["trace_id"] == exemplar for s in spans)
 

@@ -175,23 +175,297 @@ def test_ring_buffer_and_trace_index_bounded():
 
 
 # ---------------------------------------------------------------------------
+# phases: a span's two further outlets
+
+def _phase_series(span: str) -> dict:
+    """{endpoint: (sum, count)} of phase_us for one span name."""
+    from dgraph_tpu.utils.metrics import METRICS
+    out = {}
+    for series, h in METRICS.hist_snapshot().items():
+        if series.startswith("phase_us{") and f'span="{span}"' in series:
+            ep = series.split('endpoint="', 1)[1].split('"', 1)[0]
+            out[ep] = (h["sum"], h["n"])
+    return out
+
+
+def test_phase_span_feeds_phase_us_once_with_its_duration():
+    with tracing.span("t.phase_once", phase=True) as sp:
+        time.sleep(0.002)
+    with tracing.span("t.phase_once"):       # unflagged: a span only
+        pass
+    assert sp.dur_us >= 2000
+    assert _phase_series("t.phase_once") == {"": (float(sp.dur_us), 1)}
+    assert [s.name for s in tracing.recent(2)] == ["t.phase_once"] * 2
+
+
+def test_root_endpoint_reaches_nested_phases_on_its_thread_only():
+    other = {}
+
+    def elsewhere():
+        with tracing.span("t.phase_ep", phase=True) as sp:
+            pass
+        other["span"] = sp
+
+    with tracing.trace("http.t", endpoint="t_endpoint") as tid:
+        with tracing.span("outer"):
+            with tracing.span("t.phase_ep", phase=True) as inner:
+                pass
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    with tracing.span("t.phase_ep", phase=True) as after:
+        pass
+    got = _phase_series("t.phase_ep")
+    assert got["t_endpoint"] == (float(inner.dur_us), 1)
+    # another thread, and this one once the request is over: no endpoint
+    assert got[""] == (float(other["span"].dur_us + after.dur_us), 2)
+    assert inner.trace_id == tid and other["span"].trace_id == ""
+
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records, in one list
+    shared with the fake start/stop, what was entered and left."""
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+@pytest.fixture()
+def fake_profiler(monkeypatch):
+    import jax
+    log = _FakeAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, profiler_options=None: log.append(
+            ("start", d, profiler_options.python_tracer_level,
+             profiler_options.host_tracer_level)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: log.append(("stop",)))
+    yield log
+    if tracing.profile_status()["running"]:
+        tracing.profile_stop()
+
+
+def test_spans_are_trace_annotations_only_while_a_capture_runs(
+        fake_profiler, tmp_path):
+    import jax
+
+    log = fake_profiler
+    with tracing.span("before"):
+        pass
+    assert log == []
+    tracing.profile_start(str(tmp_path))
+    # the capture leaves the Python tracer out and XLA's host events in
+    default_host_level = jax.profiler.ProfileOptions().host_tracer_level
+    assert log == [("start", str(tmp_path), 0, default_host_level)]
+    with tracing.span("outer", phase=True):
+        with tracing.span("inner"):
+            pass
+    assert log[1:] == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner"), ("exit", "outer")]
+    tracing.profile_stop()
+    del log[:]
+    with tracing.span("after"):
+        pass
+    assert log == []
+
+
+def test_profile_stop_marks_the_end_before_it_stops(fake_profiler,
+                                                    tmp_path):
+    log = fake_profiler
+    tracing.profile_start(str(tmp_path))
+    del log[:]
+    tracing.profile_stop()
+    assert tracing.PROFILE_STOP_MARKER.endswith("stop_trace")
+    assert log == [("enter", tracing.PROFILE_STOP_MARKER),
+                   ("exit", tracing.PROFILE_STOP_MARKER), ("stop",)]
+
+
+# ---------------------------------------------------------------------------
+# the served path's phases
+
+BATCH_PHASES = ["http.decode", "admission.admit", "mvcc.read_view",
+                "batch.plan", "batch.seed", "batch.device_wait",
+                "batch.fetch", "batch.scan", "batch.walk_back",
+                "batch.render", "http.encode"]
+
+
+def _chain_alpha(n: int):
+    """p0 -> p1 -> ... -> p(n-1), and a shortcut p0 -> p2."""
+    from dgraph_tpu.server.api import Alpha
+    a = Alpha(device_threshold=10**9)
+    a.alter("name: string @index(exact) .\nfollows: [uid] @reverse .")
+    lines = [f'_:p{i} <name> "p{i}" .' for i in range(n)]
+    lines += [f"_:p{i} <follows> _:p{i + 1} ." for i in range(n - 1)]
+    lines.append("_:p0 <follows> _:p2 .")
+    uids = a.mutate(set_nquads="\n".join(lines))["uids"]
+    return a, [uids[f"_:p{i}"] for i in range(n)]
+
+
+def _shortest(u_from: str, u_to: str) -> str:
+    return ('{ path as shortest(from: %s, to: %s) { follows } '
+            'p(func: uid(path)) { name } }' % (u_from, u_to))
+
+
+def _wait_for_root(tid: str, name: str) -> list:
+    """The request's spans once its root has closed: it closes after the
+    response is on the wire, so a client can be here first."""
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        spans = tracing.trace_spans(tid)
+        if spans and spans[-1].name == name:
+            return spans
+        time.sleep(0.005)
+    raise AssertionError(f"{name} of trace {tid} never closed")
+
+
+def test_query_batch_opens_each_phase_once_and_they_cover_the_request():
+    import urllib.request
+
+    from dgraph_tpu.server.http import make_http_server, serve_background
+
+    alpha, u = _chain_alpha(13)
+    alpha.attach_admission(max_inflight=4, queue_depth=4)
+    alpha.slow_query_ms = 0.0001            # every request is slow
+    srv = make_http_server(alpha)
+    serve_background(srv)
+    base = f"http://127.0.0.1:{srv.server_address[1]}"
+    # 64 distinct pairs, none over 8 hops apart: one launch
+    pairs = [(i, j) for i in range(12) for j in range(i + 1, 13)
+             if j - i <= 8][:64]
+    assert len(pairs) == 64
+    qs = [_shortest(u[i], u[j]) for i, j in pairs]
+    try:
+        for attempt in ("cold", "warm"):
+            if attempt == "warm":           # new texts: a plan-cache miss
+                qs = [q.replace("p(func", "r(func") for q in qs]
+            before = {p: _phase_series(p).get("query_batch", (0, 0))
+                      for p in BATCH_PHASES}
+            req = urllib.request.Request(
+                base + "/query/batch",
+                data=json.dumps({"queries": qs}).encode(),
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=300) as r:
+                out = json.loads(r.read())
+            assert len(out["data"]) == 64
+            tid = out["extensions"]["trace_id"]
+            spans = _wait_for_root(tid, "http.query_batch")
+            root = spans[-1]
+            by_name = {}
+            for s in spans:
+                by_name.setdefault(s.name, []).append(s)
+            for p in BATCH_PHASES:
+                assert len(by_name.get(p, ())) == 1, (attempt, p)
+                s, n = _phase_series(p)["query_batch"]
+                assert (s - before[p][0], n - before[p][1]) == \
+                    (by_name[p][0].dur_us, 1), (attempt, p)
+            # the phases are a constant number of spans a request; one a
+            # query is only `engine.block`, the companion block's own
+            assert len(by_name["engine.block"]) == 64
+            assert len(spans) - 64 < 24
+            kernel = by_name["batch.shortest_kernel"][0]
+            for p in ("batch.device_wait", "batch.fetch", "batch.scan"):
+                assert by_name[p][0].parent_id == kernel.span_id
+            assert by_name["batch.fetch"][0].attrs["bytes"] > 0
+            # what no phase names is the shell between them: some tenths
+            # of a millisecond, which shows only against a warm batch on
+            # a store this small (20 ms)
+            covered = sum(by_name[p][0].dur_us for p in BATCH_PHASES)
+            least = 0.95 if attempt == "cold" else 0.8
+            assert least * root.dur_us <= covered <= root.dur_us, (
+                attempt, covered, root.dur_us,
+                {p: by_name[p][0].dur_us for p in BATCH_PHASES})
+        # the slow-query ring carries the request's phase table
+        from dgraph_tpu.server.http import slow_queries_snapshot
+        entry = slow_queries_snapshot(tid)[-1]
+        assert set(BATCH_PHASES[:-1]) <= set(entry["phases_ms"])
+        assert entry["phases_ms"]["batch.device_wait"] == round(
+            by_name["batch.device_wait"][0].dur_us / 1000.0, 1)
+    finally:
+        srv.shutdown()
+
+
+def test_hops_used_is_the_longest_found_path_of_the_launch():
+    from dgraph_tpu.engine.batch import SHORTEST_STAGE
+    from dgraph_tpu.utils.metrics import METRICS
+
+    alpha, u = _chain_alpha(10)
+
+    def hops():
+        return (METRICS.get("kernel_hops_run_total", family="shortest"),
+                METRICS.get("kernel_hops_used_total", family="shortest"))
+
+    # p0 -> p6 is 5 hops by the shortcut; the others are shorter
+    # (four queries: a smaller group is served one by one)
+    run0, used0 = hops()
+    out = alpha.query_batch([_shortest(u[0], u[6]), _shortest(u[1], u[3]),
+                             _shortest(u[4], u[5]), _shortest(u[0], u[2])])
+    lengths = [len(o["p"]) - 1 for o in out]
+    assert lengths == [5, 2, 1, 1]
+    run1, used1 = hops()
+    assert (run1 - run0, used1 - used0) == (SHORTEST_STAGE, max(lengths))
+    # a lane still open at the stage's end uses the whole stage: p0 -> p9
+    # is 8 hops, found at the stage's last hop
+    alpha.query_batch([_shortest(u[0], u[9])] +
+                      [_shortest(u[i], u[i + 1]) for i in range(3)])
+    run2, used2 = hops()
+    assert (run2 - run1, used2 - used1) == (SHORTEST_STAGE, SHORTEST_STAGE)
+    assert used2 - used0 <= run2 - run0
+
+
+# ---------------------------------------------------------------------------
 # tier-1 guard: observability must never become the regression
 
-def _hot_loop_secs(engine, queries, reps):
+class _CountingLock:
+    """A lock that counts how often the thread that made it takes it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._thread = threading.get_ident()
+        self.taken = 0
+
+    def __enter__(self):
+        if threading.get_ident() == self._thread:
+            self.taken += 1
+        return self._lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self._lock.__exit__(*exc)
+
+
+def _best_secs(fn, reps: int) -> float:
+    """The fastest of `reps` calls: load only ever adds time."""
     best = float("inf")
     for _ in range(reps):
         t0 = time.perf_counter()
-        for q in queries:
-            engine.query(q)
+        fn()
         best = min(best, time.perf_counter() - t0)
     return best
 
 
-def test_query_path_overhead_under_5_percent():
+def test_query_path_overhead_under_5_percent(monkeypatch):
     """The instrumented query path (spans + counters armed, the serving
     default) must stay within 5% of the same path with observability
-    disarmed, measured over test_query.py's kind of hot loop. min-of-N
-    on both sides damps scheduler noise."""
+    disarmed, over test_query.py's kind of hot loop.
+
+    The promise is held by counting, not by a ratio of two wall-clock
+    loops (the armed share is 0.5%, far under what a loaded machine adds
+    to either loop): what a disarmed span and a disarmed registry do at
+    all, how many recordings the armed loop makes, and what one
+    recording costs. Only that last step reads a clock, against a budget
+    ten times its reading."""
+    import jax
+
     from dgraph_tpu.engine import Engine
     from dgraph_tpu.store import StoreBuilder, parse_schema
     from dgraph_tpu.utils.metrics import METRICS
@@ -213,22 +487,61 @@ def test_query_path_overhead_under_5_percent():
         '{ q(func: has(friend), first: 20) { name friend { friend '
         '{ name } } } }',
     ]
-    for q in queries:  # warm parse/caches once
-        engine.query(q)
 
-    # interleaved best-of: measure off/on pairs, keep the best ratio —
-    # a single noisy scheduling quantum must not fail tier-1
-    best_ratio = float("inf")
-    for _attempt in range(3):
-        tracing.set_enabled(False)
-        METRICS.set_enabled(False)
-        off = _hot_loop_secs(engine, queries, reps=5)
+    def loop():
+        for q in queries:
+            engine.query(q)
+
+    loop()   # warm parse/caches once
+
+    span_lock, reg_lock = _CountingLock(), _CountingLock()
+    monkeypatch.setattr(tracing, "_LOCK", span_lock)
+    monkeypatch.setattr(METRICS, "_lock", reg_lock)
+    annotations = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name: annotations.append(name))
+
+    # disarmed: a span is one flag check and the shared null span (no
+    # id drawn, so no Span made), a recording one flag check; no lock
+    tracing.set_enabled(False)
+    METRICS.set_enabled(False)
+    try:
+        with tracing.span("ghost", phase=True, k=1) as sp:
+            assert sp is tracing._NULL_SPAN
+        next_id = next(tracing._IDS)
+        loop()
+        assert next(tracing._IDS) == next_id + 1
+        assert (span_lock.taken, reg_lock.taken) == (0, 0)
+        off = _best_secs(loop, reps=15)
+    finally:
         tracing.set_enabled(True)
         METRICS.set_enabled(True)
-        on = _hot_loop_secs(engine, queries, reps=5)
-        best_ratio = min(best_ratio, on / off)
-        if best_ratio <= 1.05:
-            break
-    assert best_ratio <= 1.05, (
-        f"observability overhead {best_ratio:.3f}x exceeds the 5% "
-        f"budget on the hot query path")
+
+    # armed, no capture running: each span takes the registry of spans
+    # once, each recording the registry of metrics once, and nothing
+    # reaches the profiler (the `_PROFILE_DIR is None` fast path)
+    assert tracing.profile_status()["running"] is False
+    span_lock.taken = reg_lock.taken = 0
+    loop()
+    spans, recordings = span_lock.taken, reg_lock.taken
+    assert annotations == []
+    assert 0 < spans <= 16 and 0 < recordings <= 32, (spans, recordings)
+
+    def one_span():
+        with tracing.span("t.unit", phase=True, k=1):
+            pass
+
+    def unit_secs(fn, n=500):
+        def many():
+            for _ in range(n):
+                fn()
+        return _best_secs(many, reps=9) / n
+
+    # a phase span is the dearest kind: it also records (one lock each)
+    span_s = unit_secs(one_span)
+    rec_s = unit_secs(lambda: METRICS.observe("t_unit_us", 5.0, k="v"))
+    share = (spans * span_s + recordings * rec_s) / off
+    assert share <= 0.05, (
+        f"observability costs {share:.1%} of the hot query path: "
+        f"{spans} spans at {span_s * 1e6:.1f} us and {recordings} "
+        f"recordings at {rec_s * 1e6:.1f} us in {off * 1e3:.2f} ms")
