@@ -39,16 +39,19 @@ MIN_BATCH = 4            # below this the per-query engine is cheaper
 # (utils/costprior.py; priors below the sample floor leave the count
 # rule in charge)
 KERNEL_WORTH_US = 5_000.0
-# Depth is a static arg of the jitted kernel: each distinct value is an
-# XLA compile, and the scan materializes a [depth, n+1, W] hops buffer
-# with no early exit. Depths past any real graph's diameter fall back to
-# the per-query engine (whose host loop exits when the frontier empties)
-# instead of letting a client-controlled depth size device buffers.
+# @recurse depth is a static arg of its jitted kernel: each distinct
+# value is an XLA compile, and the scan materializes a [depth, n+1, W]
+# hops buffer with no early exit. Depths past any real graph's diameter
+# fall back to the per-query engine (whose host loop exits when the
+# frontier empties) instead of letting a client-controlled depth size
+# device buffers.
 MAX_KERNEL_DEPTH = 64
-# shortest lane-BFS: hops per kernel launch. The staged host loop stops
-# as soon as every lane resolved (found / exhausted), so a short path
-# never pays the full depth cap; mask carries are DONATED between
-# stages (ops/bfs.py make_ell_step).
+# shortest lane-BFS: the most hops one kernel launch runs, which sizes
+# its [SHORTEST_STAGE, n+1, W] level buffer and bounds one
+# uninterruptible dispatch between deadline checkpoints. The launch
+# stops itself at the hop that closes its last open lane (found /
+# exhausted), so a short path pays for its own hops only; mask carries
+# are DONATED between stages (ops/bfs.py make_ell_step).
 SHORTEST_STAGE = 8
 
 
@@ -619,9 +622,13 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                 # sticky OOM degrade: the per-query path serves this shape
                 raise memgov.OomDegraded("bfs.ell_step", str(skey))
             unresolved = {q: None for q in active}   # lanes still open
-            dst_rows = {q: int(g.new_of_old[int(dst[q])]) for q in active}
+            # a lane with no target reads the all-zero sentinel row n
+            targets = np.full(lanes, n, np.int32)
+            targets[active] = g.new_of_old[dst[active]]
+            dst_rows = {q: int(targets[q]) for q in active}
             frontier = jax.device_put(mask0)
             seen = jax.device_put(mask0)
+            targets = jax.device_put(targets)
     if active:
         with tracing.span("batch.shortest_kernel", attr=plan.attr,
                           depth=plan.depth, queries=B, lanes=lanes,
@@ -630,23 +637,24 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
             done = 0
             while done < plan.depth and unresolved:
                 # budget gate per stage: each launch is one
-                # uninterruptible dispatch of SHORTEST_STAGE hops
+                # uninterruptible dispatch of at most SHORTEST_STAGE hops
                 deadline.checkpoint("kernel")
                 chunk = min(SHORTEST_STAGE, plan.depth - done)
                 with tracing.span("batch.device_wait", phase=True,
-                                  hops=chunk):
+                                  hops=chunk) as sp:
                     try:
                         memgov.check_alloc_fault("bfs.ell_step")
                         # each staged dispatch is one launch: jit_call
                         # counts it and bills the host gap between stages
                         with jit_call("bfs.ell_step",
-                                      (plan.attr, plan.reverse, W, chunk,
+                                      (plan.attr, plan.reverse, W,
                                        plan.first_visit, n)):
-                            frontier, seen, hops = step(frontier, seen,
-                                                        chunk)
+                            frontier, seen, hops, ran, _open = step(
+                                frontier, seen, targets,
+                                _lane_mask(unresolved, W), np.int32(chunk))
                         # the dispatch returns at once: the span ends
-                        # when the device has the hops ready
-                        jax.block_until_ready(hops)
+                        # when the device says how many hops it ran
+                        ran = int(ran)
                     except Exception as e:
                         if not memgov.is_alloc_failure(e):
                             raise
@@ -660,16 +668,21 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                         memgov.GOVERNOR.degrade("bfs.ell_step", skey)
                         raise memgov.OomDegraded("bfs.ell_step",
                                                  str(skey)) from e
+                    sp.attrs["hops_run"] = ran
                 with tracing.span("batch.fetch", phase=True) as sp:
-                    hops_np = np.asarray(hops)
-                    sp.attrs["bytes"] = hops_np.nbytes
+                    # only the levels the device ran are data
+                    for lvl in hops[:ran]:
+                        lvl.copy_to_host_async()
+                    lvls = [np.asarray(lvl) for lvl in hops[:ran]]
+                    sp.attrs["bytes"] = sum(lvl.nbytes for lvl in lvls)
                 with tracing.span("batch.scan", phase=True,
-                                  hops=chunk) as sp:
-                    # hops a perfect early exit would have run: up to
-                    # the one that closed the last open lane
-                    used = chunk
-                    for h in range(chunk):
-                        lvl = hops_np[h]
+                                  hops=ran) as sp:
+                    # the host's own reading of which lane closed where
+                    # (the device's exit applies the same rule): `used`
+                    # is the hop that closed the last open lane, so
+                    # used == ran says the device's exit was exact
+                    used = ran
+                    for h, lvl in enumerate(lvls):
                         levels.append(lvl)
                         alive = np.bitwise_or.reduce(lvl[:n], axis=0)
                         for q in list(unresolved):
@@ -684,11 +697,11 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                             used = h + 1
                             break
                     sp.attrs["hops_used"] = used
-                METRICS.inc("kernel_hops_run_total", float(chunk),
+                METRICS.inc("kernel_hops_run_total", float(ran),
                             family="shortest")
                 METRICS.inc("kernel_hops_used_total", float(used),
                             family="shortest")
-                done += chunk
+                done += ran
         costprofile.add_kernel("shortest", execute_us=ksp.dur_us)
         costprofile.add_tablet_cost(plan.attr, ksp.dur_us)
         costprofile.add("bytes_gathered",
@@ -724,6 +737,15 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
             out.append(to_json(ex, [results[i]
                                     for i in range(len(blocks))]))
     return out
+
+
+def _lane_mask(lanes, W: int) -> np.ndarray:
+    """Packed uint32[W] mask with the bit of every lane in `lanes`."""
+    q = np.fromiter(lanes, np.int64)
+    mask = np.zeros(W, np.uint32)
+    np.bitwise_or.at(mask, q // 32,
+                     np.uint32(1) << (q % 32).astype(np.uint32))
+    return mask
 
 
 def _level_member(g, levels, lvl: int, ranks: np.ndarray, q: int):
@@ -1025,7 +1047,7 @@ def _step_for(store, attr: str, reverse: bool, W: int,
             _governed_host_cache(host, "_ell_fns", "batch.kernel",
                                  "host", lambda v: _KERNEL_NBYTES_EST)
         if key not in fns:
-            fns[key] = make_ell_step(dev, g.n, W,
+            fns[key] = make_ell_step(dev, g.n, W, SHORTEST_STAGE,
                                      first_visit=first_visit)
         return fns[key]
 

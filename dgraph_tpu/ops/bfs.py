@@ -557,35 +557,68 @@ def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
     return recurse
 
 
-def make_ell_step(dev: DeviceEll, n: int, W: int, word_bits: int = 32,
-                  first_visit: bool = True):
-    """Compile a RESUMABLE hop block: fn(frontier, seen, depth) →
-    (frontier', seen', hops[depth, n+1, W]). Both mask carries are
-    DONATED — successive blocks of a staged traversal (engine/batch.py's
-    shortest groups) hand their buffers forward instead of re-allocating
-    per stage, the donation contract the README documents.
+def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
+                  word_bits: int = 32, first_visit: bool = True):
+    """Compile a RESUMABLE hop block that stops itself:
+    fn(frontier, seen, dst_rows, open_lanes, limit) →
+    (frontier', seen', hops, ran, open_lanes').
+
+    It runs hops until no lane is open or `limit` (a traced scalar, at
+    most `levels`) is reached, and at least one a call, so a staged
+    traversal always advances. `open_lanes` is the packed mask [W] of
+    lanes still open. After each hop a lane closes when its target row
+    `dst_rows[lane]` (int32[lanes], permuted space; the all-zero
+    sentinel row n for a lane with no target, which so never reads
+    "found") shows the lane's bit in the fresh mask, or when no row of
+    the fresh mask carries its bit (frontier exhausted): the rule of
+    engine/batch.py's host scan, which stays the authority on which
+    lane closed where. `hops` is a tuple of `levels` masks [n+1, W]
+    (separate arrays, so a caller copies back only what was run):
+    hops[h] is the fresh mask of this call's hop h+1 for h < `ran`, and
+    not data beyond. Both mask carries are DONATED — successive blocks
+    of a staged traversal (engine/batch.py's shortest groups) hand their
+    buffers forward instead of re-allocating per stage, the donation
+    contract the README documents.
 
     `first_visit=False` drops the seen-masking: hops[h] is then the FULL
     set reachable in exactly h+1 hops (the level-DAG the k-shortest
-    enumeration consumes), with `seen` passed through untouched."""
+    enumeration consumes), with `seen` passed through untouched, and a
+    lane closes only when its frontier is exhausted."""
     prepared = prepare_parts(dev, W)
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
+    lane = jnp.arange(W * word_bits, dtype=jnp.int32)
+    lane_word = lane // word_bits
+    lane_bit = dtype(1) << (lane % word_bits).astype(dtype)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1),
-                       static_argnames=("depth",))
-    def step(frontier, seen, depth: int):
-        def hop(carry, _):
-            f, s = carry
+    def or_over(x, axis):
+        return lax.reduce(x, dtype(0), lax.bitwise_or, (axis,))
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def step(frontier, seen, dst_rows, open_lanes, limit):
+        def more(carry):
+            _f, _s, _buf, ran, open_ = carry
+            return (ran == 0) | ((ran < limit) & (open_ != 0).any())
+
+        def hop(carry):
+            f, s, buf, ran, open_ = carry
             nxt = _ell_hop(prepared, f, W, dtype)
             if first_visit:
                 fresh = nxt & ~s
                 s = s | fresh
             else:
                 fresh = nxt
-            return (fresh, s), fresh
+            buf = lax.dynamic_update_index_in_dim(buf, fresh, ran, 0)
+            # row n is the zero sentinel: OR over all rows == over [:n]
+            open_ = open_ & or_over(fresh, 0)
+            if first_visit:
+                hit = fresh[dst_rows, lane_word] & lane_bit
+                open_ = open_ & ~or_over(hit.reshape(W, word_bits), 1)
+            return fresh, s, buf, ran + 1, open_
 
-        (f, s), hops = lax.scan(hop, (frontier, seen), None, length=depth)
-        return f, s, hops
+        buf = jnp.zeros((levels,) + frontier.shape, dtype)
+        f, s, buf, ran, open_ = lax.while_loop(
+            more, hop, (frontier, seen, buf, jnp.int32(0), open_lanes))
+        return f, s, tuple(buf[h] for h in range(levels)), ran, open_
 
     return step
 

@@ -350,3 +350,63 @@ def test_fold_carries_ell_cache(alpha):
     eng = Engine(alpha.mvcc.read_view(alpha.oracle.read_only_ts()),
                  device_threshold=10**9)
     assert out == [eng.query(q) for q in _queries(6)]
+
+
+@pytest.fixture(scope="module")
+def chain():
+    """p0 -> p1 -> ... -> p13 with a shortcut p0 -> p2: paths longer
+    than one SHORTEST_STAGE, two routes to every node past p1, and no
+    way back."""
+    a = Alpha(device_threshold=10**9)
+    a.alter("name: string @index(exact) .\nfollows: [uid] @reverse .")
+    lines = [f'_:p{i} <name> "p{i}" .' for i in range(14)]
+    lines += [f"_:p{i} <follows> _:p{i + 1} ." for i in range(13)]
+    lines.append("_:p0 <follows> _:p2 .")
+    uids = a.mutate(set_nquads="\n".join(lines))["uids"]
+    return a, [uids[f"_:p{i}"] for i in range(14)]
+
+
+# (pairs, shortest's extra arguments, hops of each launch): a launch
+# stops at the hop that closes its last open lane
+STOPPING_GROUPS = {
+    # p0 -> p12 is 11 hops: a full stage, then 3 more and no further
+    "two-launches": ([(0, 12), (1, 4), (3, 5), (2, 9)], "", [8, 3]),
+    # nothing leads back to p0: the search from p13 dies at hop 1,
+    # the one from p9 at hop 5, after the longest path found (4)
+    "unreachable": ([(13, 0), (9, 0), (0, 5), (4, 6)], "", [5]),
+    # the level-DAG closes a lane only when nothing is left to expand:
+    # from p0 every node is passed by hop 13, hop 14 is empty
+    "numpaths-2": ([(0, 3), (1, 4), (0, 12), (5, 6)], ", numpaths: 2",
+                   [8, 6]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STOPPING_GROUPS))
+def test_shortest_batch_stops_with_its_last_lane(chain, case):
+    """Answers are byte-equal to the per-query engine's, and each launch
+    runs the hops some open lane needs and none after."""
+    from dgraph_tpu.utils.metrics import METRICS
+
+    alpha, u = chain
+    pairs, extra, launches = STOPPING_GROUPS[case]
+    qs = ['{ path as shortest(from: %s, to: %s%s) { follows } '
+          'p(func: uid(path)) { name } }' % (u[i], u[j], extra)
+          for i, j in pairs]
+
+    def hops():
+        return (METRICS.get("kernel_hops_run_total", family="shortest"),
+                METRICS.get("kernel_hops_used_total", family="shortest"),
+                METRICS.get("jit_cache_hits_total", kernel="bfs.ell_step")
+                + METRICS.get("jit_compile_total", kernel="bfs.ell_step"))
+
+    run0, used0, calls0 = hops()
+    got = alpha.query_batch(qs)
+    run1, used1, calls1 = hops()
+    assert (run1 - run0, used1 - used0, calls1 - calls0) == \
+        (sum(launches), sum(launches), len(launches))
+    eng = Engine(alpha.mvcc.read_view(alpha.oracle.read_only_ts()),
+                 device_threshold=10**9)
+    want = [eng.query(q) for q in qs]
+    assert json.dumps(got) == json.dumps(want)
+    if case == "unreachable":
+        assert [len(o.get("p", [])) for o in got] == [0, 0, 5, 3]

@@ -6,6 +6,8 @@ queries at once (SURVEY §4: property-style random-graph checks as in
 algo/uidlist_test.go).
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -260,3 +262,143 @@ class TestSegmentCsr:
 def ell_recurse_local(g, mask0, depth):
     from dgraph_tpu.ops.bfs import ell_recurse
     return ell_recurse(g, mask0, depth)
+
+
+# -- the lane step that stops itself (ops/bfs.py make_ell_step) --------------
+
+STEP_LEVELS = 8
+
+
+def _scan_levels(prepared, mask0, depth, W, first_visit):
+    """The plain form: a scan of `depth` hops of _ell_hop with no exit.
+    Returns every hop's (fresh, seen)."""
+    import jax
+    from jax import lax
+
+    from dgraph_tpu.ops.bfs import _ell_hop
+
+    def hop(carry, _):
+        f, s = carry
+        nxt = _ell_hop(prepared, f, W)
+        if first_visit:
+            fresh = nxt & ~s
+            s = s | fresh
+        else:
+            fresh = nxt
+        return (fresh, s), (fresh, s)
+
+    _, (fs, ss) = jax.jit(lambda m: lax.scan(hop, (m, m), None,
+                                             length=depth))(mask0)
+    return np.asarray(fs), np.asarray(ss)
+
+
+def _host_rule(levels, n, dst_rows, unresolved, first_visit):
+    """engine/batch.py's scan of one launch's levels: pops the lanes it
+    closes from `unresolved`, returns the hop that closed the last one
+    (the count of levels when some stay open)."""
+    for h, lvl in enumerate(levels):
+        alive = np.bitwise_or.reduce(lvl[:n], axis=0)
+        for q in list(unresolved):
+            wq, bq = q // 32, np.uint32(1 << (q % 32))
+            if first_visit and (lvl[dst_rows[q], wq] & bq):
+                unresolved.discard(q)
+            elif not (alive[wq] & bq):
+                unresolved.discard(q)
+        if not unresolved:
+            return h + 1
+    return len(levels)
+
+
+def _packed(lanes_set, W):
+    m = np.zeros(W, np.uint32)
+    for q in lanes_set:
+        m[q // 32] |= np.uint32(1 << (q % 32))
+    return m
+
+
+@functools.lru_cache(maxsize=None)
+def _step_case(lanes, first_visit, acyclic):
+    """One graph, its lanes, the step program and the plain scan's levels.
+    The hop limit is a traced argument, so both limits share all of it."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import (build_ell, device_ell, make_ell_step,
+                                    prepare_parts)
+    from dgraph_tpu.store.store import _csr_from_pairs
+
+    # a random core, and a few nodes no edge touches. With cycles a
+    # level-DAG lane never dies; without (edges run to higher nodes, 25
+    # to 59 on: no path has over 6 edges) every lane does, in both modes
+    rng = np.random.default_rng(1000 * lanes + 2 * first_visit + acyclic)
+    core, lone = 150, 6
+    n = core + lone
+    src = np.repeat(np.arange(core, dtype=np.int32), 4)
+    if acyclic:
+        dst = np.minimum(src + rng.integers(25, 60, src.size), core - 1)
+        src, dst = src[src < dst], dst[src < dst].astype(np.int32)
+    else:
+        dst = rng.integers(0, core, src.size).astype(np.int32)
+    rel = _csr_from_pairs(src, dst, n)
+    g = build_ell(rel.indptr, rel.indices)
+    W = lanes // 32
+    B = lanes - 5                       # the last five lanes are padding
+    srcs = rng.integers(0, core, B)
+    dsts = rng.integers(0, core, B)
+    dsts[0] = core                      # unreachable: the search dies out
+    srcs[1] = core + 1                  # nothing to expand: dies at hop 1
+    active = {q for q in range(B) if q != 2 and srcs[q] != dsts[q]}
+    mask0 = np.zeros((n + 1, W), np.uint32)
+    dst_rows = {}
+    targets = np.full(lanes, n, np.int32)      # lane 2: inactive
+    for q in active:
+        mask0[g.new_of_old[srcs[q]], q // 32] |= np.uint32(1 << (q % 32))
+        dst_rows[q] = targets[q] = int(g.new_of_old[dsts[q]])
+
+    dev = device_ell(g)
+    want_f, want_s = _scan_levels(prepare_parts(dev, W),
+                                  jax.device_put(mask0), 3 * STEP_LEVELS,
+                                  W, first_visit)
+    step = make_ell_step(dev, n, W, STEP_LEVELS, first_visit=first_visit)
+    return n, W, mask0, active, dst_rows, targets, step, want_f, want_s
+
+
+@pytest.mark.parametrize("limit", [2, STEP_LEVELS])
+@pytest.mark.parametrize("first_visit,acyclic", [
+    (True, False), (False, False), (False, True)])
+@pytest.mark.parametrize("lanes", [32, 64, 128])
+def test_step_stops_where_the_host_rule_closes_the_last_lane(
+        lanes, first_visit, acyclic, limit):
+    import jax
+
+    (n, W, mask0, active, dst_rows, targets, step, want_f,
+     want_s) = _step_case(lanes, first_visit, acyclic)
+    unresolved = set(active)
+    frontier = seen = mask0
+    done = 0
+    for call, lim in enumerate((limit, STEP_LEVELS, STEP_LEVELS)):  # resumed
+        open_before = _packed(unresolved, W)
+        closing = _host_rule(want_f[done:done + lim], n, dst_rows,
+                             unresolved, first_visit)
+        f, s, hops, ran, open_after = step(
+            jax.device_put(frontier), jax.device_put(seen), targets,
+            open_before, np.int32(lim))
+        ran = int(ran)
+        assert ran == closing, (call, ran, closing)
+        assert len(hops) == STEP_LEVELS
+        for h in range(ran):
+            assert np.array_equal(np.asarray(hops[h]), want_f[done + h])
+        done += ran
+        frontier, seen = np.asarray(f), np.asarray(s)
+        assert np.array_equal(frontier, want_f[done - 1])
+        assert np.array_equal(seen, want_s[done - 1])
+        assert np.array_equal(np.asarray(open_after),
+                              _packed(unresolved, W))
+        if call == 0 and limit < STEP_LEVELS:
+            assert ran == limit and unresolved, \
+                "the limit must cut the first call short of its exit"
+        if not unresolved:
+            break
+    if first_visit or acyclic:
+        assert not unresolved, "every such search over 150 nodes ends"
+    else:
+        assert 0 in unresolved, "a level-DAG lane over cycles never dies"
