@@ -634,7 +634,7 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                           depth=plan.depth, queries=B, lanes=lanes,
                           padded_lanes=lanes - B,
                           first_visit=plan.first_visit) as ksp:
-            done = 0
+            done = pulled = 0
             while done < plan.depth and unresolved:
                 # budget gate per stage: each launch is one
                 # uninterruptible dispatch of at most SHORTEST_STAGE hops
@@ -649,12 +649,13 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                         with jit_call("bfs.ell_step",
                                       (plan.attr, plan.reverse, W,
                                        plan.first_visit, n)):
-                            frontier, seen, hops, ran, _open = step(
+                            frontier, seen, hops, ran, _open, pushed = step(
                                 frontier, seen, targets,
                                 _lane_mask(unresolved, W), np.int32(chunk))
                         # the dispatch returns at once: the span ends
-                        # when the device says how many hops it ran
-                        ran = int(ran)
+                        # when the device says how many hops it ran,
+                        # and how many of them pushed
+                        ran, pushed = map(int, jax.device_get((ran, pushed)))
                     except Exception as e:
                         if not memgov.is_alloc_failure(e):
                             raise
@@ -668,7 +669,7 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                         memgov.GOVERNOR.degrade("bfs.ell_step", skey)
                         raise memgov.OomDegraded("bfs.ell_step",
                                                  str(skey)) from e
-                    sp.attrs["hops_run"] = ran
+                    sp.attrs.update(hops_run=ran, hops_push=pushed)
                 with tracing.span("batch.fetch", phase=True) as sp:
                     # only the levels the device ran are data
                     for lvl in hops[:ran]:
@@ -701,11 +702,16 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                             family="shortest")
                 METRICS.inc("kernel_hops_used_total", float(used),
                             family="shortest")
+                METRICS.inc("kernel_hops_push_total", float(pushed),
+                            family="shortest")
                 done += ran
+                pulled += ran - pushed
         costprofile.add_kernel("shortest", execute_us=ksp.dur_us)
         costprofile.add_tablet_cost(plan.attr, ksp.dur_us)
+        # a pushed hop gathers its frontier's out-edges, a count only the
+        # device knows and small beside a pull's: only the pulls are billed
         costprofile.add("bytes_gathered",
-                        done * g.padded_edges * (4 + W * 4))
+                        pulled * g.padded_edges * (4 + W * 4))
 
     # two passes over the queries, one span each: a span a query would
     # make the trace grow with the batch
@@ -1030,8 +1036,13 @@ def _recurse_for(store, attr: str, reverse: bool, W: int):
 def _step_for(store, attr: str, reverse: bool, W: int,
               first_visit: bool):
     """Compiled resumable hop block per (snapshot, pred, dir, width,
-    family) — the staged shortest path's kernel, donated carries."""
-    from dgraph_tpu.ops.bfs import make_ell_step
+    family) — the staged shortest path's kernel, donated carries. Its
+    pushed hops read the relation's out-CSR in the ELL's row space: built
+    and uploaded here, the first time a step is asked for, into the
+    DeviceEll that `batch.ell_dev` governs."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import make_ell_step, out_csr
     from dgraph_tpu.ops.pallas_hop import pallas_enabled
 
     host = _cache_host(store, attr, reverse)
@@ -1047,6 +1058,15 @@ def _step_for(store, attr: str, reverse: bool, W: int,
             _governed_host_cache(host, "_ell_fns", "batch.kernel",
                                  "host", lambda v: _KERNEL_NBYTES_EST)
         if key not in fns:
+            if dev.out is None:
+                rel = store.rel(attr, reverse)
+                with tracing.span("batch.build_ell", phase=True, pred=attr,
+                                  reverse=reverse, part="out_csr"):
+                    out = out_csr(g, rel.indptr, rel.indices)
+                with tracing.span("batch.upload_ell", phase=True, pred=attr,
+                                  reverse=reverse, part="out_csr") as sp:
+                    dev.out = jax.block_until_ready(jax.device_put(out))
+                    sp.attrs["bytes"] = memgov.estimate_nbytes(dev.out)
             fns[key] = make_ell_step(dev, g.n, W, SHORTEST_STAGE,
                                      first_visit=first_visit)
         return fns[key]

@@ -13,11 +13,12 @@ One hop for ALL queries is two wide array ops over the COO edge list:
     active  = mask[src]                  row-gather   [E, B]
     next    = zeros.at[dst].max(active)  row-scatter  [N, B]
 
-The point is access *width*: TPU random gather/scatter costs are bounded by
-access count, not bytes (measured ~8 ns/access on v5e regardless of row
-width), so widening each access to a B-byte lane row amortises the
-irregular-memory tax across B queries — the same shape the reference can't
-reach because its per-query goroutines share nothing.
+The point is access *width*: a random row gather on the v5e costs its
+access, not its bytes (6.2 ns a gather of an 8-byte mask row: 45 M of them
+in 279 ms, the pull of make_ell_step on the chip, PR 30; the ledger reads
+the same since PR 23), so widening each access to a B-bit lane row
+amortises the irregular-memory tax across B queries — the same shape the
+reference can't reach because its per-query goroutines share nothing.
 """
 
 from __future__ import annotations
@@ -32,6 +33,7 @@ from jax import lax
 __all__ = ["ranks_to_bitmap", "bitmap_to_ranks", "bitmap_hop",
            "bitmap_recurse", "EllGraph", "build_ell", "ell_recurse",
            "DeviceEll", "device_ell", "prepare_parts", "make_ell_recurse",
+           "out_csr", "push_caps",
            "make_ell_step", "make_ell_count", "make_ell_tree",
            "pack_seed_masks", "unpack_masks"]
 
@@ -95,12 +97,22 @@ def bitmap_recurse(src: jax.Array, dst: jax.Array, deg: jax.Array,
 # ELL pull-hop: the access-amortised form of the batched traversal.
 #
 # The push kernel above pays one random row-gather AND one random
-# row-scatter per edge. Measured on v5e, random row access costs ~10 ns
-# REGARDLESS of row width, so the winning shape is: (1) eliminate the
+# row-scatter per edge, and on the v5e the scatter is the dear one: XLA:TPU
+# applies a scatter's updates one after another, about 120 ns a slot for a
+# gather of the source's row and a scatter-max of 64 lane bytes (PR 30,
+# _push_hop: the same at 32 K, 64 K and 128 K slots a turn), against 6.2 ns
+# a gather; and it sorts the updates first once they number over 2^16 to
+# 2^17, a sort that takes the compiler longer than the whole pull. So for a
+# frontier that covers the graph the winning shape is: (1) eliminate the
 # scatter entirely by pulling over in-neighbor lists, and (2) amortise each
 # access over as many concurrent queries as fit in the row (bit-packed
 # lanes: W words = word_bits·W queries per access). One hop is then pure
-# gathers + bitwise ORs — no scatter, no sort, fully static shapes.
+# gathers + bitwise ORs — no scatter, no sort, fully static shapes — and
+# costs one gather for every stored in-edge whatever the frontier holds.
+# For a frontier of a few rows that is the waste: make_ell_step therefore
+# pushes, hop by hop, while the frontier's out-edges number under a 128th
+# of the relation's (the two costs cross at a twentieth), and pulls
+# otherwise; the recurse and tree families pull always.
 #
 # Layout (PR 7, FeatGraph-style degree buckets): nodes are RENUMBERED by
 # in-degree class so each class's output is a contiguous slice and the
@@ -318,6 +330,10 @@ class DeviceEll:
     tiles: object          # device [M, seg_tile] | None
     lvl2: list             # device [h_b, K2] blocks
     seg_rows: int
+    # the same edges by SOURCE row, for make_ell_step's pushed hops
+    # (out_csr, on the device); None until a step program is asked for,
+    # so the pull-only families (recurse, tree) never pay for it
+    out: object = None
 
 
 def device_ell(g: EllGraph) -> DeviceEll:
@@ -327,6 +343,24 @@ def device_ell(g: EllGraph) -> DeviceEll:
         n=g.n, parts=parts,
         tiles=jax.device_put(g.tiles) if g.tiles is not None else None,
         lvl2=[jax.device_put(t) for t in g.lvl2], seg_rows=g.seg_rows)
+
+
+def out_csr(g: EllGraph, indptr, indices) -> tuple:
+    """The relation's out-edges in `g`'s permuted row space, for
+    DeviceEll.out once on the device: (indptr int32[n+1], indices
+    int32[E], deg int32[n]). Row r is old row perm_order[r], its targets
+    relabelled by new_of_old and kept in their stored order."""
+    import numpy as np
+    deg = np.diff(indptr).astype(np.int64)[g.perm_order]
+    ptr = np.zeros(g.n + 1, np.int64)
+    np.cumsum(deg, out=ptr[1:])
+    # permuted position p of row r reads old position
+    # indptr[perm_order[r]] + (p - ptr[r])
+    old_pos = np.repeat(
+        np.asarray(indptr[:-1], np.int64)[g.perm_order] - ptr[:-1], deg)
+    old_pos += np.arange(len(old_pos))
+    idx = g.new_of_old[np.asarray(indices)[old_pos]].astype(np.int32)
+    return ptr.astype(np.int32), idx, deg.astype(np.int32)
 
 
 def prepare_parts(dev: DeviceEll, W: int):
@@ -557,11 +591,110 @@ def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
     return recurse
 
 
+# The pushed hop of make_ell_step takes a frontier whose rows with an
+# out-edge number at most a PUSH_FANOUT-th of its slot cap, and whose
+# out-edges number at most the cap: the relation's edges over
+# PUSH_EDGE_SHARE, scaled from the edges so that a small graph keeps a pull
+# side. On the v5e a pushed slot costs 114-123 ns and a pulled in-edge 6.2,
+# so the two cross at a twentieth of the edges; the cap stands at a sixth of
+# that, where a push costs under a fifth of a pull (PR 30: at a 32nd the
+# follower cell's third hop, 0.7 to 3.7 M out-edges, pushed in two batches
+# of five, 4 % more queries a second, and a batch's time moved with its
+# pairs: runs spread by 1.3 % where they spread by 0.3). It expands them
+# PUSH_CHUNK slots a turn: XLA:TPU sorts the updates of a scatter of over
+# 2^16 to 2^17, and that sort alone takes longer to compile than the whole
+# pull. A turn takes at most a PUSH_TURN_FANOUT-th as many rows as slots (a
+# row of the follower cell's frontiers has 25 to 50 out-edges).
+PUSH_EDGE_SHARE = 128
+PUSH_FANOUT = 32
+PUSH_CHUNK = 1 << 15
+PUSH_TURN_FANOUT = 8
+
+
+def push_caps(edges: int) -> tuple:
+    """(row cap, slot cap, slots a turn) of the pushed hop for a relation
+    of `edges` edges."""
+    e_cap = edges // PUSH_EDGE_SHARE
+    return e_cap // PUSH_FANOUT, e_cap, min(PUSH_CHUNK, max(e_cap, 1))
+
+
+ROWS_BLK = 128        # _set_rows' block: one row gather finds a row's place
+
+
+def _set_rows(act, n: int, cap: int):
+    """The first `cap` set positions of act[n], ascending, padded with n:
+    jnp.nonzero(act, size=cap, fill_value=n) without its cumsum and
+    scatter over all n (12.5 ms on the v5e at 1.3 M rows, and seconds of
+    compile). Two levels: a search of the per-block counts finds the
+    block of the k-th set row, a prefix sum of that block's flags (a
+    matmul with a triangle: 0/1 inputs, exact) its place inside."""
+    nb = -(-n // ROWS_BLK)
+    blk = jnp.concatenate(
+        [act, jnp.zeros((nb * ROWS_BLK - n,), bool)]).reshape(nb, ROWS_BLK)
+    cnt = blk.sum(axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(cnt)
+    k = jnp.arange(cap, dtype=jnp.int32)
+    b = jnp.minimum(jnp.searchsorted(ends, k, side="right"),
+                    nb - 1).astype(jnp.int32)
+    nth = (k - (ends[b] - cnt[b])).astype(jnp.float32)   # within the block
+    upto = jnp.dot(blk[b].astype(jnp.float32),
+                   jnp.tri(ROWS_BLK, dtype=jnp.float32).T)
+    place = (upto <= nth[:, None]).sum(axis=1, dtype=jnp.int32)
+    return jnp.where(k < ends[-1], b * ROWS_BLK + place, n)
+
+
+def _push_hop(out, f, act, n, W, dtype, word_bits, f_cap, chunk):
+    """next[v] = OR of f[u] over the out-edges u→v of the frontier's own
+    rows: the same array _ell_hop gives, from as many slots as the
+    frontier has out-edges. `act` marks the rows of f[:n] that have a bit
+    and an out-edge; the caller has checked that they are at most f_cap
+    and that none has over `chunk` out-edges.
+
+    The rows are taken in ascending order, as many a turn as have
+    `chunk` out-edges between them and at most `win` (ops/hop.py
+    gather_edges expands them into flat slots), for as many turns as the
+    frontier takes. A slot's lanes travel one byte each, so that the OR
+    over the slots of one destination is a scatter-max. Slots beyond a
+    turn's last edge carry an out-of-range destination and are dropped,
+    so the sentinel row n stays zero."""
+    from dgraph_tpu.ops.hop import gather_edges
+    indptr, indices, deg = out
+    win = min(f_cap, max(chunk // PUSH_TURN_FANOUT, 1))
+    rows = _set_rows(act, n, f_cap)
+    ends = jnp.cumsum(jnp.take(deg, rows, mode="fill", fill_value=0))
+    # (both bounds hold by the caller's check; the loop ends without it)
+    count = jnp.minimum(act.sum(dtype=jnp.int32), f_cap)
+    # padding row n has no out-edge (indptr[n + 1] clips to indptr[n])
+    rows = jnp.concatenate([rows, jnp.full((win,), n, jnp.int32)])
+    shifts = jnp.arange(word_bits, dtype=dtype)
+
+    def turn(carry):
+        r0, acc = carry
+        before = jnp.where(r0 > 0, ends[r0 - 1], 0)
+        r1 = jnp.clip(jnp.searchsorted(ends, before + chunk, side="right"),
+                      r0 + 1, r0 + win).astype(jnp.int32)
+        mine = lax.dynamic_slice_in_dim(rows, r0, win)
+        mine = jnp.where(r0 + jnp.arange(win) < r1, mine, n)
+        dst, seg, _pos, _valid, _total = gather_edges(indptr, indices,
+                                                      mine, chunk)
+        bits = ((f[mine][seg][:, :, None] >> shifts) & dtype(1)).astype(
+            jnp.uint8).reshape(chunk, W * word_bits)
+        return r1, acc.at[dst].max(bits, mode="drop")
+
+    _r, acc = lax.while_loop(
+        lambda carry: carry[0] < count, turn,
+        (jnp.int32(0), jnp.zeros((n + 1, W * word_bits), jnp.uint8)))
+    return lax.reduce(
+        acc.reshape(n + 1, W, word_bits).astype(dtype) << shifts,
+        dtype(0), lax.bitwise_or, (2,))
+
+
 def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
-                  word_bits: int = 32, first_visit: bool = True):
+                  word_bits: int = 32, first_visit: bool = True,
+                  caps: tuple | None = None):
     """Compile a RESUMABLE hop block that stops itself:
     fn(frontier, seen, dst_rows, open_lanes, limit) →
-    (frontier', seen', hops, ran, open_lanes').
+    (frontier', seen', hops, ran, open_lanes', pushed).
 
     It runs hops until no lane is open or `limit` (a traced scalar, at
     most `levels`) is reached, and at least one a call, so a staged
@@ -580,28 +713,66 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
     buffers forward instead of re-allocating per stage, the donation
     contract the README documents.
 
+    Each hop computes the same next mask one of two exact ways, chosen
+    on the device from the frontier it is handed: a PUSH over the
+    frontier's own out-edges (`dev.out`, _push_hop) when `caps` (row cap,
+    slot cap, slots a turn) hold its rows with an out-edge, the sum of
+    their out-degrees and the largest of them, else the PULL over every
+    stored in-edge (_ell_hop). `pushed` counts the hops of this call
+    that pushed. `caps` is push_caps of the relation's edges; tests pass
+    their own.
+
     `first_visit=False` drops the seen-masking: hops[h] is then the FULL
     set reachable in exactly h+1 hops (the level-DAG the k-shortest
     enumeration consumes), with `seen` passed through untouched, and a
     lane closes only when its frontier is exhausted."""
-    prepared = prepare_parts(dev, W)
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
     lane = jnp.arange(W * word_bits, dtype=jnp.int32)
     lane_word = lane // word_bits
     lane_bit = dtype(1) << (lane % word_bits).astype(dtype)
+    f_cap, e_cap, chunk = caps or push_caps(int(dev.out[1].shape[0]))
+    # The index blocks ride as arguments. A device array that a jitted
+    # function closes over is a constant of its program: fetched to the
+    # host, compiled in and uploaded again, 15 s of the first call for the
+    # out-CSR's 180 MB on the chip, and a second copy on the device (PR 30)
+    leaves, tree = jax.tree_util.tree_flatten((prepare_parts(dev, W),
+                                               dev.out))
+    held = [x for x in leaves if isinstance(x, jax.Array)]
+
+    def blocks(arrays):
+        it = iter(arrays)
+        return tree.unflatten([next(it) if isinstance(x, jax.Array) else x
+                               for x in leaves])
 
     def or_over(x, axis):
         return lax.reduce(x, dtype(0), lax.bitwise_or, (axis,))
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def step(frontier, seen, dst_rows, open_lanes, limit):
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def step(arrays, frontier, seen, dst_rows, open_lanes, limit):
+        prepared, out = blocks(arrays)
+        outdeg = out[2]
+
         def more(carry):
-            _f, _s, _buf, ran, open_ = carry
+            _f, _s, _buf, ran, _pushed, open_ = carry
             return (ran == 0) | ((ran < limit) & (open_ != 0).any())
 
         def hop(carry):
-            f, s, buf, ran, open_ = carry
-            nxt = _ell_hop(prepared, f, W, dtype)
+            f, s, buf, ran, pushed, open_ = carry
+            pull = functools.partial(_ell_hop, prepared, f, W, dtype)
+            if f_cap:
+                act = (f[:n] != 0).any(axis=1) & (outdeg > 0)
+                degs = jnp.where(act, outdeg, 0)
+                fits = ((act.sum(dtype=jnp.int32) <= f_cap)
+                        & (degs.sum(dtype=jnp.int32) <= e_cap)
+                        & (degs.max() <= chunk))
+                nxt = lax.cond(
+                    fits,
+                    lambda: _push_hop(out, f, act, n, W, dtype,
+                                      word_bits, f_cap, chunk),
+                    pull)
+                pushed = pushed + fits
+            else:                   # caps that hold no row: every hop pulls
+                nxt = pull()
             if first_visit:
                 fresh = nxt & ~s
                 s = s | fresh
@@ -613,14 +784,16 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
             if first_visit:
                 hit = fresh[dst_rows, lane_word] & lane_bit
                 open_ = open_ & ~or_over(hit.reshape(W, word_bits), 1)
-            return fresh, s, buf, ran + 1, open_
+            return fresh, s, buf, ran + 1, pushed, open_
 
         buf = jnp.zeros((levels,) + frontier.shape, dtype)
-        f, s, buf, ran, open_ = lax.while_loop(
-            more, hop, (frontier, seen, buf, jnp.int32(0), open_lanes))
-        return f, s, tuple(buf[h] for h in range(levels)), ran, open_
+        f, s, buf, ran, pushed, open_ = lax.while_loop(
+            more, hop,
+            (frontier, seen, buf, jnp.int32(0), jnp.int32(0), open_lanes))
+        return (f, s, tuple(buf[h] for h in range(levels)), ran, open_,
+                pushed)
 
-    return step
+    return functools.partial(step, held)
 
 
 def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):

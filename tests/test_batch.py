@@ -410,3 +410,80 @@ def test_shortest_batch_stops_with_its_last_lane(chain, case):
     assert json.dumps(got) == json.dumps(want)
     if case == "unreachable":
         assert [len(o.get("p", [])) for o in got] == [0, 0, 5, 3]
+
+
+def _hops_pushed(store, attr, pairs, levels):
+    """The plain count behind `kernel_hops_push_total` for one launch of
+    first-visit lanes: a search a lane from each pair's source; a hop
+    pushes when the rows that some lane reached last hop and that have
+    an out-edge, the sum of their out-degrees and the largest of them
+    fit ops/bfs.py push_caps. Returns (hops run, hops pushed)."""
+    from dgraph_tpu.ops.bfs import push_caps
+
+    rel = store.rel(attr, False)
+    deg = np.diff(rel.indptr)
+    f_cap, e_cap, chunk = push_caps(len(rel.indices))
+    src = store.rank_of(np.asarray([a for a, _ in pairs], np.int64))
+    dst = store.rank_of(np.asarray([b for _, b in pairs], np.int64))
+    fresh = [{int(s)} for s in src]
+    seen = [set(f) for f in fresh]
+    open_ = set(range(len(pairs)))
+    ran = pushed = 0
+    while open_ and ran < levels:
+        rows = np.array(sorted(set().union(*fresh)), np.int64)
+        rows = rows[deg[rows] > 0]
+        pushed += bool(f_cap) and len(rows) <= f_cap and \
+            deg[rows].sum() <= e_cap and deg[rows].max(initial=0) <= chunk
+        ran += 1
+        for q in range(len(pairs)):
+            nxt = {int(v) for u in fresh[q] for v in rel.row(u)} - seen[q]
+            fresh[q] = nxt
+            seen[q] |= nxt
+            if not nxt or int(dst[q]) in nxt:
+                open_.discard(q)
+    return ran, pushed
+
+
+def test_shortest_batch_mixes_pushed_and_pulled_hops():
+    """On a graph large enough that a launch's first hop fits the pushed
+    hop's caps and its later hops do not, the batch answers what the
+    per-query path answers; the push counter moves by the hops pushed and
+    stays under the hops run; and only the step program brings the
+    out-CSR to the device: a @recurse batch on the same store does not."""
+    from dgraph_tpu.engine.batch import SHORTEST_STAGE, _cache_host
+    from dgraph_tpu.utils.metrics import METRICS
+
+    rng = np.random.default_rng(11)
+    a = Alpha(device_threshold=10**9)
+    a.alter(SCHEMA)
+    n = 1200            # 4,800 edges: the caps hold one row, not its 4
+    lines = [f'_:p{i} <name> "p{i}" .' for i in range(n)]
+    for i in range(n):
+        lines += [f"_:p{i} <follows> _:p{j} ."
+                  for j in rng.choice(n, 4, replace=False) if i != j]
+    uids = a.mutate(set_nquads="\n".join(lines))["uids"]
+
+    def counters():
+        return [METRICS.get(f"kernel_hops_{k}_total", family="shortest")
+                for k in ("run", "push")]
+
+    store = a.mvcc.read_view(a.oracle.read_only_ts())
+    a.query_batch(_queries(6, depth=2))
+    dev = _cache_host(store, "follows", False)._ell_devs[("follows", False)]
+    assert dev.out is None, "the lane @recurse family needs no out-CSR"
+
+    pairs = [(uids[f"_:p{i}"], uids[f"_:p{j}"])
+             for i, j in ((1, 240), (1, 77), (1, 950), (1, 123))]
+    qs = ['{ path as shortest(from: %s, to: %s) { follows } '
+          'p(func: uid(path)) { name } }' % p for p in pairs]
+    run0, push0 = counters()
+    got = a.query_batch(qs)
+    run1, push1 = counters()
+    assert dev.out is not None
+    eng = Engine(store, device_threshold=10**9)
+    assert json.dumps(got) == json.dumps([eng.query(q) for q in qs])
+    want_run, want_push = _hops_pushed(
+        store, "follows", [(int(x, 16), int(y, 16)) for x, y in pairs],
+        SHORTEST_STAGE)
+    assert (run1 - run0, push1 - push0) == (want_run, want_push)
+    assert 0 < want_push < want_run, "the batch must mix both kinds of hop"

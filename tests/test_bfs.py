@@ -316,14 +316,43 @@ def _packed(lanes_set, W):
     return m
 
 
+# (row cap, slot cap, slots a turn) of the pushed hop, small enough that
+# the 156-node case pushes some hops and pulls others ("mixed": a turn
+# of 24 slots, so a pushed hop takes several turns and rows straddle
+# them), never pushes ("pull") or always does ("push")
+STEP_CAPS = {"mixed": (40, 160, 24), "pull": (0, 0, 1),
+             "push": (156, 600, 64)}
+
+
+def _device_ell_with_out(rel):
+    """The relation's ELL on the device with its out-CSR beside it, as
+    engine/batch.py _step_for leaves it."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import build_ell, device_ell, out_csr
+    g = build_ell(rel.indptr, rel.indices)
+    dev = device_ell(g)
+    dev.out = jax.device_put(out_csr(g, rel.indptr, rel.indices))
+    return g, dev
+
+
+def _pushes(frontier, dev, n, caps):
+    """Whether the step pushes this frontier: its rows with an out-edge,
+    the sum of their out-degrees and the largest of them against the
+    caps."""
+    deg = np.asarray(dev.out[2])
+    act = (frontier[:n] != 0).any(axis=1) & (deg > 0)
+    return bool(caps[0]) and act.sum() <= caps[0] and \
+        deg[act].sum() <= caps[1] and deg[act].max(initial=0) <= caps[2]
+
+
 @functools.lru_cache(maxsize=None)
-def _step_case(lanes, first_visit, acyclic):
+def _step_case(lanes, first_visit, acyclic, caps="mixed"):
     """One graph, its lanes, the step program and the plain scan's levels.
     The hop limit is a traced argument, so both limits share all of it."""
     import jax
 
-    from dgraph_tpu.ops.bfs import (build_ell, device_ell, make_ell_step,
-                                    prepare_parts)
+    from dgraph_tpu.ops.bfs import make_ell_step, prepare_parts
     from dgraph_tpu.store.store import _csr_from_pairs
 
     # a random core, and a few nodes no edge touches. With cycles a
@@ -339,7 +368,7 @@ def _step_case(lanes, first_visit, acyclic):
     else:
         dst = rng.integers(0, core, src.size).astype(np.int32)
     rel = _csr_from_pairs(src, dst, n)
-    g = build_ell(rel.indptr, rel.indices)
+    g, dev = _device_ell_with_out(rel)
     W = lanes // 32
     B = lanes - 5                       # the last five lanes are padding
     srcs = rng.integers(0, core, B)
@@ -354,32 +383,37 @@ def _step_case(lanes, first_visit, acyclic):
         mask0[g.new_of_old[srcs[q]], q // 32] |= np.uint32(1 << (q % 32))
         dst_rows[q] = targets[q] = int(g.new_of_old[dsts[q]])
 
-    dev = device_ell(g)
     want_f, want_s = _scan_levels(prepare_parts(dev, W),
                                   jax.device_put(mask0), 3 * STEP_LEVELS,
                                   W, first_visit)
-    step = make_ell_step(dev, n, W, STEP_LEVELS, first_visit=first_visit)
-    return n, W, mask0, active, dst_rows, targets, step, want_f, want_s
+    step = make_ell_step(dev, n, W, STEP_LEVELS, first_visit=first_visit,
+                         caps=STEP_CAPS[caps])
+    return (n, W, mask0, active, dst_rows, targets, step, want_f, want_s,
+            dev)
 
 
 @pytest.mark.parametrize("limit", [2, STEP_LEVELS])
 @pytest.mark.parametrize("first_visit,acyclic", [
     (True, False), (False, False), (False, True)])
 @pytest.mark.parametrize("lanes", [32, 64, 128])
+@pytest.mark.parametrize("caps", list(STEP_CAPS))
 def test_step_stops_where_the_host_rule_closes_the_last_lane(
-        lanes, first_visit, acyclic, limit):
+        caps, lanes, first_visit, acyclic, limit):
+    """Every hop's level, `seen`, `ran` and the open lanes equal the plain
+    scan of pulls, whichever of its hops the step pushes; and it pushes
+    exactly the hops whose frontier its caps hold."""
     import jax
 
-    (n, W, mask0, active, dst_rows, targets, step, want_f,
-     want_s) = _step_case(lanes, first_visit, acyclic)
+    (n, W, mask0, active, dst_rows, targets, step, want_f, want_s,
+     dev) = _step_case(lanes, first_visit, acyclic, caps)
     unresolved = set(active)
     frontier = seen = mask0
-    done = 0
+    done = pushed_all = 0
     for call, lim in enumerate((limit, STEP_LEVELS, STEP_LEVELS)):  # resumed
         open_before = _packed(unresolved, W)
         closing = _host_rule(want_f[done:done + lim], n, dst_rows,
                              unresolved, first_visit)
-        f, s, hops, ran, open_after = step(
+        f, s, hops, ran, open_after, pushed = step(
             jax.device_put(frontier), jax.device_put(seen), targets,
             open_before, np.int32(lim))
         ran = int(ran)
@@ -387,6 +421,10 @@ def test_step_stops_where_the_host_rule_closes_the_last_lane(
         assert len(hops) == STEP_LEVELS
         for h in range(ran):
             assert np.array_equal(np.asarray(hops[h]), want_f[done + h])
+        expanded = [frontier] + list(want_f[done:done + ran - 1])
+        assert int(pushed) == sum(
+            _pushes(fr, dev, n, STEP_CAPS[caps]) for fr in expanded)
+        pushed_all += int(pushed)
         done += ran
         frontier, seen = np.asarray(f), np.asarray(s)
         assert np.array_equal(frontier, want_f[done - 1])
@@ -402,3 +440,90 @@ def test_step_stops_where_the_host_rule_closes_the_last_lane(
         assert not unresolved, "every such search over 150 nodes ends"
     else:
         assert 0 in unresolved, "a level-DAG lane over cycles never dies"
+    if caps == "pull":
+        assert pushed_all == 0
+    elif caps == "push":
+        assert pushed_all == done
+    elif first_visit or acyclic:        # frontiers that shrink again
+        assert 0 < pushed_all < done, "the case must mix both kinds of hop"
+
+
+def _one_hop(dev, n, W, mask0, caps, first_visit=True):
+    """One hop of a fresh step program under `caps`, every lane open and
+    none with a target: (level, seen, pushed)."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import make_ell_step
+    step = make_ell_step(dev, n, W, 1, first_visit=first_visit, caps=caps)
+    _f, s, hops, ran, _open, pushed = step(
+        jax.device_put(mask0), jax.device_put(mask0),
+        np.full(W * 32, n, np.int32), np.full(W, 0xFFFFFFFF, np.uint32),
+        np.int32(1))
+    assert int(ran) == 1
+    return np.asarray(hops[0]), np.asarray(s), int(pushed)
+
+
+@pytest.mark.parametrize("first_visit", [True, False])
+@pytest.mark.parametrize("over", ["fits", "one_row", "one_edge",
+                                  "one_slot"])
+def test_a_frontier_over_a_cap_takes_the_pull(over, first_visit):
+    """Caps that hold the frontier exactly push it; one row or one edge
+    less, or a turn one slot short of a row's out-edges, and the hop
+    pulls. The level is the same each way."""
+    n, W, mask0, _a, _d, _t, _step, want_f, want_s, dev = _step_case(
+        64, first_visit, False, "pull")
+    deg = np.asarray(dev.out[2])
+    act = (mask0[:n] != 0).any(axis=1) & (deg > 0)
+    rows, edges = int(act.sum()), int(deg[act].sum())
+    assert rows > 1 and edges > rows
+    widest = int(deg[act].max())
+    caps = {"fits": (rows, edges, widest),
+            "one_row": (rows - 1, edges, widest),
+            "one_edge": (rows, edges - 1, widest),
+            "one_slot": (rows, edges, widest - 1)}[over]
+    level, seen, pushed = _one_hop(dev, n, W, mask0, caps, first_visit)
+    assert pushed == (over == "fits")
+    assert np.array_equal(level, want_f[0])
+    assert np.array_equal(seen, want_s[0])
+
+
+@pytest.mark.parametrize("chunk", [5, 64, 1024])
+def test_a_pushed_hop_leaves_the_sentinel_row_zero(chunk):
+    """The slots of a turn beyond the frontier's last edge are dropped,
+    never written to row n: every padded gather of a later pull reads
+    that row as zero."""
+    n, W, mask0, _a, _d, _t, _step, want_f, _s, dev = _step_case(
+        64, True, False, "pull")
+    deg = np.asarray(dev.out[2])
+    edges = int(deg[(mask0[:n] != 0).any(axis=1)].sum())
+    assert edges % chunk, "the last turn must hold slots with no edge"
+    level, seen, pushed = _one_hop(dev, n, W, mask0, (n, 600, chunk))
+    assert pushed == 1
+    assert not level[n].any() and not seen[n].any()
+    assert np.array_equal(level, want_f[0])
+
+
+@pytest.mark.parametrize("chunk", [5, 64])
+@pytest.mark.parametrize("lanes", [32, 64])
+def test_a_hub_ors_the_lane_bits_of_its_many_sources(lanes, chunk):
+    """Forty sources carrying the same lane bit reach one hub: the hub's
+    mask holds that bit once (an add would carry 40 = 0b101000 into
+    bits 3 and 5), and the bits of the other lanes beside it."""
+    from dgraph_tpu.store.store import _csr_from_pairs
+    fans, hub, n = 40, 40, 44
+    src = np.concatenate([np.arange(fans), [hub, hub]]).astype(np.int32)
+    dst = np.concatenate([np.full(fans, hub), [41, 42]]).astype(np.int32)
+    g, dev = _device_ell_with_out(_csr_from_pairs(src, dst, n))
+    W = lanes // 32
+    mask0 = np.zeros((n + 1, W), np.uint32)
+    mask0[g.new_of_old[np.arange(fans)], 0] |= np.uint32(1)       # lane 0
+    mask0[g.new_of_old[np.arange(3)], 0] |= np.uint32(2)          # lane 1
+    mask0[g.new_of_old[7], W - 1] |= np.uint32(1 << 31)           # the last
+    pulled, _s, p0 = _one_hop(dev, n, W, mask0, (0, 0, 1))
+    pushed, _s, p1 = _one_hop(dev, n, W, mask0, (n, fans + 2, chunk))
+    assert (p0, p1) == (0, 1)
+    want = np.zeros((n + 1, W), np.uint32)
+    want[g.new_of_old[hub], 0] = 3
+    want[g.new_of_old[hub], W - 1] |= np.uint32(1 << 31)
+    assert np.array_equal(pushed, want)
+    assert np.array_equal(pulled, want)

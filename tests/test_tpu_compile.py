@@ -1,0 +1,58 @@
+"""The lane step's pushed hop, compiled for a v5e that is described and not
+attached (libtpu's compiler runs on this CPU host), at the follower cell's
+shapes. Nothing runs: these guard what only the chip's compiler refuses or
+makes dear. XLA:TPU sorts the updates of a scatter once they number over
+2^16 to 2^17, and compiling that sort alone takes longer than the whole pull
+(PERF.md, PR 30), so the pushed hop must come out with no sort in it.
+
+The topology is described inside a fixture, never at import: one process at
+a time may load libtpu, and every xdist worker imports this file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+N, E, W = 1303125, 44919214, 2      # follower-tw2010-32nd, 64 lanes
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_pushed_hop_compiles_for_the_v5e_without_a_sort(one_chip):
+    from dgraph_tpu.ops import bfs
+    f_cap, _e_cap, chunk = bfs.push_caps(E)
+
+    def push(indptr, indices, deg, frontier, act):
+        return bfs._push_hop((indptr, indices, deg), frontier, act, N, W,
+                             jnp.uint32, 32, f_cap, chunk)
+
+    c = _compiled(one_chip, push, ((N + 1,), jnp.int32), ((E,), jnp.int32),
+                  ((N,), jnp.int32), ((N + 1, W), jnp.uint32),
+                  ((N,), jnp.bool_))
+    hlo = c.as_text()
+    assert " sort(" not in hlo
+    assert " scatter(" in hlo                    # the text is the HLO's
+    # the byte mask, its repack and a turn's slots: well under a GB
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("cap", [64, 8192, 65536])
+def test_set_rows_compiles_for_the_v5e_without_a_sort(one_chip, cap):
+    from dgraph_tpu.ops import bfs
+    c = _compiled(one_chip, lambda act: bfs._set_rows(act, N, cap),
+                  ((N,), jnp.bool_))
+    assert " sort(" not in c.as_text()
