@@ -26,9 +26,7 @@ record of WHY the invariant doesn't apply.
 
 The analyzer also extracts a FACTS inventory (kernel shapes, span
 sites, metric names, lock order classes — `facts.py`): the static half
-of the ROADMAP's TpuGraphs-style cost-model item, and the input
-`bench.py` folds into BENCH JSON so the perf trajectory tracks lint
-debt alongside throughput.
+of the ROADMAP's TpuGraphs-style cost-model item.
 
 Run standalone::
 
@@ -54,7 +52,7 @@ WAIVER_SYNTAX = "waiver-syntax"
 @dataclasses.dataclass
 class Finding:
     """One rule violation at one site. `waived` findings are kept (the
-    CLI can show them; bench counts them) but never fail the build."""
+    CLI can show them; `counts` tallies them) but never fail the build."""
 
     rule: str
     path: str          # repo-relative, "/"-separated
@@ -241,10 +239,9 @@ class Analyzer:
         return [f for f in self.findings if not f.waived]
 
     def counts(self) -> dict[str, dict[str, int]]:
-        """{"findings": {rule: unwaived}, "waived": {rule: waived}} —
-        the shape bench.py embeds into BENCH JSON. Every active rule
-        is pre-seeded at 0 so the BENCH trajectory shows a clean rule
-        AS clean instead of omitting it (a new rule's debt is visible
+        """{"findings": {rule: unwaived}, "waived": {rule: waived}}.
+        Every active rule is pre-seeded at 0 so a clean rule shows AS
+        clean instead of being omitted (a new rule's debt is visible
         from its first run)."""
         out = {"findings": {r.name: 0 for r in self.rules},
                "waived": {r.name: 0 for r in self.rules}}
@@ -266,17 +263,13 @@ class Analyzer:
 
 def default_paths(repo_root: pathlib.Path) -> list[pathlib.Path]:
     """What `python -m dgraph_tpu.analysis` (and tier-1) scans: the
-    whole package, plus bench.py for the metric-docs pass."""
-    paths = [repo_root / "dgraph_tpu"]
-    bench = repo_root / "bench.py"
-    if bench.exists():
-        paths.append(bench)
-    return paths
+    whole package."""
+    return [repo_root / "dgraph_tpu"]
 
 
 def run(repo_root: pathlib.Path | None = None) -> Analyzer:
     """One-call entry: scan the default file set with the default
-    rules. Used by tests/test_lint.py and bench.py."""
+    rules. Used by tests/test_lint.py."""
     if repo_root is None:
         repo_root = pathlib.Path(__file__).resolve().parents[2]
     a = Analyzer(repo_root=repo_root)

@@ -96,8 +96,7 @@ def extract_facts(contexts) -> dict:
     guarded_sites: list[dict] = []
     jit_rule = JitPurity()
     for ctx in contexts:
-        if not (ctx.rel.startswith("dgraph_tpu/")
-                or ctx.rel == "bench.py"):
+        if not ctx.rel.startswith("dgraph_tpu/"):
             continue
         guarded_fields.extend(class_inventory(ctx))
         guarded_sites.extend(_guarded_sites(ctx))

@@ -259,7 +259,7 @@ class MetricDocs(Rule):
         self.sites: list[dict] = []
 
     def applies(self, rel: str) -> bool:
-        return rel.startswith("dgraph_tpu/") or rel == "bench.py"
+        return rel.startswith("dgraph_tpu/")
 
     def check_file(self, ctx: FileContext) -> list[Finding]:
         out = []
@@ -450,8 +450,7 @@ class ShardMapCompat(Rule):
     SHIM = "dgraph_tpu/utils/jaxcompat.py"
 
     def applies(self, rel: str) -> bool:
-        return ((rel.startswith("dgraph_tpu/") or rel == "bench.py")
-                and rel != self.SHIM)
+        return rel.startswith("dgraph_tpu/") and rel != self.SHIM
 
     def check_file(self, ctx: FileContext) -> list[Finding]:
         out = []
@@ -682,7 +681,7 @@ class SloSpec(Rule):
         self.known = frozenset(SLO_SPECS)
 
     def applies(self, rel: str) -> bool:
-        return rel.startswith("dgraph_tpu/") or rel == "bench.py"
+        return rel.startswith("dgraph_tpu/")
 
     def check_file(self, ctx: FileContext) -> list[Finding]:
         out = []

@@ -97,7 +97,7 @@ _MAX_ATTEMPTS = 16       # geometric cap growth, bounded
 
 def enabled() -> bool:
     """Default-ON flag: DGRAPH_TPU_FUSED=0 pins every query to the
-    staged path (the bench A/B toggles this in a child). Read per call
+    staged path (an A/B toggles this in a child). Read per call
     so a subprocess A/B needs no re-import."""
     return os.environ.get("DGRAPH_TPU_FUSED", "1") != "0"
 

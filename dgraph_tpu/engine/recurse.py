@@ -25,13 +25,6 @@ from dgraph_tpu.utils import deadline
 
 MAX_RECURSE_DEPTH = 64  # guard when depth: 0 (fixpoint mode)
 
-# Mesh @recurse route: chained hops (ONE compiled hop program reused at
-# every depth, frontier/seen device-resident between launches — the
-# reshard-free serving path) vs the monolithic lax.scan program
-# (recurse_fused_matrix, which retraces per depth). Chain is the
-# serving default; the scan variant stays for A/B and tests.
-MESH_CHAIN_HOPS = True
-
 
 @dataclass
 class RecurseData:
@@ -79,18 +72,15 @@ def expand_recurse(ex, root) -> None:
 
     data = split_children(ex, root.sg, RecurseData(loop=args.loop))
 
-    # Single-predicate depth-bounded visit-once recursions run as ONE
-    # compiled SPMD program on the mesh (all hops inside one lax.scan over
-    # shard_map — the north-star fusion). Filters/facet-filters/loop need
-    # per-hop host logic and fall back to the loop below.
+    # Single-predicate depth-bounded visit-once recursions run on the mesh
+    # as chained launches of ONE compiled SPMD hop program (_chain_recurse).
+    # Filters/facet-filters/loop need per-hop host logic and fall back to
+    # the loop below.
     if (ex.mesh is not None and not args.loop and args.depth
             and len(data.edge_sgs) == 1 and not data.edge_sgs[0].filters
             and not data.edge_sgs[0].facet_filter
             and len(root.nodes) > 0):
-        if MESH_CHAIN_HOPS:
-            _chain_recurse(ex, root, data, args.depth)
-        else:
-            _fused_recurse(ex, root, data, args.depth)
+        _chain_recurse(ex, root, data, args.depth)
         _bind_recurse_vars(ex, root, data, sg)
         root.recurse_data = data
         return
@@ -167,11 +157,10 @@ def _chain_recurse(ex, root, data: RecurseData, depth: int) -> None:
     and seen set stay device-resident between hops — zero cross-device
     reshards on the steady path (mesh.reshard_guard armed around the
     loop; the pjit pitfall SNIPPETS calls out) — and the compile is
-    depth-independent, where the lax.scan program retraces per depth.
-    The host only READS each hop's outputs (edge matrices + the input
-    frontier's values, for rendering) and feeds the same device arrays
-    back in. Semantics are identical to _fused_recurse (visit-once,
-    first-visit-tree), pinned by tests against it and the host loop."""
+    depth-independent. The host only READS each hop's outputs (edge
+    matrices + the input frontier's values, for rendering) and feeds the
+    same device arrays back in. Semantics are the host loop's
+    (visit-once, first-visit-tree), pinned by tests against it."""
     from dgraph_tpu.engine.execute import _bucket, pad_host
     from dgraph_tpu.ops.uidalgebra import SENTINEL32
     from dgraph_tpu.parallel.dhop import chain_hop
@@ -247,55 +236,3 @@ def _chain_recurse(ex, root, data: RecurseData, depth: int) -> None:
                          np.concatenate(parts_c).astype(np.int32))
     seen_h = host_np(seen)
     data.all_nodes = seen_h[seen_h != SENTINEL32].astype(np.int32)
-
-
-def _fused_recurse(ex, root, data: RecurseData, depth: int) -> None:
-    """Drive parallel.dhop.recurse_fused_matrix: the whole hop loop is one
-    jitted shard_map program (reference: query/recurse.go expandRecurse,
-    with the per-level ProcessTaskOverNetwork fan-out collapsed into
-    on-mesh collectives). Host work is only cap policy + matrix unpack."""
-    from dgraph_tpu import ops
-    from dgraph_tpu.engine.execute import _bucket
-    from dgraph_tpu.ops.uidalgebra import SENTINEL32
-    from dgraph_tpu.parallel.dhop import recurse_fused_matrix
-
-    esg = data.edge_sgs[0]
-    srel = ex.store.sharded_rel(esg.attr, esg.is_reverse, ex.mesh)
-    out_cap = _bucket(max(len(root.nodes), 1))
-    seen_cap = _bucket(4 * out_cap, lo=256)
-    edge_cap = _bucket(1, lo=1024)
-    for _attempt in range(12):  # geometric cap growth, bounded
-        fr = ops.pad_to(np.sort(root.nodes).astype(np.int32), out_cap)
-        (last, seen, edges, needs, nbrs_s, seg_s, _pos_s,
-         frontiers) = recurse_fused_matrix(
-            ex.mesh, srel, fr, edge_cap=edge_cap, out_cap=out_cap,
-            seen_cap=seen_cap, depth=depth)
-        from dgraph_tpu.parallel.mesh import host_np
-        need_out, need_seen, need_edge = (int(x) for x in host_np(needs))
-        if (need_out <= out_cap and need_seen <= seen_cap
-                and need_edge <= edge_cap):
-            break
-        out_cap = _bucket(max(need_out, out_cap))
-        seen_cap = _bucket(max(need_seen, seen_cap), lo=256)
-        edge_cap = _bucket(max(need_edge, edge_cap), lo=1024)
-    else:
-        raise RuntimeError("recurse caps failed to converge")
-
-    nbrs_s = host_np(nbrs_s)         # [D, depth, edge_cap]
-    seg_s = host_np(seg_s)
-    frontiers = host_np(frontiers)   # [depth, out_cap]
-    parts_p, parts_c = [], []
-    for h in range(depth):
-        fr_h = frontiers[h]
-        for d in range(nbrs_s.shape[0]):
-            row = nbrs_s[d, h]
-            m = row != SENTINEL32
-            if not m.any():
-                continue
-            parts_p.append(fr_h[seg_s[d, h][m]])
-            parts_c.append(row[m])
-    if parts_p:
-        data.edges[0] = (np.concatenate(parts_p).astype(np.int32),
-                         np.concatenate(parts_c).astype(np.int32))
-    seen = host_np(seen)
-    data.all_nodes = seen[seen != SENTINEL32].astype(np.int32)

@@ -8,7 +8,7 @@ available in this environment (zero egress), so this module generates a
 deterministic graph with the same *shape*: SF-scaled entity counts, a
 community-clustered heavy-tailed `knows` graph, activity (posts/comments)
 with creator/reply/tag edges, and typed scalar properties — enough for the
-IC-style query mix in bench_baseline.py to be structurally honest.
+IC-style query mix (`ic_templates`) to be structurally honest.
 
 Scale factors follow SNB's published SF1 proportions (~10k persons, ~180k
 knows half-edges, ~1M messages at SF1), scaled linearly.
@@ -245,8 +245,8 @@ def ic_params(g: SNBGraph) -> dict:
 
 def ic_templates(g: SNBGraph) -> dict[str, str]:
     """All 14 LDBC SNB Interactive Complex template shapes as DQL — the
-    single source used by both the benchmark (bench_baseline.py config
-    5) and its regression test (tests/test_ldbc_ic.py)."""
+    single source used by both the served-path check (chip_smoke.py)
+    and its regression test (tests/test_ldbc_ic.py)."""
     pr = ic_params(g)
     p_uid = hex(pr["p"])
     p2_uid = hex(pr["p2"])

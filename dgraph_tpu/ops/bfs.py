@@ -4,14 +4,12 @@ Reference parity: the reference serves concurrent queries with goroutines,
 each walking posting lists independently (worker/task.go, one goroutine per
 `ProcessTaskOverNetwork`; LDBC SNB IC mixes in BASELINE.json run many
 queries at once). The TPU-native equivalent batches B concurrent traversals
-into the *lanes* of a dense frontier bitmap:
+into the *lanes* of a dense frontier bitmap, word_bits lanes a mask word:
 
-    mask[n_nodes, B] int8      mask[v, q] = 1 iff node v is in query q's set
+    mask[n_nodes + 1, W] uint32    bit q % 32 of mask[v, q // 32] is set
+                                   iff node v is in query q's set
 
-One hop for ALL queries is two wide array ops over the COO edge list:
-
-    active  = mask[src]                  row-gather   [E, B]
-    next    = zeros.at[dst].max(active)  row-scatter  [N, B]
+One hop for ALL queries is next[v] = OR of mask[u] over the edges u→v.
 
 The point is access *width*: a random row gather on the v5e costs its
 access, not its bytes (6.2 ns a gather of an 8-byte mask row: 45 M of them
@@ -30,78 +28,23 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["ranks_to_bitmap", "bitmap_to_ranks", "bitmap_hop",
-           "bitmap_recurse", "EllGraph", "build_ell", "ell_recurse",
+__all__ = ["EllGraph", "build_ell", "ell_recurse",
            "DeviceEll", "device_ell", "prepare_parts", "make_ell_recurse",
            "out_csr", "push_caps",
-           "make_ell_step", "make_ell_count", "make_ell_tree",
+           "make_ell_step", "make_ell_tree",
            "pack_seed_masks", "unpack_masks"]
-
-
-def ranks_to_bitmap(rank_lists, n_nodes: int) -> jnp.ndarray:
-    """Host helper: B rank lists → [n_nodes, B] int8 frontier bitmap."""
-    import numpy as np
-    out = np.zeros((n_nodes, len(rank_lists)), np.int8)
-    for q, ranks in enumerate(rank_lists):
-        out[np.asarray(ranks, np.int64), q] = 1
-    return out
-
-
-def bitmap_to_ranks(mask) -> list:
-    """Host helper: [n_nodes, B] bitmap → list of B sorted rank arrays."""
-    import numpy as np
-    m = np.asarray(mask)
-    return [np.nonzero(m[:, q])[0].astype(np.int32)
-            for q in range(m.shape[1])]
-
-
-@jax.jit
-def bitmap_hop(src: jax.Array, dst: jax.Array, mask: jax.Array) -> jax.Array:
-    """One hop of B concurrent traversals: next[v,q] = OR over edges u→v of
-    mask[u,q]. `src`/`dst` are the COO edge list ([E] int32, any order)."""
-    active = jnp.take(mask, src, axis=0, mode="clip")
-    return jnp.zeros_like(mask).at[dst].max(active, mode="drop")
-
-
-@functools.partial(jax.jit, static_argnames=("depth",))
-def bitmap_recurse(src: jax.Array, dst: jax.Array, deg: jax.Array,
-                   mask0: jax.Array, depth: int):
-    """Depth-bounded loop=false @recurse for B queries at once, fully fused.
-
-    `deg[n_nodes] int32` is the out-degree vector (for edge counting);
-    `mask0[n_nodes, B] int8` holds each query's seed set. Returns
-    `(last[n,B], seen[n,B], edges[B] int32)` where `seen` is each query's
-    visited set (reference: expandRecurse's seen map per query) and
-    `edges[q]` counts edges traversed from every expanded frontier — the
-    north-star counter.
-    """
-    degf = deg.astype(jnp.float32)
-
-    def hop(carry, _):
-        frontier, seen, edges = carry
-        # per-query frontier out-degree sum — one MXU matvec
-        hop_edges = degf @ frontier.astype(jnp.float32)
-        edges = edges + hop_edges.astype(jnp.int32)
-        nxt = bitmap_hop(src, dst, frontier)
-        fresh = jnp.where(seen > 0, jnp.int8(0), nxt)
-        seen = jnp.maximum(seen, fresh)
-        return (fresh, seen, edges), None
-
-    B = mask0.shape[1]
-    (last, seen, edges), _ = lax.scan(
-        hop, (mask0, mask0, jnp.zeros((B,), jnp.int32)), None, length=depth)
-    return last, seen, edges
 
 
 # ---------------------------------------------------------------------------
 # ELL pull-hop: the access-amortised form of the batched traversal.
 #
-# The push kernel above pays one random row-gather AND one random
-# row-scatter per edge, and on the v5e the scatter is the dear one: XLA:TPU
-# applies a scatter's updates one after another, about 120 ns a slot for a
-# gather of the source's row and a scatter-max of 64 lane bytes (PR 30,
-# _push_hop: the same at 32 K, 64 K and 128 K slots a turn), against 6.2 ns
-# a gather; and it sorts the updates first once they number over 2^16 to
+# A hop over the COO edge list (gather mask[src], scatter-max into dst)
+# pays one random row-gather AND one random row-scatter per edge, and on
+# the v5e the scatter is the dear one: XLA:TPU applies a scatter's
+# updates one after another, about 120 ns a slot for a gather of the
+# source's row and a scatter-max of 64 lane bytes (PR 30, _push_hop: the
+# same at 32 K, 64 K and 128 K slots a turn), against 6.2 ns a gather;
+# and it sorts the updates first once they number over 2^16 to
 # 2^17, a sort that takes the compiler longer than the whole pull. So for a
 # frontier that covers the graph the winning shape is: (1) eliminate the
 # scatter entirely by pulling over in-neighbor lists, and (2) amortise each
@@ -287,7 +230,7 @@ def pack_seed_masks(g: EllGraph, rank_lists,
                     word_bits: int = 32) -> "jnp.ndarray":
     """B seed rank lists (OLD rank space) → [n+1, B/word_bits] packed mask
     in the permuted space, sentinel zero row last. B must be a multiple of
-    `word_bits` (32 for the serving default, 64 for the x64 bench path)."""
+    `word_bits` (32 for the serving default, 64 under jax.enable_x64)."""
     import numpy as np
     B = len(rank_lists)
     assert B % word_bits == 0, "lane count must pack into mask words"
@@ -529,24 +472,6 @@ def _count_mask(mask, outdeg_pad, n, W, word_bits):
     return out.astype(jnp.int32)
 
 
-def make_ell_count(outdeg, n: int, W: int, word_bits: int = 32):
-    """Compile the exact per-query edge counter over final masks:
-    edges[q] = Σ outdeg[v]·[v ∈ seen \\ last] — every frontier the run
-    expanded is exactly `seen` minus the never-expanded last fresh set,
-    so ONE matvec replaces the old per-hop accumulation (same integers,
-    depth× less unpack traffic)."""
-    nblk = -(-max(n, 1) // COUNT_BLK)
-    outdeg_pad = jnp.concatenate(
-        [jnp.asarray(outdeg),
-         jnp.zeros((nblk * COUNT_BLK - n,), jnp.float32)])
-
-    @jax.jit
-    def count(last, seen):
-        return _count_mask(seen & ~last, outdeg_pad, n, W, word_bits)
-
-    return count
-
-
 def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
                      count_edges: bool = True, word_bits: int = 32):
     """Compile a depth-parameterised loop=false @recurse over a DeviceEll
@@ -575,10 +500,9 @@ def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
         (last, seen), hops = lax.scan(
             hop, (mask0, mask0), None, length=depth)
         if count_edges:
-            # exact per-lane counters from the final masks (one matvec;
-            # see make_ell_count) — identical integers to the per-hop
-            # accumulation because first-visit frontiers partition
-            # seen \ last
+            # exact per-lane counters from the final masks (one matvec)
+            # — identical integers to the per-hop accumulation because
+            # first-visit frontiers partition seen \ last
             edges = _count_mask(seen & ~last, outdeg_pad, n, W, word_bits)
         else:
             edges = jnp.zeros((W * word_bits,), jnp.int32)

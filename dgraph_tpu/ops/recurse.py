@@ -1,41 +1,34 @@
-"""Fused multi-hop @recurse as ONE compiled single-device program.
+"""One visit-once @recurse hop for the whole-query fused program.
 
 Reference parity: `query/recurse.go` (expandRecurse) — the north-star
 workload. The reference's outer loop (re-seed SubGraph, re-run ProcessGraph
-per depth) becomes a `lax.scan` over hops, so an entire depth-k traversal is
-a single XLA program with zero host round-trips: each hop is gather →
-sort-unique → seen-set subtraction, all fused.
+per depth) becomes a `lax.scan` of this hop inside one compiled program
+(engine/fused.py), so an entire depth-k traversal has zero host
+round-trips: each hop is gather → filter → seen-set subtraction →
+sort-unique, all fused.
 
 TPU design note: the seen set is a dense int8 bitmap over rank space, not a
 sorted list — membership is one vectorised gather instead of the
-log2(n)-round binary search a sorted-set difference costs on TPU (measured
-~50× slower). The sorted-list form (`uidalgebra.difference_sorted`) remains
-for the small host-side paths.
+log2(n)-round binary search a sorted-set difference
+(`uidalgebra.difference_sorted`) costs on TPU (measured ~50× slower).
 
-The multi-device version (shard_map + collectives) lives in
-`parallel/dhop.py::recurse_fused`; this is its single-chip core and the
-kernel `bench.py` times on real TPU hardware.
+The multi-device form of the hop (shard_map + collectives) is
+`parallel/dhop.py chain_hop`.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax import lax
 
 from dgraph_tpu.ops.hop import gather_edges
-from dgraph_tpu.ops.uidalgebra import (
-    _member, compact_with_count, sentinel, sort_unique_count, valid_mask)
+from dgraph_tpu.ops.uidalgebra import _member, sentinel, sort_unique_count
 
 
 def masked_hop(indptr, indices, frontier, allowed, seen_mask,
                edge_cap: int, out_cap: int, use_allowed: bool):
     """One visit-once @recurse hop with the filter fused into the gather
     mask — the per-hop body of the whole-query fused program
-    (engine/fused.py): the single-device sibling of `recurse_frontier`'s
-    scan body that ALSO keeps the per-hop edge matrix (parents render)
+    (engine/fused.py). It keeps the per-hop edge matrix (parents render)
     and the filter's allowed-set membership test, so a filtered
     `@recurse` block compiles into one program instead of per-hop
     expand → filter → subtract host passes.
@@ -70,54 +63,3 @@ def masked_hop(indptr, indices, frontier, allowed, seen_mask,
     seen_mask = seen_mask.at[nxt].set(jnp.int8(1), mode="drop")
     return (m_nbrs[order], m_seg[order], n_kept, nxt, n_unique,
             seen_mask, total)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("edge_cap", "out_cap", "seen_cap", "depth"))
-def recurse_frontier(indptr: jax.Array, indices: jax.Array,
-                     frontier: jax.Array, edge_cap: int, out_cap: int,
-                     seen_cap: int, depth: int):
-    """Depth-bounded loop-free @recurse over one CSR relation, fully fused.
-
-    `frontier` must be sorted, sentinel-padded to exactly `out_cap` (it is
-    the per-hop frontier buffer carried through the scan). Returns
-    `(last_frontier[out_cap], seen[seen_cap], edges_traversed, needs[3])`
-    with `needs = [max frontier slots, n visited, max edge slots]` — results
-    are valid only if `needs <= [out_cap, seen_cap, edge_cap]` elementwise;
-    otherwise re-run with the caps `needs` asks for (the same overflow
-    contract as ops.hop.expand_frontier).
-    """
-    if frontier.shape[0] != out_cap:
-        raise ValueError(
-            f"frontier buffer {frontier.shape[0]} != out_cap {out_cap}")
-    n_nodes = indptr.shape[0] - 1
-
-    def mark(mask, uids):
-        # sentinel padding >= n_nodes, so mode="drop" discards it
-        return mask.at[uids].set(jnp.int8(1), mode="drop")
-
-    def hop(carry, _):
-        fr, seen_mask, edges, need_out, need_edge = carry
-        nbrs, _seg, _pos, _valid, total = gather_edges(
-            indptr, indices, fr, edge_cap)
-        merged, mcnt = sort_unique_count(nbrs, out_cap)
-        # loop=false: a node expands at most once — bitmap membership test
-        visited = jnp.take(seen_mask, jnp.clip(merged, 0, n_nodes - 1),
-                           mode="clip") > 0
-        keep = valid_mask(merged) & ~visited
-        fresh, _ = compact_with_count(merged, keep, out_cap)
-        seen_mask = mark(seen_mask, fresh)
-        return (fresh, seen_mask, edges + total,
-                jnp.maximum(need_out, mcnt),
-                jnp.maximum(need_edge, total)), None
-
-    seen0 = mark(jnp.zeros((n_nodes,), jnp.int8), frontier)
-    (last, seen_mask, edges, need_out, need_edge), _ = lax.scan(
-        hop, (frontier, seen0, jnp.int32(0), jnp.int32(0), jnp.int32(0)),
-        None, length=depth)
-
-    # materialise the visited set as a sorted padded uid list — iota is
-    # already ascending, so compaction alone suffices (no sort)
-    iota = jnp.arange(n_nodes, dtype=frontier.dtype)
-    seen, n_seen = compact_with_count(iota, seen_mask > 0, seen_cap)
-    return last, seen, edges, jnp.stack([need_out, n_seen, need_edge])

@@ -4,20 +4,25 @@ Reference parity: `worker/task.go ProcessTaskOverNetwork` — scatter the
 frontier to the groups owning each tablet over gRPC, each Alpha walks its
 posting lists, gather `pb.Result`s and k-way merge (`algo.MergeSorted`).
 Here the scatter/gather is XLA collectives over ICI inside a single jitted
-`shard_map` program:
+`shard_map` program. Every hop returns the edge matrix the engine renders
+from (engine/execute.py, engine/recurse.py):
 
-  scatter-gather hop  — frontier replicated; each device expands the rows
-      it owns; `all_gather` + fused sort-unique produce the merged next
-      frontier on every device. One collective per hop.
+  matrix hop / level  — frontier replicated; each device expands the rows
+      it owns (the level form also filters and paginates them); outputs
+      stay sharded.
 
-  ring hop            — frontier *sharded* (too big to replicate, the
+  ring matrix hop     — frontier *sharded* (too big to replicate, the
       long-context case of SURVEY §5); chunks rotate around the mesh via
       `ppermute` while every device expands the resident chunk against its
       local rows. D steps, each overlapping compute with a neighbour
       exchange — the structural cousin of ring attention.
 
-Edge totals are `psum`-reduced — the north-star edges-traversed/sec counter
-falls out of the kernel itself.
+  chain hop           — one visit-once @recurse hop whose replicated
+      (frontier, seen) outputs are the next launch's inputs; `all_gather`
+      + fused sort-unique merge the next frontier on every device.
+
+Edge totals are `psum`/`pmax`-reduced — the north-star edges-traversed/sec
+counter falls out of the kernel itself.
 """
 
 from __future__ import annotations
@@ -31,7 +36,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dgraph_tpu.ops.hop import gather_edges
 from dgraph_tpu.ops.uidalgebra import (
-    _member, difference_sorted, sentinel, sort_unique_count, valid_mask)
+    _member, sentinel, sort_unique_count, valid_mask)
 from dgraph_tpu.utils.jaxcompat import shard_map
 from dgraph_tpu.parallel.mesh import SHARD_AXIS, hop_input
 from dgraph_tpu.parallel.pshard import ShardedRel
@@ -46,53 +51,6 @@ def _local_expand_full(indptr, indices, row_lo, frontier, edge_cap):
             & (frontier < row_lo + n_rows))
     local_f = jnp.where(mine, frontier - row_lo, sentinel(frontier.dtype))
     return gather_edges(indptr, indices, local_f, edge_cap)
-
-
-def _local_expand(indptr, indices, row_lo, frontier, edge_cap):
-    nbrs, _seg, _pos, _valid, total = _local_expand_full(
-        indptr, indices, row_lo, frontier, edge_cap)
-    return nbrs, total
-
-
-@functools.lru_cache(maxsize=64)
-def _build_sg_hop(mesh: Mesh, edge_cap: int, out_cap: int):
-    def per_device(indptr_b, indices_b, row_lo_b, frontier):
-        nbrs, total = _local_expand(
-            indptr_b[0], indices_b[0], row_lo_b[0], frontier, edge_cap)
-        local, local_cnt = sort_unique_count(nbrs, out_cap)
-        total_all = lax.psum(total, SHARD_AXIS)
-        # Overflow witnesses survive the reductions: if any shard needed
-        # more than edge_cap slots or out_cap uniques, the max carries it.
-        max_shard_edges = lax.pmax(total, SHARD_AXIS)
-        gathered = lax.all_gather(local, SHARD_AXIS)  # [D, out_cap]
-        merged, count = sort_unique_count(gathered.reshape(-1), out_cap)
-        count = jnp.maximum(count, lax.pmax(local_cnt, SHARD_AXIS))
-        return merged, count, total_all, max_shard_edges
-
-    fn = shard_map(
-        per_device, mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P()),
-        out_specs=(P(), P(), P(), P()),
-        check_vma=False,
-    )
-    return jax.jit(fn)
-
-
-def scatter_gather_hop(mesh: Mesh, rel: ShardedRel, frontier: jax.Array,
-                       edge_cap: int, out_cap: int):
-    """One hop with a replicated frontier.
-
-    Returns `(next_frontier[out_cap], n_unique, edges_traversed,
-    max_shard_edges)` — all replicated. Overflow contract (same as
-    ops.hop): results are valid only if `n_unique <= out_cap` AND
-    `max_shard_edges <= edge_cap`; otherwise re-run at the next bucket
-    size. `n_unique` is inflated to the largest per-shard union size so
-    per-shard truncation cannot hide below a merged count of exactly
-    out_cap.
-    """
-    return _build_sg_hop(mesh, edge_cap, out_cap)(
-        rel.indptr_s, rel.indices_s, rel.row_lo,
-        hop_input(frontier, mesh))
 
 
 @functools.lru_cache(maxsize=64)
@@ -184,63 +142,6 @@ def matrix_level(mesh: Mesh, rel: ShardedRel, frontier: jax.Array,
 
 
 @functools.lru_cache(maxsize=64)
-def _build_ring_hop(mesh: Mesh, edge_cap: int, out_cap: int):
-    n_dev = mesh.devices.size
-    perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
-
-    def per_device(indptr_b, indices_b, row_lo_b, chunk_b):
-        indptr, indices, row_lo = indptr_b[0], indices_b[0], row_lo_b[0]
-        chunk = chunk_b[0]
-        acc = jnp.full((out_cap,), sentinel(chunk.dtype), chunk.dtype)
-
-        def step(i, carry):
-            chunk, acc, total, need, max_step_edges = carry
-            nbrs, t = _local_expand(indptr, indices, row_lo, chunk, edge_cap)
-            # Fold this step's neighbours into the running local union,
-            # remembering the largest size the union ever *needed*.
-            acc, cnt = sort_unique_count(jnp.concatenate([acc, nbrs]), out_cap)
-            chunk = lax.ppermute(chunk, SHARD_AXIS, perm)
-            return (chunk, acc, total + t, jnp.maximum(need, cnt),
-                    jnp.maximum(max_step_edges, t))
-
-        _, acc, total, need, max_step_edges = lax.fori_loop(
-            0, n_dev, step,
-            (chunk, acc, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
-        total_all = lax.psum(total, SHARD_AXIS)
-        max_edges = lax.pmax(max_step_edges, SHARD_AXIS)
-        gathered = lax.all_gather(acc, SHARD_AXIS)
-        merged, count = sort_unique_count(gathered.reshape(-1), out_cap)
-        count = jnp.maximum(count, lax.pmax(need, SHARD_AXIS))
-        return acc[None], merged, count, total_all, max_edges
-
-    fn = shard_map(
-        per_device, mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
-        out_specs=(P(SHARD_AXIS), P(), P(), P(), P()),
-        check_vma=False,
-    )
-    return jax.jit(fn)
-
-
-def ring_hop(mesh: Mesh, rel: ShardedRel, frontier_chunks: jax.Array,
-             edge_cap: int, out_cap: int):
-    """One hop with a SHARDED frontier rotating ring-wise over the mesh.
-
-    `frontier_chunks` is [D, f_cap] (see pshard.shard_frontier). Returns
-    `(local_unions[D, out_cap], merged[out_cap], n_unique, edges,
-    max_step_edges)` where `local_unions` stays sharded for pipelined
-    multi-hop chains and `merged` is the replicated deduped next frontier.
-    Results are valid only if `n_unique <= out_cap` AND
-    `max_step_edges <= edge_cap` (n_unique is inflated to the largest size
-    any device's running union ever needed, so mid-ring truncation is
-    always visible).
-    """
-    return _build_ring_hop(mesh, edge_cap, out_cap)(
-        rel.indptr_s, rel.indices_s, rel.row_lo,
-        hop_input(frontier_chunks, mesh, P(SHARD_AXIS)))
-
-
-@functools.lru_cache(maxsize=64)
 def _build_ring_matrix(mesh: Mesh, edge_cap: int, f_cap: int):
     n_dev = mesh.devices.size
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
@@ -303,161 +204,15 @@ def ring_matrix_hop(mesh: Mesh, rel: ShardedRel, frontier_chunks,
 
 
 @functools.lru_cache(maxsize=64)
-def _build_recurse(mesh: Mesh, edge_cap: int, out_cap: int, seen_cap: int,
-                   depth: int):
-    """Whole multi-hop @recurse as ONE compiled program (frontier loop in
-    lax.scan, not Python) — the reference's expandRecurse outer loop
-    (query/recurse.go) with zero host round-trips between hops."""
-
-    def per_device(indptr_b, indices_b, row_lo_b, frontier):
-        indptr, indices, row_lo = indptr_b[0], indices_b[0], row_lo_b[0]
-
-        def hop(carry, _):
-            frontier, seen, edges, need_out, need_seen, need_edge = carry
-            nbrs, t = _local_expand(indptr, indices, row_lo, frontier, edge_cap)
-            local, local_cnt = sort_unique_count(nbrs, out_cap)
-            gathered = lax.all_gather(local, SHARD_AXIS)
-            merged, mcnt = sort_unique_count(gathered.reshape(-1), out_cap)
-            # loop=false semantics: drop uids already visited (reference
-            # keeps a `seen` map; here a sorted-set difference).
-            fresh = difference_sorted(merged, seen)
-            seen, scnt = sort_unique_count(
-                jnp.concatenate([seen, fresh]), seen_cap)
-            need_out = jnp.maximum(
-                need_out, jnp.maximum(mcnt, lax.pmax(local_cnt, SHARD_AXIS)))
-            need_seen = jnp.maximum(need_seen, scnt)
-            need_edge = jnp.maximum(need_edge, lax.pmax(t, SHARD_AXIS))
-            return (fresh, seen, edges + lax.psum(t, SHARD_AXIS),
-                    need_out, need_seen, need_edge), None
-
-        seen0, scnt0 = sort_unique_count(frontier, seen_cap)
-        (last, seen, edges, need_out, need_seen, need_edge), _ = lax.scan(
-            hop, (frontier, seen0, jnp.int32(0), jnp.int32(0), scnt0,
-                  jnp.int32(0)),
-            None, length=depth)
-        # needs[i] > the corresponding cap ⇒ truncation happened somewhere.
-        needs = jnp.stack([need_out, need_seen, need_edge])
-        return last, seen, edges, needs
-
-    fn = shard_map(
-        per_device, mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P()),
-        out_specs=(P(), P(), P(), P()),
-        check_vma=False,
-    )
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=64)
-def _build_recurse_matrix(mesh: Mesh, edge_cap: int, out_cap: int,
-                          seen_cap: int, depth: int):
-    """recurse_fused plus per-hop edge-matrix capture: the variant the DQL
-    engine drives, because JSON rendering needs every (parent, child) edge,
-    not just the frontier (reference: expandRecurse keeps each level's
-    UidMatrix for outputnode)."""
-
-    def per_device(indptr_b, indices_b, row_lo_b, frontier):
-        indptr, indices, row_lo = indptr_b[0], indices_b[0], row_lo_b[0]
-        n_rows = indptr.shape[0] - 1
-        snt = sentinel(frontier.dtype)
-
-        def hop(carry, _):
-            frontier, seen, edges, need_out, need_seen, need_edge = carry
-            mine = (valid_mask(frontier) & (frontier >= row_lo)
-                    & (frontier < row_lo + n_rows))
-            local_f = jnp.where(mine, frontier - row_lo, snt)
-            nbrs, seg, edge_pos, valid, t = gather_edges(
-                indptr, indices, local_f, edge_cap)
-            # visit-once: drop edges to nodes seen BEFORE this hop (edges
-            # between two nodes first reached in the same hop are kept —
-            # matching the host loop's first-visit-tree semantics)
-            keep = valid & ~_member(nbrs, seen)
-            m_nbrs = jnp.where(keep, nbrs, snt)
-            m_seg = jnp.where(keep, seg, jnp.int32(-1))
-            local, local_cnt = sort_unique_count(m_nbrs, out_cap)
-            gathered = lax.all_gather(local, SHARD_AXIS)
-            fresh, mcnt = sort_unique_count(gathered.reshape(-1), out_cap)
-            seen2, scnt = sort_unique_count(
-                jnp.concatenate([seen, fresh]), seen_cap)
-            need_out = jnp.maximum(
-                need_out, jnp.maximum(mcnt, lax.pmax(local_cnt, SHARD_AXIS)))
-            need_seen = jnp.maximum(need_seen, scnt)
-            need_edge = jnp.maximum(need_edge, lax.pmax(t, SHARD_AXIS))
-            carry = (fresh, seen2, edges + lax.psum(t, SHARD_AXIS),
-                     need_out, need_seen, need_edge)
-            return carry, (m_nbrs, m_seg, edge_pos, frontier)
-
-        seen0, scnt0 = sort_unique_count(frontier, seen_cap)
-        (last, seen, edges, need_out, need_seen, need_edge), ys = lax.scan(
-            hop, (frontier, seen0, jnp.int32(0), jnp.int32(0), scnt0,
-                  jnp.int32(0)),
-            None, length=depth)
-        needs = jnp.stack([need_out, need_seen, need_edge])
-        ys_nbrs, ys_seg, ys_pos, ys_frontier = ys
-        return (last, seen, edges, needs,
-                ys_nbrs[None], ys_seg[None], ys_pos[None], ys_frontier)
-
-    fn = shard_map(
-        per_device, mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P()),
-        out_specs=(P(), P(), P(), P(),
-                   P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P()),
-        check_vma=False,
-    )
-    return jax.jit(fn)
-
-
-def recurse_fused_matrix(mesh: Mesh, rel: ShardedRel, frontier: jax.Array,
-                         edge_cap: int, out_cap: int, seen_cap: int,
-                         depth: int):
-    """Depth-bounded @recurse over one predicate as ONE compiled SPMD
-    program, returning the per-hop edge matrices the engine renders from:
-
-      (last_frontier[out_cap], seen[seen_cap], edges, needs[3],
-       nbrs[D, depth, edge_cap], seg[D, depth, edge_cap],
-       pos[D, depth, edge_cap], frontiers[depth, out_cap])
-
-    For hop h on shard d: slots with nbrs != sentinel are surviving edges
-    (visit-once filtered); seg indexes frontiers[h] (the hop's replicated
-    input frontier); pos + rel.pos_lo[d] is the absolute facet position.
-    Same overflow contract as recurse_fused: valid only if
-    needs <= [out_cap, seen_cap, edge_cap]."""
-    if frontier.shape[0] != out_cap:
-        raise ValueError(
-            f"frontier buffer {frontier.shape[0]} != out_cap {out_cap}")
-    return _build_recurse_matrix(mesh, edge_cap, out_cap, seen_cap, depth)(
-        rel.indptr_s, rel.indices_s, rel.row_lo, frontier)
-
-
-def recurse_fused(mesh: Mesh, rel: ShardedRel, frontier: jax.Array,
-                  edge_cap: int, out_cap: int, seen_cap: int, depth: int):
-    """Depth-bounded @recurse over one predicate, fully fused on-mesh.
-
-    `frontier` must be sorted, sentinel-padded to exactly `out_cap` (the
-    per-hop frontier buffer); `seen_cap` bounds the whole reachable set.
-    Returns `(last_frontier, seen[seen_cap], edges_traversed, needs[3])`
-    where `needs = [max frontier slots, max seen slots, max per-shard
-    edge slots]` any hop required — results are valid only if
-    `needs <= [out_cap, seen_cap, edge_cap]` elementwise; otherwise
-    re-run with the caps `needs` asks for.
-    """
-    if frontier.shape[0] != out_cap:
-        raise ValueError(f"frontier buffer {frontier.shape[0]} != out_cap {out_cap}")
-    return _build_recurse(mesh, edge_cap, out_cap, seen_cap, depth)(
-        rel.indptr_s, rel.indices_s, rel.row_lo, frontier)
-
-
-@functools.lru_cache(maxsize=64)
 def _build_chain_hop(mesh: Mesh, edge_cap: int, out_cap: int,
                      seen_cap: int):
     """ONE visit-once hop with edge-matrix capture, compiled so its
     replicated (frontier, seen) outputs are EXACTLY the next launch's
     replicated inputs — the reshard-free multi-hop building block. One
-    compiled program serves every depth (the lax.scan variants above
-    retrace per depth), and between launches the frontier/seen arrays
-    stay device-resident: the host reads their VALUES for rendering but
-    feeds the same jax.Arrays back in, so no bytes re-cross the mesh
-    (mesh.hop_input counts any violation)."""
+    compiled program serves every depth, and between launches the
+    frontier/seen arrays stay device-resident: the host reads their
+    VALUES for rendering but feeds the same jax.Arrays back in, so no
+    bytes re-cross the mesh (mesh.hop_input counts any violation)."""
 
     def per_device(indptr_b, indices_b, row_lo_b, frontier, seen):
         indptr, indices, row_lo = indptr_b[0], indices_b[0], row_lo_b[0]
@@ -470,7 +225,7 @@ def _build_chain_hop(mesh: Mesh, edge_cap: int, out_cap: int,
             indptr, indices, local_f, edge_cap)
         # visit-once: drop edges to nodes seen BEFORE this hop (edges
         # between two same-hop discoveries are kept — the host loop's
-        # first-visit-tree semantics, identical to recurse_fused_matrix)
+        # first-visit-tree semantics)
         keep = valid & ~_member(nbrs, seen)
         m_nbrs = jnp.where(keep, nbrs, snt)
         m_seg = jnp.where(keep, seg, jnp.int32(-1))
@@ -510,8 +265,10 @@ def chain_hop(mesh: Mesh, rel: ShardedRel, frontier, seen,
     hop's input frontier; per shard d the slots with nbrs != sentinel
     are its surviving (visit-once filtered) edges in CSR row order;
     `shard_edges[d]` is the raw edges shard d expanded (the balance /
-    per-shard cost signal). Overflow contract of recurse_fused: results
-    valid only if needs <= [out_cap, seen_cap, edge_cap]."""
+    per-shard cost signal). `needs` = [max frontier slots, seen slots,
+    max per-shard edge slots] the hop required: results are valid only
+    if needs <= [out_cap, seen_cap, edge_cap] elementwise; otherwise
+    re-run with the caps `needs` asks for."""
     return _build_chain_hop(mesh, edge_cap, out_cap, seen_cap)(
         rel.indptr_s, rel.indices_s, rel.row_lo,
         hop_input(frontier, mesh), hop_input(seen, mesh))

@@ -31,9 +31,9 @@ not (`cost_us=None`):
     request cheaper than the most expensive queued waiter DISPLACES it
     (the expensive waiter is shed, `shed_total{reason="displaced"}`)
     instead of being refused itself — sheds land on the work that was
-    going to blow the deadline anyway (shed precision, measured by the
-    bench "sched" stage). Every cost-informed shed records its
-    predicted cost (`shed_predicted_cost_us`).
+    going to blow the deadline anyway (shed precision, measured by
+    tests/test_costprior.py run_sched_workload). Every cost-informed
+    shed records its predicted cost (`shed_predicted_cost_us`).
 
 Queued waiters respect the request's deadline: a request whose budget
 expires while waiting is shed (`shed_total{reason="deadline"}`) instead

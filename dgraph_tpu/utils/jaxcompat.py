@@ -12,7 +12,7 @@ Two choke points live here:
 
 * `enable_compile_cache` — the ONLY place that sets
   `jax_compilation_cache_dir`. Every process that compiles (the alpha
-  server, the bench children, chip_smoke.py's JAX children) calls it
+  server, chip_smoke.py's JAX children) calls it
   before first use, so a restarted server and a second benchmark run
   find what the first one compiled.
 """

@@ -13,14 +13,7 @@ import pytest
 
 from dgraph_tpu.models.synthetic import powerlaw_rel, uniform_rel
 from dgraph_tpu.ops.bfs import (
-    bitmap_hop, bitmap_recurse, bitmap_to_ranks, ranks_to_bitmap)
-
-
-def coo_of(rel):
-    n = rel.indptr.shape[0] - 1
-    deg = (rel.indptr[1:] - rel.indptr[:-1]).astype(np.int64)
-    src = np.repeat(np.arange(n, dtype=np.int32), deg)
-    return src, rel.indices.astype(np.int32), (rel.indptr[1:] - rel.indptr[:-1]).astype(np.int32)
+    build_ell, ell_recurse, pack_seed_masks, unpack_masks)
 
 
 def oracle_recurse(rel, seeds, depth):
@@ -38,18 +31,26 @@ def oracle_recurse(rel, seeds, depth):
     return frontier, seen, edges
 
 
+def lanes_recurse(rel, seed_lists, depth):
+    """ell_recurse over the seed lists, padded to a whole mask word with
+    empty lanes. Returns (g, last, seen, edges), the masks as the device
+    left them."""
+    g = build_ell(rel.indptr, rel.indices)
+    lanes = list(seed_lists) + [[]] * (-len(seed_lists) % 32)
+    last, seen, edges = ell_recurse(g, pack_seed_masks(g, lanes), depth)
+    return g, np.asarray(last), np.asarray(seen), np.asarray(edges)
+
+
 @pytest.mark.parametrize("maker,n,deg", [(powerlaw_rel, 300, 3.0),
                                          (uniform_rel, 200, 4)])
-def test_bitmap_recurse_matches_oracle(maker, n, deg):
+def test_ell_recurse_matches_oracle(maker, n, deg):
     rel = maker(n, deg, 3)
-    src, dst, degv = coo_of(rel)
     rng = np.random.default_rng(0)
     B = 8
     seed_lists = [rng.integers(0, n, rng.integers(1, 6)) for _ in range(B)]
-    mask0 = ranks_to_bitmap(seed_lists, n)
 
-    last, seen, edges = bitmap_recurse(src, dst, degv, mask0, depth=3)
-    last_l, seen_l = bitmap_to_ranks(last), bitmap_to_ranks(seen)
+    g, last, seen, edges = lanes_recurse(rel, seed_lists, 3)
+    last_l, seen_l = unpack_masks(g, last), unpack_masks(g, seen)
     for q in range(B):
         of, os_, oe = oracle_recurse(rel, seed_lists[q], 3)
         assert np.array_equal(last_l[q], of), f"query {q} frontier"
@@ -57,62 +58,47 @@ def test_bitmap_recurse_matches_oracle(maker, n, deg):
         assert int(edges[q]) == oe, f"query {q} edges"
 
 
-def test_bitmap_hop_single():
+def test_ell_hop_single():
     rel = uniform_rel(64, 2, 1)
-    src, dst, _ = coo_of(rel)
-    mask0 = ranks_to_bitmap([[0, 5]], 64)
-    nxt = np.asarray(bitmap_hop(src, dst, mask0))
+    g, last, seen, _edges = lanes_recurse(rel, [[0, 5]], 1)
     want = np.unique(np.concatenate([rel.row(0), rel.row(5)]))
-    assert np.array_equal(np.nonzero(nxt[:, 0])[0], want)
+    # one hop's fresh set is the neighbour set less the seeds themselves
+    assert np.array_equal(unpack_masks(g, last)[0],
+                          np.setdiff1d(want, [0, 5]))
+    assert np.array_equal(unpack_masks(g, seen)[0], np.union1d(want, [0, 5]))
 
 
 def test_empty_seed_lane():
     rel = uniform_rel(32, 2, 5)
-    src, dst, degv = coo_of(rel)
-    mask0 = ranks_to_bitmap([[], [3]], 32)
-    last, seen, edges = bitmap_recurse(src, dst, degv, mask0, depth=2)
+    g, _last, seen, edges = lanes_recurse(rel, [[], [3]], 2)
     assert int(edges[0]) == 0
-    assert not np.asarray(seen)[:, 0].any()
+    assert not len(unpack_masks(g, seen)[0])
+    assert int(edges[1]) == oracle_recurse(rel, [3], 2)[2]
 
 
 class TestEllRecurse:
-    """ELL pull kernel == push kernel == numpy walk (identical useful-edge
-    counts and visited sets)."""
+    """ELL pull kernel == numpy walk (identical useful-edge counts and
+    visited sets)."""
 
     def _graph(self, n=512, avg=6.0, seed=3):
         from dgraph_tpu.models.synthetic import powerlaw_rel
         return powerlaw_rel(n, avg, seed=seed)
 
     def test_matches_push_kernel_and_numpy(self):
-        import numpy as np
-        from dgraph_tpu.ops.bfs import (
-            bitmap_recurse, build_ell, ell_recurse, pack_seed_masks,
-            ranks_to_bitmap, unpack_masks)
-
         rel = self._graph()
         n = rel.indptr.shape[0] - 1
         rng = np.random.default_rng(11)
         B = 64
         seeds = [rng.integers(0, n, 3) for _ in range(B)]
 
-        g = build_ell(rel.indptr, rel.indices)
+        g, _last, seen, edges = lanes_recurse(rel, seeds, 3)
         assert g.nnz == rel.nnz
-        mask0 = pack_seed_masks(g, seeds)
-        last, seen, edges = ell_recurse(g, mask0, depth=3)
-
-        deg = (rel.indptr[1:] - rel.indptr[:-1]).astype(np.int32)
-        src = np.repeat(np.arange(n, dtype=np.int32), deg)
-        pm0 = ranks_to_bitmap(seeds, n)
-        _pl, pseen, pedges = bitmap_recurse(
-            jnp_put(src), jnp_put(rel.indices), jnp_put(deg),
-            jnp_put(pm0), depth=3)
-        assert np.array_equal(np.asarray(edges), np.asarray(pedges))
+        want = [oracle_recurse(rel, s, 3) for s in seeds]
+        assert np.array_equal(edges, [w[2] for w in want])
 
         seen_lists = unpack_masks(g, seen)
-        pseen = np.asarray(pseen)
         for q in range(0, B, 7):
-            want = np.nonzero(pseen[:, q])[0]
-            assert np.array_equal(seen_lists[q], want.astype(np.int32))
+            assert np.array_equal(seen_lists[q], want[q][1].astype(np.int32))
 
     def test_single_query_deep(self):
         import numpy as np
@@ -142,11 +128,6 @@ class TestEllRecurse:
             seen_np |= nxt
         assert int(np.asarray(edges)[0]) == total
         assert list(unpack_masks(g, seen)[0]) == sorted(seen_np)
-
-
-def jnp_put(x):
-    import jax
-    return jax.device_put(x)
 
 
 class TestSegmentCsr:
@@ -229,12 +210,12 @@ class TestSegmentCsr:
         assert g.padded_edges < 1.25 * g.nnz
 
     def test_u64_words_match_u32(self):
-        """uint64 lane words (the x64 bench path) produce bit-identical
-        traversals to the uint32 default."""
+        """uint64 lane words produce bit-identical traversals and edge
+        counts to the uint32 default."""
         import jax
 
         from dgraph_tpu.ops.bfs import (build_ell, device_ell,
-                                        make_ell_count, make_ell_recurse,
+                                        make_ell_recurse,
                                         pack_seed_masks, unpack_masks)
         rel = powerlaw_rel(300, 6.0, seed=7)
         n = rel.indptr.shape[0] - 1
@@ -247,11 +228,9 @@ class TestSegmentCsr:
             m64 = pack_seed_masks(g, seeds, word_bits=64)
             dev = device_ell(g)
             fn = make_ell_recurse(dev, g.outdeg, g.n, m64.shape[1],
-                                  count_edges=False, word_bits=64)
-            last64, seen64, _e = fn(jax.device_put(m64), 3)
-            cnt = make_ell_count(g.outdeg, g.n, m64.shape[1],
-                                 word_bits=64)
-            edges64 = np.asarray(cnt(last64, seen64))
+                                  count_edges=True, word_bits=64)
+            _last64, seen64, edges64 = fn(jax.device_put(m64), 3)
+            edges64 = np.asarray(edges64)
             s64 = unpack_masks(g, np.asarray(seen64), word_bits=64)
         s32 = unpack_masks(g, np.asarray(seen32), word_bits=32)
         assert np.array_equal(np.asarray(edges32), edges64)

@@ -15,7 +15,6 @@ import urllib.request
 import numpy as np
 import pytest
 
-import bench
 from dgraph_tpu.server.admission import AdmissionController, ServerOverloaded
 from dgraph_tpu.server.api import Alpha
 from dgraph_tpu.store import StoreBuilder, parse_schema
@@ -286,14 +285,142 @@ def test_idle_lane_ema_decays_to_seed():
 # ---------------------------------------------------------------------------
 # acceptance: priors-on beats priors-off (fixed seed), /debug/scheduler
 
+def run_sched_workload(priors_on: bool, chain_n: int = 2000,
+                       n_expensive: int = 3, n_cheap: int = 6,
+                       queue_depth: int = 4, seed: int = 23) -> dict:
+    """Mixed cheap/expensive serving under admission pressure — the
+    cost-prior A/B harness of the acceptance test below.
+
+    One token, a bounded queue: an EXPENSIVE query (shortest-path grind
+    over a `chain_n` uid chain hunting an unreachable island) holds the
+    token while more expensive queries queue; CHEAP name lookups then
+    arrive. With priors OFF the cheap arrivals queue FIFO behind the
+    expensive ones or get shed at the full queue (sheds land on cheap
+    work). With priors ON the scheduler predicts each arrival's cost
+    from its warmed shape prior: cheap queries displace queued
+    expensive ones (sheds land on the expensive work) and drain first
+    (SJF handoff). Reports cheap p50/p99 µs over COMPLETED cheap
+    queries, shed counts by kind, and shed precision = expensive sheds
+    / total sheds."""
+    import threading as _threading
+
+    from dgraph_tpu.server.admission import ServerOverloaded
+    from dgraph_tpu.server.api import Alpha
+    from dgraph_tpu.store import StoreBuilder, parse_schema
+    from dgraph_tpu.utils import costprior, costprofile
+
+    costprior.reset()
+    costprofile.reset()
+    floor0 = costprior.PRIORS.sample_floor
+    costprior.PRIORS.sample_floor = 2  # 2 warm runs arm a prior
+    try:
+        b = StoreBuilder(parse_schema(
+            "link: [uid] @reverse .\nname: string @index(exact) ."))
+        uids = np.arange(1, chain_n, dtype=np.int64)
+        b.add_edges("link", uids, uids + 1)
+        for i in range(1, 65):
+            b.add_value(i, "name", f"p{i}")
+        b.add_value(chain_n + 5, "name", "island")  # unreachable
+        alpha = Alpha(base=b.finalize(), device_threshold=10**9)
+        alpha.cost_priors = priors_on
+
+        exp_q = ("{ path as shortest(from: 0x1, to: 0x%x, depth: %d) "
+                 "{ link } }" % (chain_n + 5, chain_n))
+        rng = np.random.default_rng(seed)
+        cheap_qs = ['{ q(func: eq(name, "p%d")) { name } }' % i
+                    for i in rng.integers(1, 65, n_cheap)]
+
+        # warm uncontended: parse caches + (priors on) text→shape memo
+        # and per-shape priors past the (lowered) sample floor
+        for _ in range(2):
+            alpha.query(exp_q)
+            for q in cheap_qs:
+                alpha.query(q)
+
+        adm = alpha.attach_admission(max_inflight=1,
+                                     queue_depth=queue_depth)
+        results = {"cheap_us": [], "shed": {"cheap": 0, "expensive": 0},
+                   "ok": {"cheap": 0, "expensive": 0}}
+        lock = _threading.Lock()
+
+        def run(q: str, kind: str):
+            t0 = time.perf_counter()
+            try:
+                alpha.query(q)
+                us = (time.perf_counter() - t0) * 1e6
+                with lock:
+                    results["ok"][kind] += 1
+                    if kind == "cheap":
+                        results["cheap_us"].append(us)
+            except ServerOverloaded:
+                with lock:
+                    results["shed"][kind] += 1
+
+        threads = []
+
+        def submit(q, kind):
+            t = _threading.Thread(target=run, args=(q, kind))
+            t.start()
+            threads.append(t)
+
+        def wait_for(pred, timeout=10.0):
+            end = time.monotonic() + timeout
+            while time.monotonic() < end:
+                if pred():
+                    return True
+                time.sleep(0.002)
+            return False
+
+        lane = adm.lanes["read"]
+
+        def lane_state():
+            # under the lane lock: request threads mutate these and the
+            # race sanitizer (rightly) convicts an unlocked poll
+            with lane.lock:
+                return lane.inflight, len(lane.waiters)
+
+        submit(exp_q, "expensive")
+        wait_for(lambda: lane_state()[0] >= 1)
+        for _ in range(n_expensive - 1):
+            submit(exp_q, "expensive")
+        wait_for(lambda: lane_state()[1] >= n_expensive - 1)
+        for q in cheap_qs:
+            submit(q, "cheap")
+            time.sleep(0.01)
+        for t in threads:
+            t.join(60)
+
+        lats = sorted(results["cheap_us"])
+        sheds = results["shed"]["cheap"] + results["shed"]["expensive"]
+        out = {
+            "priors": priors_on,
+            "cheap_completed": len(lats),
+            "cheap_p50_us": round(lats[len(lats) // 2]) if lats else 0,
+            "cheap_p99_us": round(lats[min(len(lats) - 1,
+                                           int(len(lats) * 0.99))])
+            if lats else 0,
+            "shed_cheap": results["shed"]["cheap"],
+            "shed_expensive": results["shed"]["expensive"],
+            "shed_precision": (results["shed"]["expensive"] / sheds
+                               if sheds else None),
+            "expensive_ok": results["ok"]["expensive"],
+        }
+        if priors_on:
+            st = costprior.status()
+            out["prior"] = {"hits": st["hits"],
+                            "fallbacks": st["fallbacks"],
+                            "error": st["error"]}
+        return out
+    finally:
+        costprior.PRIORS.sample_floor = floor0
+
+
 def test_sched_acceptance_priors_on_beats_off():
     """ISSUE 9 acceptance: on the mixed cheap/expensive workload
-    (bench.run_sched_workload, fixed seed), priors-on beats priors-off
+    (run_sched_workload, fixed seed), priors-on beats priors-off
     on BOTH cheap-query p99 and shed precision."""
-    off = bench.run_sched_workload(priors_on=False, chain_n=1500,
-                                   seed=23)
-    on = bench.run_sched_workload(priors_on=True, chain_n=1500,
-                                  seed=23)
+    off = run_sched_workload(priors_on=False, chain_n=1500, seed=23)
+    on = run_sched_workload(priors_on=True, chain_n=1500, seed=23)
     assert on["cheap_completed"] >= off["cheap_completed"]
     assert on["cheap_p99_us"] < off["cheap_p99_us"], (on, off)
     off_prec = off["shed_precision"] or 0.0

@@ -484,7 +484,7 @@ def test_mesh_msgpass_bit_identical_on_4_virtual_devices(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# inventory + compare-gate satellites
+# inventory satellite
 
 def test_fused_inventory_carries_five_stage_kinds():
     from dgraph_tpu.engine.fused import _STAGE_EMITTERS, STAGE_KINDS
@@ -492,14 +492,3 @@ def test_fused_inventory_carries_five_stage_kinds():
     assert "featprop" in STAGE_KINDS
     # both-ways pin mirrors test_lint's facts discipline
     assert set(STAGE_KINDS) == set(_STAGE_EMITTERS)
-
-
-def test_compare_gate_watches_feature_bytes_per_s():
-    from dgraph_tpu.analysis import compare
-    assert compare.direction(
-        "stages.featprop.feature_bytes_per_s") == "higher"
-    old = {"featprop": {"feature_bytes_per_s": 1000.0}}
-    new = {"featprop": {"feature_bytes_per_s": 500.0}}
-    rows = compare.compare(old, new, threshold=0.10)
-    assert rows and rows[0]["regressed"]
-    assert rows[0]["direction"] == "higher"

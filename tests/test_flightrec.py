@@ -223,10 +223,9 @@ def test_deadline_requests_judged_only_against_their_budget(tmp_path):
 
 
 def test_explicit_budget_track_convicts_like_bench_stage(tmp_path):
-    """bench.py's shape: track(name, budget_s=...) — a stage wedged
-    past its deadline is convicted as `wedged` and the on_dump hook
-    observes the bundle record (how a wedged stage's bundle path
-    reaches BENCH JSON)."""
+    """track(name, budget_s=...) — a stage wedged past its deadline is
+    convicted as `wedged` and the on_dump hook observes the bundle
+    record."""
     seen = []
     flightrec.arm(diag_dir=str(tmp_path), poll_s=0.02, grace_s=0.02,
                   min_dump_interval_s=60.0,

@@ -10,6 +10,7 @@ synthetic-fixture tests pin each rule's detection and the waiver
 grammar so a refactor of the analyzer can't silently blind a rule.
 """
 
+import ast
 import functools
 import json
 import pathlib
@@ -50,7 +51,7 @@ def rules_of(a: Analyzer, waived: bool = False) -> set[str]:
 
 def test_package_has_zero_unwaived_findings():
     """THE build gate: `python -m dgraph_tpu.analysis` over the whole
-    package + bench.py must be clean. Fix the finding or waive it with
+    package must be clean. Fix the finding or waive it with
     `# graftlint: allow(<rule>): <reason>` — the failure message below
     is exactly the analyzer's own report."""
     a = run(ROOT)
@@ -90,9 +91,61 @@ def test_facts_inventory_shapes():
     assert t["span_names"] >= 15
     assert t["lock_classes"] >= 15
     names = {k["name"] for k in a.facts["kernels"]}
-    assert {"bitmap_hop", "bitmap_recurse"} <= names
+    assert {"gather_edges", "step"} <= names  # masked_hop's gather, the lane step
     ladder = {x["name"] for x in a.facts["lock_classes"]}
     assert {"metrics.registry", "mvcc.store", "wal.write"} <= ladder
+
+
+def _entry_names(tree, line: int) -> set[str]:
+    """The names a caller could reach the jitted function at `line` by:
+    the top-level def that holds it, and every top-level def of the same
+    file that (transitively) names one of those."""
+    tops = [n for n in tree.body
+            if isinstance(n, (ast.FunctionDef, ast.ClassDef))]
+    names = {next(n.name for n in tops if n.lineno <= line <= n.end_lineno)}
+    while True:
+        more = {n.name for n in tops if n.name not in names and any(
+            isinstance(x, ast.Name) and x.id in names for x in ast.walk(n))}
+        if not more:
+            return names
+        names |= more
+
+
+def _names_used(tree) -> set[str]:
+    """Every identifier a file's code names (imports, calls, attributes);
+    docstrings and comments name nothing."""
+    out = set()
+    for x in ast.walk(tree):
+        if isinstance(x, ast.Name):
+            out.add(x.id)
+        elif isinstance(x, ast.Attribute):
+            out.add(x.attr)
+        elif isinstance(x, ast.alias):
+            out.add(x.name.rsplit(".", 1)[-1])
+    return out
+
+
+def test_every_kernel_has_a_caller():
+    """A device program is in the tree because a served route launches
+    it: every jitted function the facts inventory finds under ops/ or
+    parallel/ is named by the code of some file of the package other than
+    its own. PR 31 deleted the eight this named (kernels whose only
+    callers were their own tests, the driver's dry run or a pre-chip
+    benchmark)."""
+    a = run(ROOT)
+    trees = {c.rel: c.tree for c in a.contexts
+             if c.rel.startswith("dgraph_tpu/")}
+    used = {rel: _names_used(t) for rel, t in trees.items()}
+    kernels = [k for k in a.facts["kernels"] if k["file"].startswith(
+        ("dgraph_tpu/ops/", "dgraph_tpu/parallel/"))]
+    assert len(kernels) >= 15
+    orphans = []
+    for k in kernels:
+        names = _entry_names(trees[k["file"]], k["line"])
+        if not any(names & u for rel, u in used.items() if rel != k["file"]):
+            orphans.append(f"{k['file']}:{k['line']} {k['name']} "
+                           f"(reached as {sorted(names)})")
+    assert not orphans, "kernels no route launches:\n" + "\n".join(orphans)
 
 
 def test_cost_record_schema_shares_the_facts_vocabulary():
@@ -548,7 +601,7 @@ def test_r7_flags_every_direct_spelling():
     assert "shard-map-compat" in rules_of(a)
 
     src = "import jax.experimental.shard_map as sm\n"
-    a = scan("bench.py", src)
+    a = scan("dgraph_tpu/server/fake.py", src)
     assert "shard-map-compat" in rules_of(a)
 
 

@@ -1,7 +1,7 @@
 """Mesh-sharded serving (ISSUE 10): the 4-virtual-device subprocess
 fixture (sharded multi-hop bit-identical to the single-device engine
 with ZERO steady-path reshards — the acceptance contract), chain-hop
-@recurse vs the lax.scan variant vs the host loop, the reshard guard's
+@recurse vs the host loop, the reshard guard's
 detection of mis-sharded hop inputs, tablet residency gauges + fold
 carry, learned route promotion, and the cost-prior plumbing: mesh
 expansions record shard-keyed costs that /debug/scheduler surfaces
@@ -120,15 +120,12 @@ def test_sharded_hops_bit_identical_on_4_virtual_devices(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# chain hops vs the scan program vs the host loop (in-process mesh)
+# chain hops vs the host loop (in-process mesh)
 
-def test_chain_recurse_matches_scan_and_host(monkeypatch):
-    """The reshard-free chained-hop @recurse (the serving default) and
-    the monolithic lax.scan program agree with the host loop — and the
-    chain's hop loop, armed with reshard_guard by the engine, stays
-    copy-free."""
-    from dgraph_tpu.engine import recurse as recurse_mod
-
+def test_chain_recurse_matches_host():
+    """The reshard-free chained-hop @recurse (the mesh route) agrees with
+    the host loop — and the chain's hop loop, armed with reshard_guard by
+    the engine, stays copy-free."""
     st = _powerlaw_store()
     host = Engine(st, device_threshold=10**9)
     mesh = Engine(st, device_threshold=0, mesh=make_mesh(8))
@@ -136,13 +133,10 @@ def test_chain_recurse_matches_scan_and_host(monkeypatch):
     want = host.query(q)
 
     before = reshard_count()
-    monkeypatch.setattr(recurse_mod, "MESH_CHAIN_HOPS", True)
+    routed = METRICS.get("mesh_route_total", route="chain")
     assert mesh.query(q) == want
     assert reshard_count() == before  # guard armed inside the loop too
-    assert METRICS.get("mesh_route_total", route="chain") >= 1
-
-    monkeypatch.setattr(recurse_mod, "MESH_CHAIN_HOPS", False)
-    assert mesh.query(q) == want
+    assert METRICS.get("mesh_route_total", route="chain") == routed + 1
 
 
 def test_hop_input_counts_mismatched_sharding():

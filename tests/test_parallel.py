@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from dgraph_tpu.ops.uidalgebra import SENTINEL32
-from dgraph_tpu.parallel.dhop import recurse_fused, ring_hop, scatter_gather_hop
+from dgraph_tpu.parallel.dhop import chain_hop, matrix_hop, ring_matrix_hop
 from dgraph_tpu.parallel.mesh import make_mesh
 from dgraph_tpu.parallel.pshard import device_put_rel, shard_frontier, shard_rel
 from dgraph_tpu.store.store import EdgeRel
@@ -39,6 +39,20 @@ def np_neighbors(rel, frontier):
 
 def np_edges(rel, frontier):
     return int(sum(rel.indptr[r + 1] - rel.indptr[r] for r in frontier))
+
+
+def np_pairs(rel, frontier):
+    """The (parent, child) rows of every out-edge of the frontier, sorted."""
+    pairs = [(int(r), int(c)) for r in frontier for c in rel.row(int(r))]
+    return np.array(sorted(pairs), np.int64).reshape(-1, 2)
+
+
+def matrix_pairs(frontier, nbrs, seg, totals):
+    """matrix_hop's sharded edge matrix as sorted (parent, child) rows."""
+    nbrs, seg, totals = np.asarray(nbrs), np.asarray(seg), np.asarray(totals)
+    pairs = [(int(frontier[seg[d, k]]), int(nbrs[d, k]))
+             for d in range(nbrs.shape[0]) for k in range(int(totals[d]))]
+    return np.array(sorted(pairs), np.int64).reshape(-1, 2)
 
 
 def pad(a, size):
@@ -71,91 +85,111 @@ def test_shard_rel_reconstructs(graph):
 
 
 @pytest.mark.parametrize("fsize", [1, 17, 100])
-def test_scatter_gather_hop(mesh, graph, fsize):
+def test_matrix_hop(mesh, graph, fsize):
     rng = np.random.default_rng(fsize)
     frontier = np.unique(rng.integers(0, 503, fsize)).astype(np.int32)
     srel = device_put_rel(shard_rel(graph, 8), mesh)
-    nxt, count, edges, max_shard_edges = scatter_gather_hop(
-        mesh, srel, pad(frontier, 128), edge_cap=4096, out_cap=1024)
-    want = np_neighbors(graph, frontier)
-    assert int(count) == len(want)
-    np.testing.assert_array_equal(np.asarray(nxt)[:len(want)], want)
-    assert int(edges) == np_edges(graph, frontier)
-    assert 0 < int(max_shard_edges) <= int(edges)
+    nbrs, seg, _pos, totals, max_shard_edges = matrix_hop(
+        mesh, srel, pad(frontier, 128), edge_cap=4096)
+    got = matrix_pairs(frontier, nbrs, seg, totals)
+    np.testing.assert_array_equal(got, np_pairs(graph, frontier))
+    np.testing.assert_array_equal(np.unique(got[:, 1]),
+                                  np_neighbors(graph, frontier))
+    totals = np.asarray(totals)
+    assert int(totals.sum()) == np_edges(graph, frontier)
+    assert 0 < int(max_shard_edges) == int(totals.max())
 
 
-def test_ring_hop_matches_scatter_gather(mesh, graph):
+@pytest.mark.parametrize("fsize", [5, 120])  # 5: some chunks are empty
+def test_ring_matrix_hop_matches_matrix_hop(mesh, graph, fsize):
     rng = np.random.default_rng(7)
-    frontier = np.unique(rng.integers(0, 503, 120)).astype(np.int32)
+    frontier = np.unique(rng.integers(0, 503, fsize)).astype(np.int32)
     srel = device_put_rel(shard_rel(graph, 8), mesh)
     chunks = shard_frontier(frontier, 8, f_cap=32)
-    locals_, merged, count, edges, max_step_edges = ring_hop(
-        mesh, srel, chunks, edge_cap=4096, out_cap=1024)
-    assert int(max_step_edges) <= int(edges)
-    want = np_neighbors(graph, frontier)
-    assert int(count) == len(want)
-    np.testing.assert_array_equal(np.asarray(merged)[:len(want)], want)
-    assert int(edges) == np_edges(graph, frontier)
-    # sharded local unions cover exactly the merged set
-    loc = np.asarray(locals_).reshape(-1)
-    loc = np.unique(loc[loc != SENTINEL32])
-    np.testing.assert_array_equal(loc, want)
+    nbrs, seg, _pos, totals, max_step_edges = ring_matrix_hop(
+        mesh, srel, chunks, edge_cap=4096)
+    nbrs, seg, totals = np.asarray(nbrs), np.asarray(seg), np.asarray(totals)
+    assert int(max_step_edges) == int(totals.max())
+    assert int(totals.sum()) == np_edges(graph, frontier)
+    # shard d at ring step i expands the chunk that started on (d - i) % 8
+    pairs = [(int(chunks[(d - i) % 8][seg[d, i, k]]), int(nbrs[d, i, k]))
+             for d in range(8) for i in range(8)
+             for k in range(int(totals[d, i]))]
+    got = np.array(sorted(pairs), np.int64).reshape(-1, 2)
+    np.testing.assert_array_equal(got, np_pairs(graph, frontier))
+    mnbrs, mseg, _mpos, mtotals, _mx = matrix_hop(
+        mesh, srel, pad(frontier, 128), edge_cap=4096)
+    np.testing.assert_array_equal(
+        got, matrix_pairs(frontier, mnbrs, mseg, mtotals))
 
 
-def test_recurse_fused_matches_bfs(mesh, graph):
-    start = np.array([3, 77], np.int32)
+@pytest.mark.parametrize("start,depth", [([3, 77], 3), ([500], 5)])
+def test_chain_hop_matches_bfs(mesh, graph, start, depth):
+    start = np.array(start, np.int32)
     srel = device_put_rel(shard_rel(graph, 8), mesh)
-    depth = 3
-    last, seen, edges, needs = recurse_fused(
-        mesh, srel, pad(start, 1024), edge_cap=8192, out_cap=1024,
-        seen_cap=2048, depth=depth)
-    assert np.all(np.asarray(needs) <= np.array([1024, 2048, 8192]))
+    caps = dict(edge_cap=8192, out_cap=1024, seen_cap=2048)
+    fr, seen = pad(start, 1024), pad(start, 2048)
     # numpy oracle: BFS layers with global seen set (loop=false semantics)
     seen_np = set(start.tolist())
     frontier = start
-    total_edges = 0
     for _ in range(depth):
-        total_edges += np_edges(graph, frontier)
+        fr_in = np.asarray(fr)
+        fr, seen, edges, needs, nbrs, seg, shard_edges, kept = chain_hop(
+            mesh, srel, fr, seen, **caps)
+        assert np.all(np.asarray(needs) <= np.array([1024, 2048, 8192]))
+        assert (int(edges) == int(np.asarray(shard_edges).sum())
+                == np_edges(graph, frontier))
         nxt = np_neighbors(graph, frontier)
+        # the kept edges are the frontier's out-edges to nodes not seen
+        # before this hop, parents read through seg
+        nbrs, seg = np.asarray(nbrs), np.asarray(seg)
+        m = nbrs != SENTINEL32
+        assert int(kept) == int(m.sum())
+        want = np_pairs(graph, frontier)
+        want = want[~np.isin(want[:, 1], sorted(seen_np))]
+        got = np.stack([fr_in[seg[m]], nbrs[m]], axis=1).astype(np.int64)
+        np.testing.assert_array_equal(got[np.lexsort(got.T[::-1])], want)
         fresh = np.array(sorted(set(nxt.tolist()) - seen_np), np.int32)
         seen_np |= set(fresh.tolist())
         frontier = fresh
     got_seen = np.asarray(seen)
     got_seen = got_seen[got_seen != SENTINEL32]
     np.testing.assert_array_equal(got_seen, np.array(sorted(seen_np), np.int32))
-    got_last = np.asarray(last)
+    got_last = np.asarray(fr)
     got_last = got_last[got_last != SENTINEL32]
     np.testing.assert_array_equal(got_last, frontier)
-    assert int(edges) == total_edges
 
 
 def test_overflow_is_detectable(mesh, graph):
-    """Per-shard truncation must surface in the returned counts even when
-    the merged count alone would sit exactly at out_cap (review finding)."""
+    """Truncation must surface in the returned counts: a shard's (or a ring
+    step's) edges over edge_cap, and a chained hop's frontier, seen set or
+    edges over its caps."""
     frontier = np.arange(200, dtype=np.int32)
     srel = device_put_rel(shard_rel(graph, 8), mesh)
-    want = np_neighbors(graph, frontier)
-    small = 32  # far below the ~500 distinct neighbours this frontier has
-    nxt, count, edges, max_shard_edges = scatter_gather_hop(
-        mesh, srel, pad(frontier, 256), edge_cap=4096, out_cap=small)
-    assert int(count) > small  # overflow visible
-    # tight edge_cap must also be visible via max_shard_edges
-    nxt, count, edges, mse = scatter_gather_hop(
-        mesh, srel, pad(frontier, 256), edge_cap=16, out_cap=1024)
+    *_, mse = matrix_hop(mesh, srel, pad(frontier, 256), edge_cap=16)
     assert int(mse) > 16
+    # the witness is the true need, not the clipped count
+    *_, full, _mx = matrix_hop(mesh, srel, pad(frontier, 256), edge_cap=4096)
+    assert int(mse) == int(np.asarray(full).max())
 
     chunks = shard_frontier(frontier, 8, f_cap=32)
-    _, _, rcount, _, rmse = ring_hop(mesh, srel, chunks, edge_cap=4096, out_cap=small)
-    assert int(rcount) > small
-    _, _, _, _, rmse = ring_hop(mesh, srel, chunks, edge_cap=8, out_cap=1024)
+    *_, rmse = ring_matrix_hop(mesh, srel, chunks, edge_cap=8)
     assert int(rmse) > 8
 
+    small = 32
     start = np.arange(20, dtype=np.int32)
-    _, _, _, needs = recurse_fused(
-        mesh, srel, pad(start, small), edge_cap=4096, out_cap=small,
-        seen_cap=64, depth=2)
-    needs = np.asarray(needs)
-    assert needs[0] > small or needs[1] > 64
+    needs = np.asarray(chain_hop(
+        mesh, srel, pad(start, small), pad(start, 64), edge_cap=4096,
+        out_cap=small, seen_cap=64)[3])
+    assert needs[0] > small
+    needs = np.asarray(chain_hop(
+        mesh, srel, pad(start, 1024), pad(start, 64), edge_cap=4096,
+        out_cap=1024, seen_cap=64)[3])
+    assert needs[0] <= 1024 and needs[1] > 64
+    needs = np.asarray(chain_hop(
+        mesh, srel, pad(start, 1024), pad(start, 2048), edge_cap=8,
+        out_cap=1024, seen_cap=2048)[3])
+    assert needs[2] > 8
 
 
 def test_engine_mesh_matches_host_at_scale():

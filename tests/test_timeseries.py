@@ -513,44 +513,3 @@ def test_breach_exemplar_and_debug_surfaces_live(alpha, tmp_path):
         srv.shutdown()
         flightrec.disarm()
         alpha.slow_query_ms = 0.0
-
-
-# ---------------------------------------------------------------------------
-# bench regression gate (analysis/compare.py)
-
-def test_bench_compare_gate(tmp_path, capsys):
-    from dgraph_tpu.analysis.__main__ import main as lint_main
-    old = {"value": 100.0, "stages": {"sched": {"priors_on": {
-               "cheap_p50_us": 10.0, "shed_precision": 0.9}}},
-           "fused_ab": {"on": {"p50_us": 40.0,
-                               "mean_kernel_launches": 3.0}},
-           "label": "seed"}
-    # within threshold everywhere → gate passes
-    ok = json.loads(json.dumps(old))
-    ok["value"] = 95.0
-    # a >10% latency regression + a throughput collapse → gate fails
-    bad = json.loads(json.dumps(old))
-    bad["value"] = 50.0
-    bad["fused_ab"]["on"]["p50_us"] = 80.0
-    p_old = tmp_path / "old.json"
-    p_ok = tmp_path / "ok.json"
-    p_bad = tmp_path / "bad.json"
-    p_old.write_text(json.dumps(old))
-    p_ok.write_text(json.dumps(ok))
-    p_bad.write_text(json.dumps(bad))
-
-    assert lint_main(["--bench-compare", str(p_old), str(p_ok)]) == 0
-    capsys.readouterr()
-    assert lint_main(["--bench-compare", str(p_old), str(p_bad)]) == 1
-    text = capsys.readouterr().out
-    assert "value" in text and "p50_us" in text
-    # non-numeric keys (label) never gate; unreadable input is usage
-    assert lint_main(["--bench-compare", str(p_old),
-                      str(tmp_path / "missing.json")]) == 2
-    capsys.readouterr()
-    # json format carries the same verdict machine-readably
-    assert lint_main(["--bench-compare", str(p_old), str(p_bad),
-                      "--format", "json"]) == 1
-    doc = json.loads(capsys.readouterr().out)
-    assert any(r["regressed"] and r["key"] == "value"
-               for r in doc["rows"])
