@@ -49,9 +49,14 @@ MAX_KERNEL_DEPTH = 64
 # shortest lane-BFS: the most hops one kernel launch runs, which sizes
 # its [SHORTEST_STAGE, n+1, W] level buffer and bounds one
 # uninterruptible dispatch between deadline checkpoints. The launch
-# stops itself at the hop that closes its last open lane (found /
-# exhausted), so a short path pays for its own hops only; mask carries
-# are DONATED between stages (ops/bfs.py make_ell_step).
+# stops itself at the hop that closes its last open lane, so a short
+# path pays for its own hops only. Three rules close a lane (ops/bfs.py
+# make_ell_step; the host's scan applies the same): at the seed, before
+# any hop, when the target has no in-edge or the source is one of its
+# in-neighbours; ahead, at the hop whose fresh mask reaches an
+# in-neighbour of the target (numpaths = 1), so the hop that would show
+# the target itself is never run; exhausted, when the fresh mask holds
+# nothing of the lane. Mask carries are DONATED between stages.
 SHORTEST_STAGE = 8
 
 
@@ -600,12 +605,35 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
         # lanes needing a kernel at all: known endpoints, src != dst
         active = [q for q in range(B)
                   if src[q] >= 0 and dst[q] >= 0 and src[q] != dst[q]]
-        sp.attrs.update(lanes=lanes, active=len(active))
-        if active:
-            mask0 = np.zeros((n + 1, W), np.uint32)
+        # the look-ahead (numpaths = 1): a lane closes at the hop that
+        # reaches an in-neighbour of its target, so a path of d edges
+        # takes d - 1 hops, and the depth cap, which counts edges, one
+        # hop fewer than it says. near_rows[q]: those in-neighbours'
+        # rows. Two kinds of lane are settled here and never opened: a
+        # target nobody leads to, and a source that is itself one of the
+        # in-neighbours (a path of one edge)
+        hop_cap = plan.depth - 1 if plan.first_visit else plan.depth
+        opened, near_rows = active, {}
+        if plan.first_visit:
             for q in active:
-                r = g.new_of_old[int(src[q])]
-                mask0[r, q // 32] |= np.uint32(1 << (q % 32))
+                preds = rrel.row(int(dst[q]))
+                if len(preds) and not (preds == src[q]).any():
+                    near_rows[q] = g.new_of_old[preds]
+            METRICS.inc("kernel_lanes_closed_total",
+                        float(len(active) - len(near_rows)),
+                        family="shortest", by="seed")
+            # depth: 1 allows no hop at all: the seed settled what it can
+            opened = list(near_rows) if hop_cap else []
+        sp.attrs.update(lanes=lanes, active=len(active),
+                        opened=len(opened))
+        if opened:
+            mask0 = np.zeros((n + 1, W), np.uint32)
+            near = np.zeros_like(mask0) if plan.first_visit else None
+            for q in opened:
+                wq, bq = q // 32, np.uint32(1 << (q % 32))
+                mask0[g.new_of_old[int(src[q])], wq] |= bq
+                if plan.first_visit:
+                    near[near_rows[q], wq] |= bq
             deadline.checkpoint("kernel")
             METRICS.inc("kernel_group_launches_total", family="shortest")
             METRICS.inc("kernel_group_queries_total", float(B),
@@ -621,25 +649,21 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
             if memgov.GOVERNOR.is_degraded("bfs.ell_step", skey):
                 # sticky OOM degrade: the per-query path serves this shape
                 raise memgov.OomDegraded("bfs.ell_step", str(skey))
-            unresolved = {q: None for q in active}   # lanes still open
-            # a lane with no target reads the all-zero sentinel row n
-            targets = np.full(lanes, n, np.int32)
-            targets[active] = g.new_of_old[dst[active]]
-            dst_rows = {q: int(targets[q]) for q in active}
+            unresolved = {q: None for q in opened}   # lanes still open
             frontier = jax.device_put(mask0)
             seen = jax.device_put(mask0)
-            targets = jax.device_put(targets)
-    if active:
+            near = jax.device_put(near)
+    if opened:
         with tracing.span("batch.shortest_kernel", attr=plan.attr,
                           depth=plan.depth, queries=B, lanes=lanes,
                           padded_lanes=lanes - B,
                           first_visit=plan.first_visit) as ksp:
             done = pulled = 0
-            while done < plan.depth and unresolved:
+            while done < hop_cap and unresolved:
                 # budget gate per stage: each launch is one
                 # uninterruptible dispatch of at most SHORTEST_STAGE hops
                 deadline.checkpoint("kernel")
-                chunk = min(SHORTEST_STAGE, plan.depth - done)
+                chunk = min(SHORTEST_STAGE, hop_cap - done)
                 with tracing.span("batch.device_wait", phase=True,
                                   hops=chunk) as sp:
                     try:
@@ -650,7 +674,7 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                                       (plan.attr, plan.reverse, W,
                                        plan.first_visit, n)):
                             frontier, seen, hops, ran, _open, pushed = step(
-                                frontier, seen, targets,
+                                frontier, seen, near,
                                 _lane_mask(unresolved, W), np.int32(chunk))
                         # the dispatch returns at once: the span ends
                         # when the device says how many hops it ran,
@@ -679,25 +703,34 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                 with tracing.span("batch.scan", phase=True,
                                   hops=ran) as sp:
                     # the host's own reading of which lane closed where
-                    # (the device's exit applies the same rule): `used`
+                    # (the device's exit applies the same rules): `used`
                     # is the hop that closed the last open lane, so
                     # used == ran says the device's exit was exact
                     used = ran
+                    closed = {"ahead": 0, "exhausted": 0}
                     for h, lvl in enumerate(lvls):
                         levels.append(lvl)
                         alive = np.bitwise_or.reduce(lvl[:n], axis=0)
                         for q in list(unresolved):
                             wq, bq = q // 32, np.uint32(1 << (q % 32))
-                            if plan.first_visit and \
-                                    (lvl[dst_rows[q], wq] & bq):
-                                unresolved.pop(q)   # found: walk back later
-                                continue
                             if not (alive[wq] & bq):
-                                unresolved.pop(q)   # frontier exhausted
+                                by = "exhausted"
+                            elif q in near_rows and \
+                                    (lvl[near_rows[q], wq] & bq).any():
+                                by = "ahead"    # the walk-back finishes it
+                            else:
+                                continue
+                            unresolved.pop(q)
+                            closed[by] += 1
                         if not unresolved:
                             used = h + 1
                             break
-                    sp.attrs["hops_used"] = used
+                    sp.attrs.update(hops_used=used,
+                                    lanes_ahead=closed["ahead"])
+                for by, lanes_closed in closed.items():
+                    METRICS.inc("kernel_lanes_closed_total",
+                                float(lanes_closed), family="shortest",
+                                by=by)
                 METRICS.inc("kernel_hops_run_total", float(ran),
                             family="shortest")
                 METRICS.inc("kernel_hops_used_total", float(used),
@@ -792,17 +825,19 @@ def _shortest_path_data(store, plan, g, rrel, levels, src: int,
         if plan.minw <= 0 <= plan.maxw:
             paths.append([(src, -1)])
     elif plan.first_visit:
-        found = None
-        for h in range(len(levels)):
-            if _level_member(g, levels, h, np.array([dst]), q)[0]:
-                found = h
-                break
+        # the look-ahead's reading of the levels: dst is h + 2 edges
+        # from src when one of its in-neighbours sits on level h (-1:
+        # src itself), and the level that would show dst was never run.
+        # The depth cap counts edges, so h stops at depth - 2
+        found = next((h for h in range(-1, min(len(levels),
+                                               plan.depth - 1))
+                      if parents_of(dst, h)), None)
         if found is not None:
             # walk back choosing each level's FIRST parent — first-visit
             # BFS makes that exactly the host fast path's plist[0]
             rev = [(dst, 0)]
             cur = dst
-            for lvl in range(found - 1, -2, -1):
+            for lvl in range(found, -2, -1):
                 ps = parents_of(cur, lvl)
                 cur = ps[0]
                 rev.append((cur, 0) if lvl >= 0 else (cur, -1))

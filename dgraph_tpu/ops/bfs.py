@@ -617,17 +617,27 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
                   word_bits: int = 32, first_visit: bool = True,
                   caps: tuple | None = None):
     """Compile a RESUMABLE hop block that stops itself:
-    fn(frontier, seen, dst_rows, open_lanes, limit) →
+    fn(frontier, seen, near, open_lanes, limit) →
     (frontier', seen', hops, ran, open_lanes', pushed).
 
     It runs hops until no lane is open or `limit` (a traced scalar, at
     most `levels`) is reached, and at least one a call, so a staged
     traversal always advances. `open_lanes` is the packed mask [W] of
-    lanes still open. After each hop a lane closes when its target row
-    `dst_rows[lane]` (int32[lanes], permuted space; the all-zero
-    sentinel row n for a lane with no target, which so never reads
-    "found") shows the lane's bit in the fresh mask, or when no row of
-    the fresh mask carries its bit (frontier exhausted): the rule of
+    lanes still open. Three rules close a lane, two of them here and one
+    before the launch:
+      * exhausted: no row of the hop's fresh mask carries the lane's bit;
+      * ahead (`first_visit` only): some row of the fresh mask carries
+        the lane's bit in `near` too. `near` [n+1, W] (permuted space,
+        sentinel row zero, not donated: every stage reads it) has bit q
+        of row r set iff r is an in-neighbour of lane q's target, so the
+        target is one edge beyond this hop and the hop that would show
+        it is never run: the caller finishes the path from the
+        in-neighbours, which it has to read for the walk back anyway;
+      * at the seed, by the caller: a lane whose source is itself such
+        an in-neighbour, or whose target has none, is never opened.
+    With `near` whole, a target cannot show in a fresh mask while its
+    lane is open (an in-neighbour would have carried the bit a hop
+    sooner), so the program tests no target row. The rules are those of
     engine/batch.py's host scan, which stays the authority on which
     lane closed where. `hops` is a tuple of `levels` masks [n+1, W]
     (separate arrays, so a caller copies back only what was run):
@@ -649,11 +659,9 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
     `first_visit=False` drops the seen-masking: hops[h] is then the FULL
     set reachable in exactly h+1 hops (the level-DAG the k-shortest
     enumeration consumes), with `seen` passed through untouched, and a
-    lane closes only when its frontier is exhausted."""
+    lane closes only when its frontier is exhausted: that program makes
+    no use of `near`, and its caller passes None."""
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
-    lane = jnp.arange(W * word_bits, dtype=jnp.int32)
-    lane_word = lane // word_bits
-    lane_bit = dtype(1) << (lane % word_bits).astype(dtype)
     f_cap, e_cap, chunk = caps or push_caps(int(dev.out[1].shape[0]))
     # The index blocks ride as arguments. A device array that a jitted
     # function closes over is a constant of its program: fetched to the
@@ -672,7 +680,7 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
         return lax.reduce(x, dtype(0), lax.bitwise_or, (axis,))
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def step(arrays, frontier, seen, dst_rows, open_lanes, limit):
+    def step(arrays, frontier, seen, near, open_lanes, limit):
         prepared, out = blocks(arrays)
         outdeg = out[2]
 
@@ -706,8 +714,7 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
             # row n is the zero sentinel: OR over all rows == over [:n]
             open_ = open_ & or_over(fresh, 0)
             if first_visit:
-                hit = fresh[dst_rows, lane_word] & lane_bit
-                open_ = open_ & ~or_over(hit.reshape(W, word_bits), 1)
+                open_ = open_ & ~or_over(fresh & near, 0)
             return fresh, s, buf, ran + 1, pushed, open_
 
         buf = jnp.zeros((levels,) + frontier.shape, dtype)

@@ -366,29 +366,55 @@ def chain():
     return a, [uids[f"_:p{i}"] for i in range(14)]
 
 
-# (pairs, shortest's extra arguments, hops of each launch): a launch
-# stops at the hop that closes its last open lane
+# (pairs, shortest's extra arguments, hops of each launch, lanes closed
+# by each rule): a launch stops at the hop that closes its last open
+# lane, and a numpaths = 1 lane closes a hop AHEAD of its target, at the
+# hop that reaches one of the target's in-neighbours: a path of d edges
+# takes d - 1 hops
 STOPPING_GROUPS = {
-    # p0 -> p12 is 11 hops: a full stage, then 3 more and no further
-    "two-launches": ([(0, 12), (1, 4), (3, 5), (2, 9)], "", [8, 3]),
-    # nothing leads back to p0: the search from p13 dies at hop 1,
-    # the one from p9 at hop 5, after the longest path found (4)
-    "unreachable": ([(13, 0), (9, 0), (0, 5), (4, 6)], "", [5]),
+    # p0 -> p12 is 11 edges (p0 -> p2, then ten on): p11 shows at hop
+    # 10, a full stage and 2 more. p1 -> p4 closes at hop 2, p3 -> p5 at
+    # hop 1, p2 -> p9 at hop 6
+    "two-launches": ([(0, 12), (1, 4), (3, 5), (2, 9)], "", [8, 2],
+                     {"ahead": 4}),
+    # nothing leads to p0, so the searches from p13 and from p9 are
+    # settled before the launch; p0 -> p5 is 4 edges, closed at hop 3
+    # (p4 shows), p4 -> p6 at hop 1
+    "unreachable": ([(13, 0), (9, 0), (0, 5), (4, 6)], "", [3],
+                    {"seed": 2, "ahead": 2}),
     # the level-DAG closes a lane only when nothing is left to expand:
     # from p0 every node is passed by hop 13, hop 14 is empty
     "numpaths-2": ([(0, 3), (1, 4), (0, 12), (5, 6)], ", numpaths: 2",
-                   [8, 6]),
+                   [8, 6], {"exhausted": 4}),
+    # p0 -> p1, p0 -> p2 and p5 -> p6 are edges: settled before the
+    # launch, which p3 -> p5 alone opens and closes at hop 1
+    "one-edge": ([(0, 1), (0, 2), (3, 5), (5, 6)], "", [1],
+                 {"seed": 3, "ahead": 1}),
+    # every target is p0, which nothing leads to: no lane is opened and
+    # no launch made, as for a batch whose every source is its target
+    "no-in-edge": ([(13, 0), (9, 0), (5, 0), (1, 0)], "", [], {"seed": 4}),
+    # the cap counts edges: p0 -> p5 and p1 -> p5 are 4 (p4 shows at
+    # hop 3, the last the cap allows), p0 -> p6 and p1 -> p6 are 5 and
+    # stay open: hop 4 would show p5 and is not run
+    "depth-cap": ([(0, 5), (0, 6), (1, 5), (1, 6)], ", depth: 4", [3],
+                  {"ahead": 2}),
+    # a cap of one edge allows no hop: the three pairs one edge apart are
+    # settled before the launch, p3 -> p5 (2 edges) is cut by the cap,
+    # and no launch is made
+    "depth-one": ([(0, 1), (0, 2), (3, 5), (5, 6)], ", depth: 1", [],
+                  {"seed": 3}),
 }
 
 
 @pytest.mark.parametrize("case", sorted(STOPPING_GROUPS))
 def test_shortest_batch_stops_with_its_last_lane(chain, case):
-    """Answers are byte-equal to the per-query engine's, and each launch
-    runs the hops some open lane needs and none after."""
+    """Answers are byte-equal to the per-query engine's, each launch runs
+    the hops some open lane needs and none after, and every lane that
+    closes is counted once, under the rule that closed it."""
     from dgraph_tpu.utils.metrics import METRICS
 
     alpha, u = chain
-    pairs, extra, launches = STOPPING_GROUPS[case]
+    pairs, extra, launches, closed = STOPPING_GROUPS[case]
     qs = ['{ path as shortest(from: %s, to: %s%s) { follows } '
           'p(func: uid(path)) { name } }' % (u[i], u[j], extra)
           for i, j in pairs]
@@ -397,37 +423,60 @@ def test_shortest_batch_stops_with_its_last_lane(chain, case):
         return (METRICS.get("kernel_hops_run_total", family="shortest"),
                 METRICS.get("kernel_hops_used_total", family="shortest"),
                 METRICS.get("jit_cache_hits_total", kernel="bfs.ell_step")
-                + METRICS.get("jit_compile_total", kernel="bfs.ell_step"))
+                + METRICS.get("jit_compile_total", kernel="bfs.ell_step"),
+                METRICS.get("kernel_group_launches_total",
+                            family="shortest"))
 
-    run0, used0, calls0 = hops()
+    def lanes_closed():
+        return {by: METRICS.get("kernel_lanes_closed_total",
+                                family="shortest", by=by)
+                for by in ("seed", "ahead", "exhausted")}
+
+    before, closed0 = hops(), lanes_closed()
     got = alpha.query_batch(qs)
-    run1, used1, calls1 = hops()
-    assert (run1 - run0, used1 - used0, calls1 - calls0) == \
-        (sum(launches), sum(launches), len(launches))
+    after, closed1 = hops(), lanes_closed()
+    assert tuple(b - a for a, b in zip(before, after)) == \
+        (sum(launches), sum(launches), len(launches), bool(launches))
+    assert {by: closed1[by] - closed0[by] for by in closed0} == \
+        {"seed": 0, "ahead": 0, "exhausted": 0, **closed}
     eng = Engine(alpha.mvcc.read_view(alpha.oracle.read_only_ts()),
                  device_threshold=10**9)
     want = [eng.query(q) for q in qs]
     assert json.dumps(got) == json.dumps(want)
+    found = [len(o.get("p", [])) for o in got]
     if case == "unreachable":
-        assert [len(o.get("p", [])) for o in got] == [0, 0, 5, 3]
+        assert found == [0, 0, 5, 3]
+    elif case == "one-edge":
+        assert found == [2, 2, 3, 2]
+    elif case == "no-in-edge":
+        assert found == [0, 0, 0, 0]
+    elif case == "depth-cap":
+        assert found == [5, 0, 5, 0]
+    elif case == "depth-one":
+        assert found == [2, 2, 0, 2]
 
 
 def _hops_pushed(store, attr, pairs, levels):
     """The plain count behind `kernel_hops_push_total` for one launch of
-    first-visit lanes: a search a lane from each pair's source; a hop
-    pushes when the rows that some lane reached last hop and that have
-    an out-edge, the sum of their out-degrees and the largest of them
-    fit ops/bfs.py push_caps. Returns (hops run, hops pushed)."""
+    first-visit lanes: a search a lane from each pair's source, but for
+    the pairs settled before the launch (a target with no in-edge, or
+    one edge from its source); a lane closes at the hop that reaches an
+    in-neighbour of its target, or nothing new; a hop pushes when the
+    rows that some lane reached last hop and that have an out-edge, the
+    sum of their out-degrees and the largest of them fit ops/bfs.py
+    push_caps. Returns (hops run, hops pushed)."""
     from dgraph_tpu.ops.bfs import push_caps
 
-    rel = store.rel(attr, False)
+    rel, rrel = store.rel(attr, False), store.rel(attr, True)
     deg = np.diff(rel.indptr)
     f_cap, e_cap, chunk = push_caps(len(rel.indices))
     src = store.rank_of(np.asarray([a for a, _ in pairs], np.int64))
     dst = store.rank_of(np.asarray([b for _, b in pairs], np.int64))
-    fresh = [{int(s)} for s in src]
+    near = [set(rrel.row(int(d)).tolist()) for d in dst]
+    open_ = {q for q in range(len(pairs))
+             if near[q] and int(src[q]) not in near[q]}
+    fresh = [{int(s)} if q in open_ else set() for q, s in enumerate(src)]
     seen = [set(f) for f in fresh]
-    open_ = set(range(len(pairs)))
     ran = pushed = 0
     while open_ and ran < levels:
         rows = np.array(sorted(set().union(*fresh)), np.int64)
@@ -439,7 +488,7 @@ def _hops_pushed(store, attr, pairs, levels):
             nxt = {int(v) for u in fresh[q] for v in rel.row(u)} - seen[q]
             fresh[q] = nxt
             seen[q] |= nxt
-            if not nxt or int(dst[q]) in nxt:
+            if not nxt or nxt & near[q]:
                 open_.discard(q)
     return ran, pushed
 

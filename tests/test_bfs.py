@@ -271,21 +271,35 @@ def _scan_levels(prepared, mask0, depth, W, first_visit):
     return np.asarray(fs), np.asarray(ss)
 
 
-def _host_rule(levels, n, dst_rows, unresolved, first_visit):
+def _closes(lvl, n, rows, q, first_visit):
+    """Whether lane q closes at this level: nothing of it is left, or
+    (first-visit lanes) its bit shows in one of `rows`."""
+    wq, bq = q // 32, np.uint32(1 << (q % 32))
+    return bool(first_visit and (lvl[rows, wq] & bq).any()) or \
+        not (np.bitwise_or.reduce(lvl[:n, wq]) & bq)
+
+
+def _host_rule(levels, n, near_rows, unresolved, first_visit):
     """engine/batch.py's scan of one launch's levels: pops the lanes it
     closes from `unresolved`, returns the hop that closed the last one
-    (the count of levels when some stay open)."""
+    (the count of levels when some stay open). A first-visit lane closes
+    a hop AHEAD of its target, when the level reaches one of the
+    target's in-neighbours (`near_rows[q]`)."""
     for h, lvl in enumerate(levels):
-        alive = np.bitwise_or.reduce(lvl[:n], axis=0)
         for q in list(unresolved):
-            wq, bq = q // 32, np.uint32(1 << (q % 32))
-            if first_visit and (lvl[dst_rows[q], wq] & bq):
-                unresolved.discard(q)
-            elif not (alive[wq] & bq):
+            if _closes(lvl, n, near_rows[q], q, first_visit):
                 unresolved.discard(q)
         if not unresolved:
             return h + 1
     return len(levels)
+
+
+def _target_rule(levels, n, dst_rows, unresolved):
+    """The rule the look-ahead replaced, kept to be compared with: a
+    first-visit lane closes at the level that shows its target's own
+    row. Same returns as _host_rule."""
+    return _host_rule(levels, n, {q: [r] for q, r in dst_rows.items()},
+                      unresolved, True)
 
 
 def _packed(lanes_set, W):
@@ -329,6 +343,8 @@ def _pushes(frontier, dev, n, caps):
 def _step_case(lanes, first_visit, acyclic, caps="mixed"):
     """One graph, its lanes, the step program and the plain scan's levels.
     The hop limit is a traced argument, so both limits share all of it."""
+    import types
+
     import jax
 
     from dgraph_tpu.ops.bfs import make_ell_step, prepare_parts
@@ -347,6 +363,7 @@ def _step_case(lanes, first_visit, acyclic, caps="mixed"):
     else:
         dst = rng.integers(0, core, src.size).astype(np.int32)
     rel = _csr_from_pairs(src, dst, n)
+    rrel = _csr_from_pairs(dst, src, n)
     g, dev = _device_ell_with_out(rel)
     W = lanes // 32
     B = lanes - 5                       # the last five lanes are padding
@@ -354,21 +371,31 @@ def _step_case(lanes, first_visit, acyclic, caps="mixed"):
     dsts = rng.integers(0, core, B)
     dsts[0] = core                      # unreachable: the search dies out
     srcs[1] = core + 1                  # nothing to expand: dies at hop 1
-    active = {q for q in range(B) if q != 2 and srcs[q] != dsts[q]}
+    # as engine/batch.py opens them: not lane 2, not a pair one edge
+    # apart (settled before the launch). Lane 0's target has no in-edge:
+    # kept open here, as the lane whose `near` rows are none
+    active = {q for q in range(B) if q != 2 and srcs[q] != dsts[q]
+              and srcs[q] not in rrel.row(dsts[q])}
     mask0 = np.zeros((n + 1, W), np.uint32)
-    dst_rows = {}
-    targets = np.full(lanes, n, np.int32)      # lane 2: inactive
+    near = np.zeros((n + 1, W), np.uint32)
+    dst_rows, near_rows = {}, {}
     for q in active:
-        mask0[g.new_of_old[srcs[q]], q // 32] |= np.uint32(1 << (q % 32))
-        dst_rows[q] = targets[q] = int(g.new_of_old[dsts[q]])
+        wq, bq = q // 32, np.uint32(1 << (q % 32))
+        mask0[g.new_of_old[srcs[q]], wq] |= bq
+        dst_rows[q] = int(g.new_of_old[dsts[q]])
+        near_rows[q] = g.new_of_old[rrel.row(dsts[q])]
+        near[near_rows[q], wq] |= bq
+    assert not len(near_rows[0]) and not near[n].any()
 
     want_f, want_s = _scan_levels(prepare_parts(dev, W),
                                   jax.device_put(mask0), 3 * STEP_LEVELS,
                                   W, first_visit)
     step = make_ell_step(dev, n, W, STEP_LEVELS, first_visit=first_visit,
                          caps=STEP_CAPS[caps])
-    return (n, W, mask0, active, dst_rows, targets, step, want_f, want_s,
-            dev)
+    return types.SimpleNamespace(
+        n=n, W=W, mask0=mask0, active=active, dst_rows=dst_rows,
+        near=near, near_rows=near_rows, step=step, want_f=want_f,
+        want_s=want_s, dev=dev)
 
 
 @pytest.mark.parametrize("limit", [2, STEP_LEVELS])
@@ -383,18 +410,19 @@ def test_step_stops_where_the_host_rule_closes_the_last_lane(
     exactly the hops whose frontier its caps hold."""
     import jax
 
-    (n, W, mask0, active, dst_rows, targets, step, want_f, want_s,
-     dev) = _step_case(lanes, first_visit, acyclic, caps)
-    unresolved = set(active)
-    frontier = seen = mask0
+    c = _step_case(lanes, first_visit, acyclic, caps)
+    n, W, step, want_f, want_s, dev = c.n, c.W, c.step, c.want_f, c.want_s, \
+        c.dev
+    unresolved = set(c.active)
+    frontier = seen = c.mask0
     done = pushed_all = 0
     for call, lim in enumerate((limit, STEP_LEVELS, STEP_LEVELS)):  # resumed
         open_before = _packed(unresolved, W)
-        closing = _host_rule(want_f[done:done + lim], n, dst_rows,
+        closing = _host_rule(want_f[done:done + lim], n, c.near_rows,
                              unresolved, first_visit)
         f, s, hops, ran, open_after, pushed = step(
-            jax.device_put(frontier), jax.device_put(seen), targets,
-            open_before, np.int32(lim))
+            jax.device_put(frontier), jax.device_put(seen),
+            c.near if first_visit else None, open_before, np.int32(lim))
         ran = int(ran)
         assert ran == closing, (call, ran, closing)
         assert len(hops) == STEP_LEVELS
@@ -427,17 +455,64 @@ def test_step_stops_where_the_host_rule_closes_the_last_lane(
         assert 0 < pushed_all < done, "the case must mix both kinds of hop"
 
 
+@pytest.mark.parametrize("near", ["given", "none", "level-dag"])
+@pytest.mark.parametrize("lanes", [32, 64, 128])
+@pytest.mark.parametrize("caps", list(STEP_CAPS))
+def test_the_look_ahead_spares_a_launch_its_last_hop(caps, lanes, near):
+    """A launch whose every open lane finds its target runs one hop fewer
+    than the rule that waited for the target's own row: the lanes close
+    at the level that reaches the target's in-neighbours, and the levels
+    up to there are the plain scan's, pushed or pulled. A lane with no
+    row in `near` closes when it dies out, as does every lane of the
+    level-DAG program, which makes no use of the argument."""
+    import jax
+
+    first_visit = near != "level-dag"
+    c = _step_case(lanes, first_visit, not first_visit, caps)
+    n, W = c.n, c.W
+    finders = {q for q in c.active
+               if (c.want_f[:STEP_LEVELS, c.dst_rows[q], q // 32]
+                   >> np.uint32(q % 32) & 1).any()}
+    assert len(finders) > (lanes // 4 if first_visit else 0)
+
+    def run(near_mask):
+        _f, _s, hops, ran, open_after, _pushed = c.step(
+            jax.device_put(c.mask0), jax.device_put(c.mask0), near_mask,
+            _packed(finders, W), np.int32(STEP_LEVELS))
+        for h in range(int(ran)):
+            assert np.array_equal(np.asarray(hops[h]), c.want_f[h])
+        return int(ran), np.asarray(open_after)
+
+    levels = c.want_f[:STEP_LEVELS]
+    dies_out = _host_rule(levels, n, {q: [] for q in finders},
+                          set(finders), first_visit)
+    if near == "given":
+        at_target = _target_rule(levels, n, c.dst_rows, set(finders))
+        ahead = _host_rule(levels, n, c.near_rows, set(finders), True)
+        assert 1 <= ahead == at_target - 1 < dies_out
+        ran, open_after = run(c.near)
+        assert ran == ahead and not open_after.any()
+    else:
+        ran, open_after = run(np.zeros_like(c.mask0) if first_visit
+                              else None)
+        assert ran == dies_out
+        assert dies_out == STEP_LEVELS or not open_after.any()
+        if not first_visit:             # given a mask, it reads none of it
+            again, open_again = run(c.near)
+            assert again == ran and np.array_equal(open_again, open_after)
+
+
 def _one_hop(dev, n, W, mask0, caps, first_visit=True):
     """One hop of a fresh step program under `caps`, every lane open and
-    none with a target: (level, seen, pushed)."""
+    none with a row to look ahead to: (level, seen, pushed)."""
     import jax
 
     from dgraph_tpu.ops.bfs import make_ell_step
     step = make_ell_step(dev, n, W, 1, first_visit=first_visit, caps=caps)
     _f, s, hops, ran, _open, pushed = step(
         jax.device_put(mask0), jax.device_put(mask0),
-        np.full(W * 32, n, np.int32), np.full(W, 0xFFFFFFFF, np.uint32),
-        np.int32(1))
+        np.zeros_like(mask0) if first_visit else None,
+        np.full(W, 0xFFFFFFFF, np.uint32), np.int32(1))
     assert int(ran) == 1
     return np.asarray(hops[0]), np.asarray(s), int(pushed)
 
@@ -449,8 +524,9 @@ def test_a_frontier_over_a_cap_takes_the_pull(over, first_visit):
     """Caps that hold the frontier exactly push it; one row or one edge
     less, or a turn one slot short of a row's out-edges, and the hop
     pulls. The level is the same each way."""
-    n, W, mask0, _a, _d, _t, _step, want_f, want_s, dev = _step_case(
-        64, first_visit, False, "pull")
+    c = _step_case(64, first_visit, False, "pull")
+    n, W, mask0, want_f, want_s, dev = c.n, c.W, c.mask0, c.want_f, \
+        c.want_s, c.dev
     deg = np.asarray(dev.out[2])
     act = (mask0[:n] != 0).any(axis=1) & (deg > 0)
     rows, edges = int(act.sum()), int(deg[act].sum())
@@ -471,8 +547,8 @@ def test_a_pushed_hop_leaves_the_sentinel_row_zero(chunk):
     """The slots of a turn beyond the frontier's last edge are dropped,
     never written to row n: every padded gather of a later pull reads
     that row as zero."""
-    n, W, mask0, _a, _d, _t, _step, want_f, _s, dev = _step_case(
-        64, True, False, "pull")
+    c = _step_case(64, True, False, "pull")
+    n, W, mask0, want_f, dev = c.n, c.W, c.mask0, c.want_f, c.dev
     deg = np.asarray(dev.out[2])
     edges = int(deg[(mask0[:n] != 0).any(axis=1)].sum())
     assert edges % chunk, "the last turn must hold slots with no edge"

@@ -399,13 +399,13 @@ def test_hops_used_is_the_longest_found_path_of_the_launch():
     from dgraph_tpu.engine.batch import SHORTEST_STAGE
     from dgraph_tpu.utils.metrics import METRICS
 
-    alpha, u = _chain_alpha(10)
+    alpha, u = _chain_alpha(11)
 
     def hops():
         return (METRICS.get("kernel_hops_run_total", family="shortest"),
                 METRICS.get("kernel_hops_used_total", family="shortest"))
 
-    # p0 -> p6 is 5 hops by the shortcut; the others are shorter
+    # p0 -> p6 is 5 edges by the shortcut; the others are shorter
     # (four queries: a smaller group is served one by one)
     run0, used0 = hops()
     out = alpha.query_batch([_shortest(u[0], u[6]), _shortest(u[1], u[3]),
@@ -413,12 +413,15 @@ def test_hops_used_is_the_longest_found_path_of_the_launch():
     lengths = [len(o["p"]) - 1 for o in out]
     assert lengths == [5, 2, 1, 1]
     run1, used1 = hops()
-    # the launch stops itself at the hop that closes its last lane: the
-    # device's count of hops run is the host's count of hops used
-    assert (run1 - run0, used1 - used0) == (max(lengths), max(lengths))
-    # a lane still open at the stage's end uses the whole stage: p0 -> p9
-    # is 8 hops, found at the stage's last hop
-    alpha.query_batch([_shortest(u[0], u[9])] +
+    # the launch stops itself at the hop that closes its last lane, the
+    # one that reaches an in-neighbour of the farthest target, a hop
+    # short of the path's edges: the device's count of hops run is the
+    # host's count of hops used
+    assert (run1 - run0, used1 - used0) == (max(lengths) - 1,
+                                            max(lengths) - 1)
+    # a lane still open at the stage's end uses the whole stage: p0 -> p10
+    # is 9 edges, p9 shows at the stage's last hop
+    alpha.query_batch([_shortest(u[0], u[10])] +
                       [_shortest(u[i], u[i + 1]) for i in range(3)])
     run2, used2 = hops()
     assert (run2 - run1, used2 - used1) == (SHORTEST_STAGE, SHORTEST_STAGE)
