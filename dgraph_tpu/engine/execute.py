@@ -55,6 +55,9 @@ class LevelNode:
     recurse_data: object | None = None     # engine.recurse.RecurseData
     path_data: object | None = None        # engine.shortest.PathData
     groups: object | None = None           # engine.groupby.GroupResult
+    # a root block answered by a count alone: |nodes| where the nodes
+    # were never named (engine/treebatch.py; count(uid) renders it)
+    count: int | None = None
     # @msgpass binding (engine/feat.py): rank → f32[d] aggregate; None
     # means the level carries no binding (key "" likewise)
     feat_vals: dict | None = None
@@ -646,6 +649,11 @@ class Executor:
                 # in-trace; anything it didn't claim aggregates here
                 feat.annotate_tree(self, fused_node)
             return fused_node
+        return self._run_staged(sg)
+
+    def _run_staged(self, sg: SubGraph) -> LevelNode:
+        """A root block level by level on the host's side of the store
+        (what _run_block does where no fused program takes the block)."""
         display = self.root_display(sg)
         nodes = np.unique(display).astype(np.int32)
         node = LevelNode(sg=sg, nodes=nodes, display=display.astype(np.int32))

@@ -157,7 +157,8 @@ class _Renderer:
                     name = leaf.alias or f"{leaf.agg_func}(val({leaf.attr}))"
                     entries.append({name: _json_val(v)})
             elif leaf.is_count and leaf.is_uid_leaf:
-                entries.append({leaf.alias or "count": int(len(node.nodes))})
+                entries.append({leaf.alias or "count": int(
+                    len(node.nodes) if node.count is None else node.count)})
         return entries
 
     # -- nodes --------------------------------------------------------------

@@ -19,11 +19,18 @@ What widens eligibility past engine/batch.py's recurse-only path
     during host rebuild exactly as the per-query engine applies them
 
 Division of labor: the device computes every level's NODE SET (the
-expansion + filter work, amortised across all lanes); the host rebuilds
-each query's per-parent edge rows by intersecting parents' CSR rows with
-the level masks (bit tests, no set algebra), then the standard renderer
-emits JSON — so batch results are bit-identical to the per-query engine,
-asserted by tests/test_treebatch.py against the LDBC IC goldens.
+expansion + filter work, amortised across all lanes). For a level that a
+block RENDERS the host rebuilds each query's per-parent edge rows by
+intersecting parents' CSR rows with the level masks (bit tests, no set
+algebra), then the standard renderer emits JSON. An @recurse stage that no
+block renders (a `var` block) rebuilds nothing: the launch keeps no hop
+masks, and each consumer of the stage's var is handed what it reads, from
+the device: a `count(uid)` over `uid(v)` its lane's population count of
+the reachable set, any other reader the lane's column of that set by one
+O(n) bit test (`tree_var_reads_total{by=}` says which). Either way batch
+results are bit-identical to the per-query engine, asserted by
+tests/test_treebatch.py against the LDBC IC goldens and by
+tests/test_khop.py against a plain breadth-first search.
 """
 
 from __future__ import annotations
@@ -35,6 +42,7 @@ import numpy as np
 from dgraph_tpu.engine.execute import EMPTY64, Executor, LevelNode, expands
 from dgraph_tpu.engine.ir import FilterNode, SubGraph
 from dgraph_tpu.engine.varorder import execution_order
+from dgraph_tpu.utils.metrics import METRICS
 
 EMPTY = np.zeros(0, np.int32)
 
@@ -211,14 +219,17 @@ def _plan_tree(store, blocks):
             seed_blocks.append(bi)
             if len(stages) >= MAX_STAGES:
                 raise _Ineligible
-            # keep_hops always: internal (var) blocks also rebuild their
-            # reachable set from the per-hop masks via candidate walks —
-            # O(visited edges), never O(n) per lane
+            # per-hop masks only where a block renders the tree: a `var`
+            # block's consumers read the reachable set or its count
+            # (_MaskedExecutor), and on a graph whose 3-hop reaches most
+            # of it a walk over the visited edges is the dear way to a
+            # set nobody renders
             stages.append(StageSpec(
                 attr=e.attr, reverse=e.is_reverse, kind="recurse",
                 parent=("seed", len(seed_blocks) - 1), filt_slot=slot,
-                depth=r.depth, keep_hops=True, path=(bi,),
-                filt_shape=fshape))
+                depth=r.depth,
+                keep_hops=not sg.is_internal or sg.msgpass is not None,
+                path=(bi,), filt_shape=fshape))
             if e.var_name or sg.var_name:
                 # block var = reachable set = the stage's seen mask;
                 # an edge-child var inside @recurse binds the same set
@@ -283,14 +294,52 @@ class _StageIndex:
 # ---------------------------------------------------------------------------
 # execution
 
+@dataclass
+class _Launch:
+    """What one launch handed back, as the per-query runs read it."""
+
+    # all keyed by stage index
+    masks: dict = field(default_factory=dict)       # hop: host [n+1, W]
+    hops: dict = field(default_factory=dict)        # rendered recurse:
+    #                                                 host [depth, n+1, W]
+    counts: dict = field(default_factory=dict)      # recurse: int32[lanes]
+    seen_dev: dict = field(default_factory=dict)    # recurse: device seen,
+    #                                                 permuted rows
+    perm_order: dict = field(default_factory=dict)  # recurse: permuted row
+    #                                                 → global rank
+    seen_host: dict = field(default_factory=dict)   # seen_dev, once read
+
+    def column(self, stage_idx: int, lane: int) -> np.ndarray:
+        """Lane's members of a recurse stage's reachable set, ascending
+        global ranks: its column of `seen`, one O(n) bit test. The mask
+        is copied back at the first such read of a batch, not before."""
+        from dgraph_tpu.utils import tracing
+        seen = self.seen_host.get(stage_idx)
+        if seen is None:
+            with tracing.span("batch.fetch_column", stage=stage_idx) as sp:
+                seen = self.seen_host[stage_idx] = np.asarray(
+                    self.seen_dev[stage_idx])
+                sp.attrs["bytes"] = seen.nbytes
+        rows = np.nonzero(seen[:-1, lane // 32]
+                          & np.uint32(1 << (lane % 32)))[0]
+        return np.sort(self.perm_order[stage_idx][rows]).astype(np.int32)
+
+
 def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
     """Execute one homogeneous group as a single make_ell_tree launch and
     render each query with the standard engine over mask-constrained
     expansion. Returns one JSON dict per query (None → caller falls back
-    to per-query execution)."""
+    to per-query execution). Once a request each, the phases of the
+    shortest route where the work is the same: `batch.seed` (roots,
+    filter sets, packing, uploads), `batch.device_wait` (dispatch until
+    the host holds the recurse stages' counts), `batch.fetch` (the masks
+    a rendered level needs), `batch.render` (the per-query runs)."""
     import jax
 
+    from dgraph_tpu.engine.batch import _ell_for, _note_kernel_features
     from dgraph_tpu.engine.outputnode import to_json
+    from dgraph_tpu.utils import costprofile, deadline, tracing
+    from dgraph_tpu.utils.jitcache import jit_call
 
     n = store.n_nodes
     B = len(plan.queries)
@@ -298,101 +347,119 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
     W = 1 << max(words - 1, 0).bit_length() if words > 1 else 1
     lanes = 32 * W
 
-    # per-(attr, dir) device state, shared with the recurse batch path
-    from dgraph_tpu.engine.batch import _ell_for
-    rels = {}
-    for s in plan.stages:
-        key = (s.attr, s.reverse)
-        if key not in rels:
-            g = _ell_for(store, s.attr, s.reverse)
-            if g is None:                 # empty relation: no kernel win
-                return None
-            if g.n != n:
-                return None
-            rels[key] = g
-
-    # per-query seeds (host root evaluation) and filter node sets
-    seed_lists: list[list[np.ndarray]] = [[] for _ in range(plan.n_seeds)]
-    filt_lists: list[list[np.ndarray]] = [[] for _ in plan.filt_paths]
-    idx_per_query: list[_StageIndex] = []
-    root_displays: list[dict[int, np.ndarray]] = []
-    # graftlint: allow(cache-registration): per-call local memo of this one batch's filter sets — it dies with the function, never holds bytes across requests
-    filt_cache: dict = {}
-    for q, blocks in enumerate(plan.queries):
-        ex = Executor(store, device_threshold=device_threshold)
-        sidx = _StageIndex(store, plan, blocks)
-        idx_per_query.append(sidx)
-        displays: dict[int, np.ndarray] = {}
-        root_displays.append(displays)
-        for slot, bi in enumerate(plan.seed_blocks):
-            try:
-                display = ex.root_display(blocks[bi])
-            except Exception:
-                return None
-            displays[bi] = display
-            seed_lists[slot].append(np.unique(display).astype(np.int32))
-        for slot, path in enumerate(plan.filt_paths):
-            sg = sidx.sg_by_path.get(path)
-            if sg is None or sg.filters is None:
-                return None
-            ckey = _filter_const_key(sg.filters)
-            allowed = filt_cache.get(ckey)
-            if allowed is None:
-                allowed = ex.filter_set(sg.filters)
-                if allowed is None:
+    with tracing.span("batch.seed", phase=True, queries=B, lanes=lanes):
+        # per-(attr, dir) device state, shared with the recurse batch path
+        rels = {}
+        for s in plan.stages:
+            key = (s.attr, s.reverse)
+            if key not in rels:
+                g = _ell_for(store, s.attr, s.reverse)
+                if g is None:             # empty relation: no kernel win
                     return None
-                filt_cache[ckey] = allowed
-            filt_lists[slot].append(allowed)
+                if g.n != n:
+                    return None
+                rels[key] = g
 
-    seeds_np = [_pack_global(n, lst, lanes) for lst in seed_lists]
-    filts_np = [_pack_global(n, lst, lanes) for lst in filt_lists]
+        # per-query seeds (host root evaluation) and filter node sets
+        seed_lists: list[list[np.ndarray]] = [
+            [] for _ in range(plan.n_seeds)]
+        filt_lists: list[list[np.ndarray]] = [[] for _ in plan.filt_paths]
+        idx_per_query: list[_StageIndex] = []
+        root_displays: list[dict[int, np.ndarray]] = []
+        # graftlint: allow(cache-registration): per-call local memo of this one batch's filter sets — it dies with the function, never holds bytes across requests
+        filt_cache: dict = {}
+        for q, blocks in enumerate(plan.queries):
+            ex = Executor(store, device_threshold=device_threshold)
+            sidx = _StageIndex(store, plan, blocks)
+            idx_per_query.append(sidx)
+            displays: dict[int, np.ndarray] = {}
+            root_displays.append(displays)
+            for slot, bi in enumerate(plan.seed_blocks):
+                try:
+                    display = ex.root_display(blocks[bi])
+                except Exception:
+                    return None
+                displays[bi] = display
+                seed_lists[slot].append(
+                    np.unique(display).astype(np.int32))
+            for slot, path in enumerate(plan.filt_paths):
+                sg = sidx.sg_by_path.get(path)
+                if sg is None or sg.filters is None:
+                    return None
+                ckey = _filter_const_key(sg.filters)
+                allowed = filt_cache.get(ckey)
+                if allowed is None:
+                    allowed = ex.filter_set(sg.filters)
+                    if allowed is None:
+                        return None
+                    filt_cache[ckey] = allowed
+                filt_lists[slot].append(allowed)
 
-    import time as _time
+        # budget gate before the device is committed to the fused program
+        deadline.checkpoint("kernel")
+        METRICS.inc("kernel_group_launches_total", family="tree")
+        METRICS.inc("kernel_group_queries_total", float(B), family="tree")
+        METRICS.inc("kernel_padded_lanes_total", float(lanes - B),
+                    family="tree")
+        _note_kernel_features("*", "tree", lanes, lanes - B,
+                              len(plan.stages), B)
+        fn = _tree_kernel_for(store, plan, rels, n, W)
+        seeds = tuple(jax.device_put(_pack_global(n, lst, lanes))
+                      for lst in seed_lists)
+        filts = tuple(jax.device_put(_pack_global(n, lst, lanes))
+                      for lst in filt_lists)
 
-    from dgraph_tpu.engine.batch import _note_kernel_features
-    from dgraph_tpu.utils import costprofile, deadline, tracing
-    from dgraph_tpu.utils.jitcache import jit_call
-    from dgraph_tpu.utils.metrics import METRICS
-    # budget gate before the device is committed to the fused program
-    deadline.checkpoint("kernel")
-    METRICS.inc("kernel_group_launches_total", family="tree")
-    METRICS.inc("kernel_group_queries_total", float(B), family="tree")
-    METRICS.inc("kernel_padded_lanes_total", float(lanes - B),
-                family="tree")
-    _note_kernel_features("*", "tree", lanes, lanes - B,
-                          len(plan.stages), B)
-    fn, stage_descs = _tree_kernel_for(store, plan, rels, n, W)
-    t_exec = _time.perf_counter()
+    recurse = [i for i, s in enumerate(plan.stages) if s.kind == "recurse"]
     with tracing.span("batch.tree_kernel", stages=len(plan.stages),
-                      queries=B, lanes=lanes, padded_lanes=lanes - B):
-        with jit_call("treebatch.tree_kernel", (plan.sig, W, n)):
-            outs = fn(tuple(jax.device_put(m) for m in seeds_np),
-                      tuple(jax.device_put(m) for m in filts_np))
+                      queries=B, lanes=lanes,
+                      padded_lanes=lanes - B) as ksp:
+        with tracing.span("batch.device_wait", phase=True,
+                          stages=len(plan.stages)):
+            with jit_call("treebatch.tree_kernel", (plan.sig, W, n)):
+                outs = fn(seeds, filts)
+            # the dispatch returns at once: the span ends when the host
+            # holds what every recurse stage counted (two int32[lanes] a
+            # stage), or, where no stage counts, when the masks are done
+            tallies = jax.device_get([outs[i][1:3] for i in recurse])
+            if not recurse:
+                jax.block_until_ready(outs)
+        with tracing.span("batch.fetch", phase=True) as sp:
+            # bit tests against these masks rebuild the edge rows of the
+            # levels a block renders; a recurse stage's set stays on the
+            # device until a consumer asks for a column of it
+            launch = _Launch()
+            for i, (s, o) in enumerate(zip(plan.stages, outs)):
+                if s.kind == "recurse":
+                    launch.seen_dev[i] = o[0]
+                    launch.perm_order[i] = rels[s.attr, s.reverse].perm_order
+                    if s.keep_hops:
+                        launch.hops[i] = np.asarray(o[3])
+                else:
+                    launch.masks[i] = np.asarray(o)
+            sp.attrs["bytes"] = sum(
+                m.nbytes for d in (launch.masks, launch.hops)
+                for m in d.values())
     # launch count + dispatch gap are recorded by jit_call itself
-    costprofile.add_kernel(
-        "tree", execute_us=(_time.perf_counter() - t_exec) * 1e6)
+    costprofile.add_kernel("tree", execute_us=ksp.dur_us)
+    for i, (count, edges) in zip(recurse, tallies):
+        launch.counts[i] = count
+        # the north star's traversed edges: a lane's sum fits int32, the
+        # lanes' sum need not
+        METRICS.inc("kernel_edges_traversed_total",
+                    float(edges[:B].astype(np.int64).sum()), family="tree")
 
-    # one host transfer per stage output; bit tests against these masks
-    # rebuild every query's edge rows
-    masks: list = []
-    for s, o in zip(plan.stages, outs):
-        if s.kind == "recurse" and s.keep_hops:
-            seen, hops = o
-            masks.append((np.asarray(seen), np.asarray(hops)))
-        else:
-            masks.append((np.asarray(o), None))
-
-    out_json = []
-    for q, blocks in enumerate(plan.queries):
-        ex = _MaskedExecutor(store, q, idx_per_query[q], masks,
-                             root_displays[q],
-                             device_threshold=device_threshold)
-        results: dict[int, LevelNode] = {}
-        for bi in execution_order(blocks):
-            ex._path = (bi,)
-            results[bi] = ex.run_block(blocks[bi])
-        roots = [results[bi] for bi in range(len(blocks))]
-        out_json.append(to_json(ex, roots))
+    with tracing.span("batch.render", phase=True, queries=B):
+        out_json = []
+        for q, blocks in enumerate(plan.queries):
+            ex = _MaskedExecutor(store, q, idx_per_query[q], launch,
+                                 root_displays[q],
+                                 device_threshold=device_threshold)
+            results: dict[int, LevelNode] = {}
+            for bi in execution_order(blocks):
+                ex._path = (bi,)
+                results[bi] = ex.run_block(blocks[bi])
+            roots = [results[bi] for bi in range(len(blocks))]
+            out_json.append(to_json(ex, roots))
     return out_json
 
 
@@ -448,8 +515,11 @@ def _tree_kernel_for(store, plan: TreePlan, rels, n: int, W: int):
                     [g.perm_order, [n]]).astype(np.int32)
                 out_idx = np.concatenate(
                     [g.new_of_old, [n]]).astype(np.int32)
+                # out-degrees by permuted row, in integers, for a
+                # recurse stage's traversed-edge count
                 devs[rkey] = (jax.device_put(perm_in),
-                              jax.device_put(out_idx))
+                              jax.device_put(out_idx),
+                              jax.device_put(g.outdeg.astype(np.int32)))
             # prepare_parts is width-independent on the XLA path and the
             # pallas row padding is too — one prepped copy per flag state
             pkey = (rkey, pallas_enabled())
@@ -458,14 +528,15 @@ def _tree_kernel_for(store, plan: TreePlan, rels, n: int, W: int):
         stage_descs = []
         for s in plan.stages:
             rkey_s = (s.attr, s.reverse)
-            perm_in, out_idx = devs[rkey_s]
+            perm_in, out_idx, outdeg = devs[rkey_s]
             prepared = prep[(rkey_s, pallas_enabled())]
             stage_descs.append({
                 "kind": s.kind, "prepared": prepared, "perm_in": perm_in,
                 "out_idx": out_idx, "parent": s.parent,
+                "outdeg": outdeg if s.kind == "recurse" else None,
                 "filt": s.filt_slot, "depth": s.depth,
                 "keep_hops": s.keep_hops})
-        fns[key] = (make_ell_tree(stage_descs, n, W), stage_descs)
+        fns[key] = make_ell_tree(stage_descs, n, W)
         return fns[key]
 
 
@@ -473,17 +544,25 @@ class _MaskedExecutor(Executor):
     """Per-query engine whose uid expansions are constrained by the
     kernel's level masks: a child level's edge list is parents' CSR rows
     bit-tested against the stage mask (filters already folded in on
-    device), then ordering/pagination/vars/rendering run unchanged."""
+    device), then ordering/pagination/vars/rendering run unchanged. The
+    var of an @recurse stage that no block renders is bound to nothing
+    until a consumer reads it (`_stage_vars`)."""
 
-    def __init__(self, store, lane: int, sidx: _StageIndex, masks,
-                 root_displays=None, **kw):
+    def __init__(self, store, lane: int, sidx: _StageIndex,
+                 launch: _Launch, root_displays=None, **kw):
         super().__init__(store, **kw)
+        self._lane = lane
         self._lane_word = lane // 32
         self._lane_bit = np.uint32(1 << (lane % 32))
         self._sidx = sidx
-        self._masks = masks
+        self._launch = launch
         self._root_displays = root_displays or {}
         self._path: tuple = ()
+        # var name → the unrendered recurse stage whose reachable set it
+        # is, and the array a read of it bound (None until one did)
+        self._stage_vars: dict[str, int] = {}
+        self._stage_bound: dict[str, np.ndarray] = {}
+        self._walk_vars: set[str] = set()    # bound by _masked_recurse
 
     def root_display(self, sg: SubGraph) -> np.ndarray:
         # seed blocks evaluated their root once pre-launch; reuse it
@@ -494,8 +573,50 @@ class _MaskedExecutor(Executor):
         return super().root_display(sg)
 
     def _member(self, stage_idx: int, ranks: np.ndarray) -> np.ndarray:
-        m = self._masks[stage_idx][0]
+        m = self._launch.masks[stage_idx]
         return (m[ranks, self._lane_word] & self._lane_bit) != 0
+
+    # -- vars of unrendered recurse stages -----------------------------------
+    def _stage_of(self, name: str) -> int | None:
+        """The stage whose set `name` still stands for (a later block
+        may have bound the name anew)."""
+        stage_idx = self._stage_vars.get(name)
+        if stage_idx is None or (
+                name in self.uid_vars
+                and self.uid_vars[name] is not self._stage_bound.get(name)):
+            return None
+        return stage_idx
+
+    def _var_ranks(self, name: str) -> np.ndarray:
+        stage_idx = self._stage_of(name)
+        if stage_idx is not None:
+            METRICS.inc("tree_var_reads_total", by="column")
+            if name not in self.uid_vars:
+                self.uid_vars[name] = self._stage_bound[name] = \
+                    self._launch.column(stage_idx, self._lane)
+        elif name in self._walk_vars and name in self.uid_vars:
+            METRICS.inc("tree_var_reads_total", by="edge_walk")
+        return super()._var_ranks(name)
+
+    def _run_block(self, sg: SubGraph) -> LevelNode:
+        # `q(func: uid(v)) { count(uid) }` over an unrendered stage's set:
+        # the lane's count is the whole answer, and no node is named
+        var = _pure_chain_root(sg)
+        stage_idx = self._stage_of(var) if var is not None else None
+        if (stage_idx is not None and sg.children and not sg.var_name
+                and sg.recurse is None and sg.msgpass is None
+                and all(c.is_count and c.is_uid_leaf and not c.var_name
+                        for c in sg.children)):
+            METRICS.inc("tree_var_reads_total", by="count")
+            return LevelNode(
+                sg=sg, nodes=EMPTY, display=EMPTY,
+                leaf_sgs=list(sg.children),
+                count=int(self._launch.counts[stage_idx][self._lane]))
+        if sg.recurse is not None and self._path in self._sidx.by_path:
+            # the launch ran this block's hops: no fused program of the
+            # block's own runs them again
+            return self._run_staged(sg)
+        return super()._run_block(sg)
 
     # -- expansion override --------------------------------------------------
     def _level_edges(self, sg: SubGraph, frontier: np.ndarray):
@@ -523,12 +644,13 @@ class _MaskedExecutor(Executor):
         sg = parent.sg
         if sg.recurse is not None:
             stage_idx = self._sidx.by_path.get(self._path)
-            if stage_idx is not None and \
-                    self._masks[stage_idx][1] is not None:
+            if stage_idx is None:
+                from dgraph_tpu.engine.recurse import expand_recurse
+                expand_recurse(self, parent)
+            elif stage_idx in self._launch.hops:
                 self._masked_recurse(parent, stage_idx)
-                return
-            from dgraph_tpu.engine.recurse import expand_recurse
-            expand_recurse(self, parent)
+            else:
+                self._unrendered_recurse(parent, stage_idx)
             return
         child_i = 0
         base_path = self._path
@@ -557,7 +679,7 @@ class _MaskedExecutor(Executor):
              else data.leaf_sgs).append(c)
         esg = data.edge_sgs[0]
         rel = self.store.rel(esg.attr, esg.is_reverse)
-        _seen, hops = self._masks[stage_idx]
+        hops = self._launch.hops[stage_idx]
         w, bit = self._lane_word, self._lane_bit
 
         parents = root.nodes
@@ -583,4 +705,29 @@ class _MaskedExecutor(Executor):
         data.all_nodes = np.unique(
             np.concatenate(all_nodes)).astype(np.int32)
         _bind_recurse_vars(self, root, data, sg)
+        if sg.var_name:
+            self._walk_vars.add(sg.var_name)
+        root.recurse_data = data
+
+    def _unrendered_recurse(self, root: LevelNode, stage_idx: int) -> None:
+        """A `var` @recurse block: nothing of it is rendered, so nothing
+        is walked. Its uid var stands for the stage's set until read
+        (_var_ranks, _run_block); a value var on one of its leaves binds
+        over every visited node and so reads the column now."""
+        from dgraph_tpu.engine.recurse import (RecurseData,
+                                               _bind_recurse_vars,
+                                               split_children)
+        sg = root.sg
+        data = split_children(self, sg, RecurseData(loop=False))
+        if any(leaf.var_name for leaf in data.leaf_sgs):
+            METRICS.inc("tree_var_reads_total", by="column")
+            data.all_nodes = self._launch.column(stage_idx, self._lane)
+            _bind_recurse_vars(self, root, data, sg)
+            if sg.var_name:
+                self._stage_bound[sg.var_name] = data.all_nodes
+        elif sg.var_name:
+            # _run_staged bound the name to the block's roots
+            self.uid_vars.pop(sg.var_name, None)
+        if sg.var_name:
+            self._stage_vars[sg.var_name] = stage_idx
         root.recurse_data = data

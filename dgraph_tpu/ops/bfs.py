@@ -440,36 +440,33 @@ def _ell_hop(prepared, frontier, W, dtype=jnp.uint32):
 COUNT_BLK = 1 << 15   # edge-counter node-block rows (bounds unpack memory)
 
 
-def _count_mask(mask, outdeg_pad, n, W, word_bits):
-    """Per-lane out-degree mass of a packed mask: unpack lane bits and
-    matvec on the MXU (f32 exact while each lane's TOTAL stays under
-    2^24 — the per-run analog of the old per-hop bound; int32 out).
-    Blocked over node rows so the unpack never materialises n·B floats.
-
-    Exactness on the chip: a TPU may run a float32 `@` in bfloat16
-    passes, and an out-degree above 256 is not a bfloat16 integer. On
-    the installed jax/libtpu this vector-matrix product measured EXACT
-    at default precision (v5e, out-degrees to 858, 64 to 4096 lanes —
-    PR 21), so no precision is forced here; chip_smoke.py's kernels
-    phase holds these counters to numpy's integers on every run, and
-    `precision=lax.Precision.HIGHEST` is the repair if it ever fails."""
-    n_pad = outdeg_pad.shape[0]
-    nblk = n_pad // COUNT_BLK
-    fpad = jnp.concatenate(
-        [mask[:n], jnp.zeros((n_pad - n, W), mask.dtype)])
+def _lane_sums(mask, weights, n, W, word_bits):
+    """int32[lanes]: per lane, the sum of `weights` (int32[n]; None for
+    all ones, the lane's population count) over the rows of mask[:n] that
+    carry the lane's bit. Unpacked and accumulated in integers, a block
+    of COUNT_BLK rows a turn, so nothing of n x lanes is ever held and
+    the sum is exact wherever it fits int32. (A float32 matvec on the MXU
+    is exact only while a lane's total stays under 2^24, and a lane of a
+    3-hop over a Kronecker graph sums out-degrees past 4*10^7; on the
+    v5e both sums of a 2.4 M-row mask take 0.4 ms, PR 34.)"""
+    pad = -max(n, 1) % COUNT_BLK
+    fpad = jnp.concatenate([mask[:n], jnp.zeros((pad, W), mask.dtype)])
+    if weights is not None:
+        weights = jnp.concatenate(
+            [weights.astype(jnp.int32), jnp.zeros((pad,), jnp.int32)])
     shifts = jnp.arange(word_bits, dtype=mask.dtype)
 
     def body(i, acc):
         sl = lax.dynamic_slice_in_dim(fpad, i * COUNT_BLK, COUNT_BLK, 0)
-        od = lax.dynamic_slice_in_dim(outdeg_pad, i * COUNT_BLK,
-                                      COUNT_BLK, 0)
         bits = ((sl[:, :, None] >> shifts) & mask.dtype.type(1)
-                ).astype(jnp.float32).reshape(COUNT_BLK, W * word_bits)
-        return acc + od @ bits
+                ).astype(jnp.int32).reshape(COUNT_BLK, W * word_bits)
+        if weights is not None:
+            bits = bits * lax.dynamic_slice_in_dim(
+                weights, i * COUNT_BLK, COUNT_BLK, 0)[:, None]
+        return acc + bits.sum(axis=0, dtype=jnp.int32)
 
-    out = lax.fori_loop(0, nblk, body,
-                        jnp.zeros((W * word_bits,), jnp.float32))
-    return out.astype(jnp.int32)
+    return lax.fori_loop(0, fpad.shape[0] // COUNT_BLK, body,
+                         jnp.zeros((W * word_bits,), jnp.int32))
 
 
 def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
@@ -482,10 +479,7 @@ def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
     prepared = prepare_parts(dev, W)
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
     if count_edges:
-        nblk = -(-max(n, 1) // COUNT_BLK)
-        outdeg_pad = jnp.concatenate(
-            [jnp.asarray(outdeg),
-             jnp.zeros((nblk * COUNT_BLK - n,), jnp.float32)])
+        outdeg = jnp.asarray(outdeg).astype(jnp.int32)
 
     @functools.partial(jax.jit, donate_argnums=(0,),
                        static_argnames=("depth", "keep_hops"))
@@ -500,10 +494,10 @@ def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
         (last, seen), hops = lax.scan(
             hop, (mask0, mask0), None, length=depth)
         if count_edges:
-            # exact per-lane counters from the final masks (one matvec)
-            # — identical integers to the per-hop accumulation because
-            # first-visit frontiers partition seen \ last
-            edges = _count_mask(seen & ~last, outdeg_pad, n, W, word_bits)
+            # exact per-lane counters from the final masks — identical
+            # integers to the per-hop accumulation because first-visit
+            # frontiers partition seen \ last
+            edges = _lane_sums(seen & ~last, outdeg, n, W, word_bits)
         else:
             edges = jnp.zeros((W * word_bits,), jnp.int32)
         if keep_hops:
@@ -737,7 +731,7 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
     level of every lane is one stage of this program, and filters are
     bitmask ANDs instead of per-uid IntersectSorted calls.
 
-    All masks live in the STORE's global rank space, shape [n+1, W]
+    Hop masks live in the STORE's global rank space, shape [n+1, W]
     (row n = sentinel, always zero). Each stage's EllGraph has its own
     degree-class permutation, so a stage translates its parent mask into
     its own permuted space (one row gather), does the ELL pull-hop, and
@@ -749,59 +743,84 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
       prepared  prepare_parts output for the stage's EllGraph
       perm_in   [n+1] int32 device: permuted row r ← global perm_in[r]
       out_idx   [n+1] int32 device: global row v ← permuted out_idx[v]
+      outdeg    recurse only: [n] int32 device, out-degrees in the
+                stage's permuted space
       parent    ("seed", slot) | ("stage", idx earlier in the list)
       filt      filter-mask slot index | None  (global space, ANDed in)
       depth     recurse only: hop count (static)
       keep_hops recurse only: also return per-hop first-visit masks
 
     Returns fn(seeds: tuple, filts: tuple) → tuple with one entry per
-    stage: hop → mask [n+1, W]; recurse → seen [n+1, W] (reachable set
-    incl. seeds) or (seen, hops [depth, n+1, W]) when keep_hops. The
-    seed and filter masks are DONATED (consumed by the first gather).
+    stage: hop → mask [n+1, W]; recurse → (seen, count, edges, hops):
+    `seen` [n+1, W] the reachable set incl. seeds, in the stage's
+    PERMUTED space (a consumer that wants a lane's members tests its
+    column and maps the rows through perm_order: no translation is run
+    for a set nobody reads); `count` int32[lanes] its population count a
+    lane; `edges` int32[lanes] the out-degree mass of the rows expanded
+    (seen less the last hop's fresh rows), the lane's traversed edges;
+    `hops` [depth, n+1, W] global-space first-visit masks when
+    keep_hops, else None. The seed and filter masks are DONATED
+    (consumed by the first gather).
+
+    The stages' index blocks ride as arguments, as make_ell_step's do: a
+    device array that a jitted function closes over is a constant of its
+    program (15 s of a first call for every 180 MB, held twice; PR 30).
     """
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
+    leaves, treedef = jax.tree_util.tree_flatten(
+        [(s["prepared"], s["perm_in"], s["out_idx"], s.get("outdeg"))
+         for s in stages])
+    held = [x for x in leaves if isinstance(x, jax.Array)]
+    # a recurse stage's set is translated to global space only for a
+    # later stage that expands it
+    chained = {s["parent"][1] for s in stages if s["parent"][0] == "stage"}
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def run(seeds, filts):
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def tree(arrays, seeds, filts):
+        it = iter(arrays)
+        blocks = treedef.unflatten(
+            [next(it) if isinstance(x, jax.Array) else x for x in leaves])
         outs = []
         results = []
-        for s in stages:
+        for i, (s, (prepared, perm_in, out_idx, outdeg)) in enumerate(
+                zip(stages, blocks)):
             kind, par = s["kind"], s["parent"]
             parent = (seeds[par[1]] if par[0] == "seed"
                       else outs[par[1]])
             filt = filts[s["filt"]] if s["filt"] is not None else None
-            pm = parent[s["perm_in"]]            # global → permuted
+            pm = parent[perm_in]                 # global → permuted
             if kind == "hop":
-                out = _ell_hop(s["prepared"], pm, W,
-                               dtype)[s["out_idx"]]
+                out = _ell_hop(prepared, pm, W, dtype)[out_idx]
                 if filt is not None:
                     out = out & filt
                 outs.append(out)
                 results.append(out)
                 continue
             # recurse: iterate in permuted space (no per-hop translation)
-            filt_p = filt[s["perm_in"]] if filt is not None else None
+            filt_p = filt[perm_in] if filt is not None else None
+            keep_hops = s["keep_hops"]
 
-            def hop(carry, _, _prep=s["prepared"], _filt_p=filt_p):
+            def hop(carry, _, _prep=prepared, _filt_p=filt_p,
+                    _keep=keep_hops):
                 frontier, seen = carry
                 nxt = _ell_hop(_prep, frontier, W, dtype)
                 fresh = nxt & ~seen
                 if _filt_p is not None:
                     fresh = fresh & _filt_p
                 seen = seen | fresh
-                return (fresh, seen), (fresh if s["keep_hops"] else None)
+                return (fresh, seen), (fresh if _keep else None)
 
-            (_last, seen_p), hops_p = lax.scan(
+            (last, seen_p), hops_p = lax.scan(
                 hop, (pm, pm), None, length=s["depth"])
-            seen = seen_p[s["out_idx"]]
-            outs.append(seen)
-            if s["keep_hops"]:
-                results.append((seen, hops_p[:, s["out_idx"]]))
-            else:
-                results.append(seen)
+            outs.append(seen_p[out_idx] if i in chained else None)
+            results.append((
+                seen_p,
+                _lane_sums(seen_p, None, n, W, word_bits),
+                _lane_sums(seen_p & ~last, outdeg, n, W, word_bits),
+                hops_p[:, out_idx] if keep_hops else None))
         return tuple(results)
 
-    return run
+    return functools.partial(tree, held)
 
 
 def ell_recurse(g: EllGraph, mask0, depth: int, count_edges: bool = True):

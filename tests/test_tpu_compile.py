@@ -56,3 +56,17 @@ def test_set_rows_compiles_for_the_v5e_without_a_sort(one_chip, cap):
     c = _compiled(one_chip, lambda act: bfs._set_rows(act, N, cap),
                   ((N,), jnp.bool_))
     assert " sort(" not in c.as_text()
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_lane_sums_compile_for_the_v5e_at_graph500_22(one_chip, weighted):
+    """The tree program's per-lane integer sums (a count of `seen`, the
+    out-degree mass of the rows expanded) at graph500-22's 2.4 M rows and
+    64 lanes: a block of rows a turn, so nothing of n x lanes is held."""
+    from dgraph_tpu.ops import bfs
+    n = 2395982
+    shapes = [((n + 1, W), jnp.uint32)] + [((n,), jnp.int32)] * weighted
+    c = _compiled(one_chip,
+                  lambda m, w=None: bfs._lane_sums(m, w, n, W, 32), *shapes)
+    assert c.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert c.memory_analysis().output_size_in_bytes <= 4 * 32 * W + 1024
