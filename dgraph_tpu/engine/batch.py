@@ -1068,16 +1068,39 @@ def _recurse_for(store, attr: str, reverse: bool, W: int):
         return fns[key]
 
 
+def _dev_with_out(store, attr: str, reverse: bool):
+    """_dev_for, with the relation's out-CSR in the ELL's row space beside
+    the blocks (DeviceEll.out): what a pushed hop reads, in the lane step
+    and in a recurse stage of the tree program alike. Built and uploaded
+    once, the first time either is asked for, into the DeviceEll that
+    `batch.ell_dev` governs."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import out_csr
+
+    g, dev = _dev_for(store, attr, reverse)
+    if dev is None or dev.out is not None:  # hot path: no lock
+        return g, dev
+    with _cache_lock:
+        if dev.out is None:
+            rel = store.rel(attr, reverse)
+            with tracing.span("batch.build_ell", phase=True, pred=attr,
+                              reverse=reverse, part="out_csr"):
+                out = out_csr(g, rel.indptr, rel.indices)
+            with tracing.span("batch.upload_ell", phase=True, pred=attr,
+                              reverse=reverse, part="out_csr") as sp:
+                dev.out = jax.block_until_ready(jax.device_put(out))
+                sp.attrs["bytes"] = memgov.estimate_nbytes(dev.out)
+    memgov.GOVERNOR.maybe_evict("device")
+    return g, dev
+
+
 def _step_for(store, attr: str, reverse: bool, W: int,
               first_visit: bool):
     """Compiled resumable hop block per (snapshot, pred, dir, width,
     family) — the staged shortest path's kernel, donated carries. Its
-    pushed hops read the relation's out-CSR in the ELL's row space: built
-    and uploaded here, the first time a step is asked for, into the
-    DeviceEll that `batch.ell_dev` governs."""
-    import jax
-
-    from dgraph_tpu.ops.bfs import make_ell_step, out_csr
+    pushed hops read the relation's out-CSR (_dev_with_out)."""
+    from dgraph_tpu.ops.bfs import make_ell_step
     from dgraph_tpu.ops.pallas_hop import pallas_enabled
 
     host = _cache_host(store, attr, reverse)
@@ -1085,7 +1108,7 @@ def _step_for(store, attr: str, reverse: bool, W: int,
     fns = getattr(host, "_ell_fns", None)
     if fns is not None and key in fns:  # hot path: no lock
         return fns[key]
-    g, dev = _dev_for(store, attr, reverse)
+    g, dev = _dev_with_out(store, attr, reverse)
     with _cache_lock:
         fns = getattr(host, "_ell_fns", None)
         if fns is None:
@@ -1093,15 +1116,6 @@ def _step_for(store, attr: str, reverse: bool, W: int,
             _governed_host_cache(host, "_ell_fns", "batch.kernel",
                                  "host", lambda v: _KERNEL_NBYTES_EST)
         if key not in fns:
-            if dev.out is None:
-                rel = store.rel(attr, reverse)
-                with tracing.span("batch.build_ell", phase=True, pred=attr,
-                                  reverse=reverse, part="out_csr"):
-                    out = out_csr(g, rel.indptr, rel.indices)
-                with tracing.span("batch.upload_ell", phase=True, pred=attr,
-                                  reverse=reverse, part="out_csr") as sp:
-                    dev.out = jax.block_until_ready(jax.device_put(out))
-                    sp.attrs["bytes"] = memgov.estimate_nbytes(dev.out)
             fns[key] = make_ell_step(dev, g.n, W, SHORTEST_STAGE,
                                      first_visit=first_visit)
         return fns[key]

@@ -419,8 +419,9 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
                 outs = fn(seeds, filts)
             # the dispatch returns at once: the span ends when the host
             # holds what every recurse stage counted (two int32[lanes] a
-            # stage), or, where no stage counts, when the masks are done
-            tallies = jax.device_get([outs[i][1:3] for i in recurse])
+            # stage, and how many of its hops pushed), or, where no stage
+            # counts, when the masks are done
+            tallies = jax.device_get([outs[i][1:4] for i in recurse])
             if not recurse:
                 jax.block_until_ready(outs)
         with tracing.span("batch.fetch", phase=True) as sp:
@@ -433,7 +434,7 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
                     launch.seen_dev[i] = o[0]
                     launch.perm_order[i] = rels[s.attr, s.reverse].perm_order
                     if s.keep_hops:
-                        launch.hops[i] = np.asarray(o[3])
+                        launch.hops[i] = np.asarray(o[4])
                 else:
                     launch.masks[i] = np.asarray(o)
             sp.attrs["bytes"] = sum(
@@ -441,8 +442,13 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
                 for m in d.values())
     # launch count + dispatch gap are recorded by jit_call itself
     costprofile.add_kernel("tree", execute_us=ksp.dur_us)
-    for i, (count, edges) in zip(recurse, tallies):
+    for i, (count, edges, pushed) in zip(recurse, tallies):
         launch.counts[i] = count
+        # the device's own count of the stage's hops that pushed over the
+        # frontier's out-edges, of the `depth` it ran
+        METRICS.inc("kernel_hops_run_total", float(plan.stages[i].depth),
+                    family="tree")
+        METRICS.inc("kernel_hops_push_total", float(pushed), family="tree")
         # the north star's traversed edges: a lane's sum fits int32, the
         # lanes' sum need not
         METRICS.inc("kernel_edges_traversed_total",
@@ -489,14 +495,20 @@ def _tree_kernel_for(store, plan: TreePlan, rels, n: int, W: int):
     vectors shared across signatures."""
     import jax
 
-    from dgraph_tpu.engine.batch import _cache_host, _cache_lock, _dev_for
+    from dgraph_tpu.engine.batch import (_cache_host, _cache_lock, _dev_for,
+                                         _dev_with_out)
     from dgraph_tpu.ops.bfs import make_ell_tree, prepare_parts
     from dgraph_tpu.ops.pallas_hop import pallas_enabled
 
     hosts = {_cache_host(store, a, r) for a, r in rels}
     host = hosts.pop() if len(hosts) == 1 else store
     key = (plan.sig, W, pallas_enabled())
-    devells = {rkey: _dev_for(store, *rkey)[1] for rkey in rels}
+    # a relation that a recurse stage expands brings its out-CSR, for the
+    # stage's pushed hops: the one the lane step of the same relation reads
+    recursed = {(s.attr, s.reverse) for s in plan.stages
+                if s.kind == "recurse"}
+    devells = {rkey: (_dev_with_out if rkey in recursed else _dev_for)(
+        store, *rkey)[1] for rkey in rels}
     with _cache_lock:
         fns = getattr(host, "_tree_fns", None)
         if fns is None:
@@ -515,11 +527,8 @@ def _tree_kernel_for(store, plan: TreePlan, rels, n: int, W: int):
                     [g.perm_order, [n]]).astype(np.int32)
                 out_idx = np.concatenate(
                     [g.new_of_old, [n]]).astype(np.int32)
-                # out-degrees by permuted row, in integers, for a
-                # recurse stage's traversed-edge count
                 devs[rkey] = (jax.device_put(perm_in),
-                              jax.device_put(out_idx),
-                              jax.device_put(g.outdeg.astype(np.int32)))
+                              jax.device_put(out_idx))
             # prepare_parts is width-independent on the XLA path and the
             # pallas row padding is too — one prepped copy per flag state
             pkey = (rkey, pallas_enabled())
@@ -528,12 +537,12 @@ def _tree_kernel_for(store, plan: TreePlan, rels, n: int, W: int):
         stage_descs = []
         for s in plan.stages:
             rkey_s = (s.attr, s.reverse)
-            perm_in, out_idx, outdeg = devs[rkey_s]
+            perm_in, out_idx = devs[rkey_s]
             prepared = prep[(rkey_s, pallas_enabled())]
             stage_descs.append({
                 "kind": s.kind, "prepared": prepared, "perm_in": perm_in,
                 "out_idx": out_idx, "parent": s.parent,
-                "outdeg": outdeg if s.kind == "recurse" else None,
+                "out": devells[rkey_s].out if s.kind == "recurse" else None,
                 "filt": s.filt_slot, "depth": s.depth,
                 "keep_hops": s.keep_hops})
         fns[key] = make_ell_tree(stage_descs, n, W)

@@ -273,9 +273,12 @@ class DeviceEll:
     tiles: object          # device [M, seg_tile] | None
     lvl2: list             # device [h_b, K2] blocks
     seg_rows: int
-    # the same edges by SOURCE row, for make_ell_step's pushed hops
-    # (out_csr, on the device); None until a step program is asked for,
-    # so the pull-only families (recurse, tree) never pay for it
+    # the same edges by SOURCE row, for the pushed hops of make_ell_step
+    # and of make_ell_tree's recurse stages (out_csr, on the device; its
+    # third array is the rows' out-degrees). None until one of the two is
+    # asked for (engine/batch.py _dev_with_out builds it once, for both),
+    # so the pull-only programs (make_ell_recurse, a tree of hop stages)
+    # never pay for it
     out: object = None
 
 
@@ -607,6 +610,32 @@ def _push_hop(out, f, act, n, W, dtype, word_bits, f_cap, chunk):
         dtype(0), lax.bitwise_or, (2,))
 
 
+def _pull_or_push(prepared, out, f, caps, n, W, dtype, word_bits):
+    """One hop of a lane program, computed one of two exact ways chosen
+    on the device from the frontier `f` it is handed: (next mask [n+1, W],
+    whether it was pushed). A PUSH over the frontier's own out-edges
+    (`out`, _push_hop) when `caps` (row cap, slot cap, slots a turn) hold
+    its rows with a bit and an out-edge, the sum of their out-degrees and
+    the largest of them, else the PULL over every stored in-edge
+    (_ell_hop). Caps that hold no row compile no push: every hop pulls."""
+    f_cap, e_cap, chunk = caps
+    pull = functools.partial(_ell_hop, prepared, f, W, dtype)
+    if not f_cap:
+        return pull(), False
+    outdeg = out[2]
+    act = (f[:n] != 0).any(axis=1) & (outdeg > 0)
+    degs = jnp.where(act, outdeg, 0)
+    fits = ((act.sum(dtype=jnp.int32) <= f_cap)
+            & (degs.sum(dtype=jnp.int32) <= e_cap)
+            & (degs.max() <= chunk))
+    nxt = lax.cond(
+        fits,
+        lambda: _push_hop(out, f, act, n, W, dtype, word_bits, f_cap,
+                          chunk),
+        pull)
+    return nxt, fits
+
+
 def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
                   word_bits: int = 32, first_visit: bool = True,
                   caps: tuple | None = None):
@@ -642,13 +671,9 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
     contract the README documents.
 
     Each hop computes the same next mask one of two exact ways, chosen
-    on the device from the frontier it is handed: a PUSH over the
-    frontier's own out-edges (`dev.out`, _push_hop) when `caps` (row cap,
-    slot cap, slots a turn) hold its rows with an out-edge, the sum of
-    their out-degrees and the largest of them, else the PULL over every
-    stored in-edge (_ell_hop). `pushed` counts the hops of this call
-    that pushed. `caps` is push_caps of the relation's edges; tests pass
-    their own.
+    on the device from the frontier it is handed (_pull_or_push over
+    `dev.out`). `pushed` counts the hops of this call that pushed. `caps`
+    is push_caps of the relation's edges; tests pass their own.
 
     `first_visit=False` drops the seen-masking: hops[h] is then the FULL
     set reachable in exactly h+1 hops (the level-DAG the k-shortest
@@ -656,7 +681,7 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
     lane closes only when its frontier is exhausted: that program makes
     no use of `near`, and its caller passes None."""
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
-    f_cap, e_cap, chunk = caps or push_caps(int(dev.out[1].shape[0]))
+    caps = caps or push_caps(int(dev.out[1].shape[0]))
     # The index blocks ride as arguments. A device array that a jitted
     # function closes over is a constant of its program: fetched to the
     # host, compiled in and uploaded again, 15 s of the first call for the
@@ -676,7 +701,6 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def step(arrays, frontier, seen, near, open_lanes, limit):
         prepared, out = blocks(arrays)
-        outdeg = out[2]
 
         def more(carry):
             _f, _s, _buf, ran, _pushed, open_ = carry
@@ -684,21 +708,9 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
 
         def hop(carry):
             f, s, buf, ran, pushed, open_ = carry
-            pull = functools.partial(_ell_hop, prepared, f, W, dtype)
-            if f_cap:
-                act = (f[:n] != 0).any(axis=1) & (outdeg > 0)
-                degs = jnp.where(act, outdeg, 0)
-                fits = ((act.sum(dtype=jnp.int32) <= f_cap)
-                        & (degs.sum(dtype=jnp.int32) <= e_cap)
-                        & (degs.max() <= chunk))
-                nxt = lax.cond(
-                    fits,
-                    lambda: _push_hop(out, f, act, n, W, dtype,
-                                      word_bits, f_cap, chunk),
-                    pull)
-                pushed = pushed + fits
-            else:                   # caps that hold no row: every hop pulls
-                nxt = pull()
+            nxt, fits = _pull_or_push(prepared, out, f, caps, n, W, dtype,
+                                      word_bits)
+            pushed = pushed + fits
             if first_visit:
                 fresh = nxt & ~s
                 s = s | fresh
@@ -734,7 +746,7 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
     Hop masks live in the STORE's global rank space, shape [n+1, W]
     (row n = sentinel, always zero). Each stage's EllGraph has its own
     degree-class permutation, so a stage translates its parent mask into
-    its own permuted space (one row gather), does the ELL pull-hop, and
+    its own permuted space (one row gather), does the ELL hop, and
     translates back (one row gather) — both translations stream
     sequentially and are noise next to the edge gather.
 
@@ -743,34 +755,49 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
       prepared  prepare_parts output for the stage's EllGraph
       perm_in   [n+1] int32 device: permuted row r ← global perm_in[r]
       out_idx   [n+1] int32 device: global row v ← permuted out_idx[v]
-      outdeg    recurse only: [n] int32 device, out-degrees in the
-                stage's permuted space
+      out       recurse only: the relation's out-CSR in the stage's
+                permuted space (DeviceEll.out: indptr, indices, and the
+                rows' out-degrees [n] int32), for the pushed hops and
+                the traversed-edge count
+      caps      recurse only: (row cap, slot cap, slots a turn) of a
+                pushed hop; None for push_caps of the relation's edges
+                (tests pass their own)
       parent    ("seed", slot) | ("stage", idx earlier in the list)
       filt      filter-mask slot index | None  (global space, ANDed in)
       depth     recurse only: hop count (static)
       keep_hops recurse only: also return per-hop first-visit masks
 
+    A hop stage pulls. Each hop of a recurse stage's scan computes its
+    next mask one of the two exact ways of make_ell_step's hops, chosen
+    on the device from the frontier it is handed (_pull_or_push): hop 1
+    of a k-hop expands its seeds' own out-edges, and a later hop pulls
+    over every stored in-edge once its frontier is over a cap.
+
     Returns fn(seeds: tuple, filts: tuple) → tuple with one entry per
-    stage: hop → mask [n+1, W]; recurse → (seen, count, edges, hops):
-    `seen` [n+1, W] the reachable set incl. seeds, in the stage's
+    stage: hop → mask [n+1, W]; recurse → (seen, count, edges, pushed,
+    hops): `seen` [n+1, W] the reachable set incl. seeds, in the stage's
     PERMUTED space (a consumer that wants a lane's members tests its
     column and maps the rows through perm_order: no translation is run
     for a set nobody reads); `count` int32[lanes] its population count a
     lane; `edges` int32[lanes] the out-degree mass of the rows expanded
     (seen less the last hop's fresh rows), the lane's traversed edges;
-    `hops` [depth, n+1, W] global-space first-visit masks when
-    keep_hops, else None. The seed and filter masks are DONATED
-    (consumed by the first gather).
+    `pushed` int32, how many of the stage's `depth` hops pushed; `hops`
+    [depth, n+1, W] global-space first-visit masks when keep_hops, else
+    None. The seed and filter masks are DONATED (consumed by the first
+    gather).
 
-    The stages' index blocks ride as arguments, as make_ell_step's do: a
-    device array that a jitted function closes over is a constant of its
-    program (15 s of a first call for every 180 MB, held twice; PR 30).
+    The stages' index blocks and out-CSRs ride as arguments, as
+    make_ell_step's do: a device array that a jitted function closes
+    over is a constant of its program (15 s of a first call for every
+    180 MB, held twice; PR 30).
     """
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
     leaves, treedef = jax.tree_util.tree_flatten(
-        [(s["prepared"], s["perm_in"], s["out_idx"], s.get("outdeg"))
+        [(s["prepared"], s["perm_in"], s["out_idx"], s.get("out"))
          for s in stages])
     held = [x for x in leaves if isinstance(x, jax.Array)]
+    caps = [s.get("caps") or push_caps(int(s["out"][1].shape[0]))
+            if s["kind"] == "recurse" else None for s in stages]
     # a recurse stage's set is translated to global space only for a
     # later stage that expands it
     chained = {s["parent"][1] for s in stages if s["parent"][0] == "stage"}
@@ -782,7 +809,7 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
             [next(it) if isinstance(x, jax.Array) else x for x in leaves])
         outs = []
         results = []
-        for i, (s, (prepared, perm_in, out_idx, outdeg)) in enumerate(
+        for i, (s, (prepared, perm_in, out_idx, out)) in enumerate(
                 zip(stages, blocks)):
             kind, par = s["kind"], s["parent"]
             parent = (seeds[par[1]] if par[0] == "seed"
@@ -790,33 +817,36 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
             filt = filts[s["filt"]] if s["filt"] is not None else None
             pm = parent[perm_in]                 # global → permuted
             if kind == "hop":
-                out = _ell_hop(prepared, pm, W, dtype)[out_idx]
+                mask = _ell_hop(prepared, pm, W, dtype)[out_idx]
                 if filt is not None:
-                    out = out & filt
-                outs.append(out)
-                results.append(out)
+                    mask = mask & filt
+                outs.append(mask)
+                results.append(mask)
                 continue
             # recurse: iterate in permuted space (no per-hop translation)
             filt_p = filt[perm_in] if filt is not None else None
             keep_hops = s["keep_hops"]
 
-            def hop(carry, _, _prep=prepared, _filt_p=filt_p,
-                    _keep=keep_hops):
-                frontier, seen = carry
-                nxt = _ell_hop(_prep, frontier, W, dtype)
+            def hop(carry, _, _prep=prepared, _out=out, _caps=caps[i],
+                    _filt_p=filt_p, _keep=keep_hops):
+                frontier, seen, pushed = carry
+                nxt, fits = _pull_or_push(_prep, _out, frontier, _caps, n,
+                                          W, dtype, word_bits)
                 fresh = nxt & ~seen
                 if _filt_p is not None:
                     fresh = fresh & _filt_p
                 seen = seen | fresh
-                return (fresh, seen), (fresh if _keep else None)
+                return (fresh, seen, pushed + fits), (
+                    fresh if _keep else None)
 
-            (last, seen_p), hops_p = lax.scan(
-                hop, (pm, pm), None, length=s["depth"])
+            (last, seen_p, pushed), hops_p = lax.scan(
+                hop, (pm, pm, jnp.int32(0)), None, length=s["depth"])
             outs.append(seen_p[out_idx] if i in chained else None)
             results.append((
                 seen_p,
                 _lane_sums(seen_p, None, n, W, word_bits),
-                _lane_sums(seen_p & ~last, outdeg, n, W, word_bits),
+                _lane_sums(seen_p & ~last, out[2], n, W, word_bits),
+                pushed,
                 hops_p[:, out_idx] if keep_hops else None))
         return tuple(results)
 

@@ -319,7 +319,7 @@ STEP_CAPS = {"mixed": (40, 160, 24), "pull": (0, 0, 1),
 
 def _device_ell_with_out(rel):
     """The relation's ELL on the device with its out-CSR beside it, as
-    engine/batch.py _step_for leaves it."""
+    engine/batch.py _dev_with_out leaves it."""
     import jax
 
     from dgraph_tpu.ops.bfs import build_ell, device_ell, out_csr
@@ -329,14 +329,20 @@ def _device_ell_with_out(rel):
     return g, dev
 
 
-def _pushes(frontier, dev, n, caps):
-    """Whether the step pushes this frontier: its rows with an out-edge,
+def _fits(rows, deg, caps):
+    """The rule of a pushed hop: the frontier's `rows` with an out-edge,
     the sum of their out-degrees and the largest of them against the
     caps."""
-    deg = np.asarray(dev.out[2])
-    act = (frontier[:n] != 0).any(axis=1) & (deg > 0)
+    act = rows & (deg > 0)
     return bool(caps[0]) and act.sum() <= caps[0] and \
         deg[act].sum() <= caps[1] and deg[act].max(initial=0) <= caps[2]
+
+
+def _pushes(frontier, dev, n, caps):
+    """Whether the step pushes this frontier (packed, in the ELL's row
+    space)."""
+    return _fits((frontier[:n] != 0).any(axis=1), np.asarray(dev.out[2]),
+                 caps)
 
 
 @functools.lru_cache(maxsize=None)
@@ -582,3 +588,173 @@ def test_a_hub_ors_the_lane_bits_of_its_many_sources(lanes, chunk):
     want[g.new_of_old[hub], W - 1] |= np.uint32(1 << 31)
     assert np.array_equal(pushed, want)
     assert np.array_equal(pulled, want)
+
+
+# -- the tree program's recurse stage (ops/bfs.py make_ell_tree) -------------
+
+TREE_DEPTH = 3
+TREE_N, TREE_EDGES = 3000, 20000   # push_caps: 4 rows, 156 slots, 156 a turn
+
+
+@functools.lru_cache(maxsize=None)
+def _tree_graph():
+    """A random relation in which a row has 2 to 11 out-edges, big enough
+    that push_caps holds a few rows, with a few nodes no edge leaves."""
+    from dgraph_tpu.store.store import _csr_from_pairs
+    rng = np.random.default_rng(5)
+    src = rng.integers(0, TREE_N - 40, TREE_EDGES).astype(np.int32)
+    dst = rng.integers(0, TREE_N, TREE_EDGES).astype(np.int32)
+    rel = _csr_from_pairs(src, dst, TREE_N)
+    g, dev = _device_ell_with_out(rel)
+    return rel, g, dev
+
+
+def _unpacked(mask, n):
+    """bool[n, lanes] of a packed uint32 mask [n+1, W]."""
+    m = np.asarray(mask)[:n]
+    return ((m[:, :, None] >> np.arange(32, dtype=np.uint32)) & 1
+            ).astype(bool).reshape(n, -1)
+
+
+def _plain_recurse(rel, seeds, depth, allowed):
+    """A breadth-first search a lane, in numpy sets: (seen bool[n, lanes],
+    hops bool[depth, n, lanes], edges a lane). A filter keeps a hop's
+    fresh rows inside `allowed`; the seeds are seen whatever it says."""
+    n, lanes = len(rel.indptr) - 1, len(seeds)
+    deg = np.diff(rel.indptr)
+    seen = np.zeros((n, lanes), bool)
+    hops = np.zeros((depth, n, lanes), bool)
+    edges = []
+    for q, s in enumerate(seeds):
+        fresh = np.unique(s)
+        seen[fresh, q] = True
+        for h in range(depth):
+            expanded = seen[:, q].copy()         # all but the last fresh
+            nxt = np.unique(np.concatenate(
+                [rel.row(int(r)) for r in fresh] + [np.zeros(0, np.int32)]))
+            fresh = nxt[~seen[nxt, q]]
+            if allowed is not None:
+                fresh = fresh[allowed[fresh]]
+            seen[fresh, q] = True
+            hops[h, fresh, q] = True
+        edges.append(int(deg[expanded].sum()))
+    return seen, hops, edges
+
+
+def _tree_pushes(frontiers, deg, caps):
+    """Which of a stage's hops the caps make a push: one flag a hop, from
+    the frontier each hop expands (bool[n, lanes])."""
+    return [_fits(f.any(axis=1), deg, caps) for f in frontiers]
+
+
+@functools.lru_cache(maxsize=None)
+def _tree_run(caps_name, lanes, filtered, keep_hops):
+    """One launch of a one-stage tree program under the named caps:
+    (outputs as numpy, the seeds, the caps)."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import make_ell_tree, prepare_parts, push_caps
+    rel, g, dev = _tree_graph()
+    n, W = TREE_N, lanes // 32
+    deg = np.diff(rel.indptr)
+    rng = np.random.default_rng(lanes + 2 * filtered)
+    starts = np.nonzero((deg > 0) & (deg <= 8))[0]
+    if caps_name == "default":
+        # caps scaled from 20,000 edges hold four rows: the lanes share
+        # four seeds, so hop 1 fits them and hop 2 does not
+        pool = rng.choice(starts, 4, replace=False)
+        seeds = [pool[q % 4:q % 4 + 1] for q in range(lanes - 3)]
+    else:
+        seeds = [rng.choice(starts, 1 + q % 2, replace=False)
+                 for q in range(lanes - 3)]      # the last three: padding
+    seeds += [np.zeros(0, np.int64)] * 3
+    first = np.unique(np.concatenate(seeds))
+    rows1, slots1, widest1 = len(first), int(deg[first].sum()), \
+        int(deg[first].max())
+    caps = {
+        "pull": (0, 0, 1),                       # none hold a row
+        "push": (TREE_N, TREE_EDGES, int(deg.max())),    # every hop fits
+        "default": push_caps(len(rel.indices)),
+        "hop1": (rows1, slots1, widest1),        # hop 2 is over the slots
+        "turn": (TREE_N, TREE_EDGES, widest1 - 1),   # a seed over a turn
+    }[caps_name]
+    allowed = None
+    if filtered:
+        allowed = rng.random(n) < 0.6
+    seed_mask = np.zeros((n + 1, W), np.uint32)
+    for q, s in enumerate(seeds):
+        seed_mask[s, q // 32] |= np.uint32(1 << (q % 32))
+    filt_mask = np.zeros((n + 1, W), np.uint32)
+    if filtered:
+        filt_mask[:n][allowed] = 0xFFFFFFFF
+    stage = {
+        "kind": "recurse", "prepared": prepare_parts(dev, W),
+        "perm_in": jax.device_put(
+            np.concatenate([g.perm_order, [n]]).astype(np.int32)),
+        "out_idx": jax.device_put(
+            np.concatenate([g.new_of_old, [n]]).astype(np.int32)),
+        "out": dev.out, "caps": None if caps_name == "default" else caps,
+        "parent": ("seed", 0), "filt": 0 if filtered else None,
+        "depth": TREE_DEPTH, "keep_hops": keep_hops}
+    fn = make_ell_tree([stage], n, W)
+    (seen, count, edges, pushed, hops), = fn(
+        (jax.device_put(seed_mask),),
+        (jax.device_put(filt_mask),) if filtered else ())
+    got = (_unpacked(seen, n)[g.new_of_old], np.asarray(count),
+           np.asarray(edges), int(pushed),
+           None if hops is None else
+           np.stack([_unpacked(h, n) for h in np.asarray(hops)]))
+    assert not np.asarray(seen)[n].any(), "the sentinel row stays zero"
+    return got, seeds, allowed, caps
+
+
+@pytest.mark.parametrize("keep_hops", [False, True])
+@pytest.mark.parametrize("filtered", [False, True])
+@pytest.mark.parametrize("lanes", [32, 64])
+@pytest.mark.parametrize("caps_name", ["pull", "push", "default", "hop1",
+                                       "turn"])
+def test_tree_recurse_stage_pushes_what_its_caps_hold(caps_name, lanes,
+                                                      filtered, keep_hops):
+    """Whichever of its hops a recurse stage pushes, `seen`, `count`,
+    `edges` and `hops` equal the pull-only program's and a plain
+    breadth-first search's, and `pushed` counts the hops whose frontier
+    the caps hold: rows with a bit and an out-edge, their out-degrees'
+    sum, and the largest of them against a turn."""
+    rel, _g, _dev = _tree_graph()
+    deg = np.diff(rel.indptr)
+    (seen, count, edges, pushed, hops), seeds, allowed, caps = _tree_run(
+        caps_name, lanes, filtered, keep_hops)
+    want_seen, want_hops, want_edges = _plain_recurse(
+        rel, seeds, TREE_DEPTH, allowed)
+    assert np.array_equal(seen, want_seen)
+    assert count.tolist() == want_seen.sum(axis=0).tolist()
+    assert edges.tolist() == want_edges
+    assert (hops is None) == (not keep_hops)
+    if keep_hops:
+        assert np.array_equal(hops, want_hops)
+
+    first = np.zeros_like(want_seen)
+    for q, s in enumerate(seeds):
+        first[s, q] = True
+    flags = _tree_pushes([first, *want_hops[:-1]], deg, caps)
+    assert pushed == sum(flags)
+    if caps_name == "turn":
+        # rows and slots fit: the widest seed alone sends hop 1 to the pull
+        assert not flags[0] and _tree_pushes(
+            [first], deg, (caps[0], caps[1], caps[2] + 1)) == [True]
+    else:
+        assert flags == {"pull": [False] * 3, "push": [True] * 3,
+                         "default": [True, False, False],
+                         "hop1": [True, False, False]}[caps_name]
+
+    # the pull-only program of the same seeds (the default caps' lanes
+    # share four seeds: its own numpy search above is its witness)
+    if caps_name not in ("pull", "default"):
+        (p_seen, p_count, p_edges, p_pushed, p_hops), *_ = _tree_run(
+            "pull", lanes, filtered, keep_hops)
+        assert p_pushed == 0
+        assert np.array_equal(seen, p_seen)
+        assert np.array_equal(count, p_count)
+        assert np.array_equal(edges, p_edges)
+        if keep_hops:
+            assert np.array_equal(hops, p_hops)

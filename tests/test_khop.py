@@ -144,8 +144,9 @@ def test_launch_returns_counts_and_no_hop_masks(g500):
     rels = {("link", False): _ell_for(store, "link", False)}
     fn = _tree_kernel_for(store, plan, rels, n, 1)
     seeds = _pack_global(n, [np.array([i], np.int32) for i in nodes], 32)
-    (seen, count, edges, hops), = fn((seeds,), ())
+    (seen, count, edges, pushed, hops), = fn((seeds,), ())
     assert hops is None and seen.shape == (n + 1, 1)
+    assert pushed.dtype == np.int32 and 0 <= int(pushed) <= 3
     assert count.dtype == np.int32 and count.shape == (32,)
     assert count.tolist() == [ref.within(int(i), 3) for i in nodes]
     # traversed edges: the out-degrees of everything within 2 hops
@@ -256,3 +257,113 @@ def test_lane_sums_pass_float32(g500):
     assert got.dtype == jnp.int32 and got.tolist() == want.tolist()
     assert _lane_sums(jnp.asarray(mask), None, n, W, 32).tolist() == \
         bits.sum(axis=0).tolist()
+
+
+# -- a recurse stage's pushed hops, on the served path ----------------------
+
+def _store_with_reverse():
+    """The generator's graph (another deal of the names) as a store of its
+    own, `link` with its reverse so that a shortest group can run over
+    the same relation; its out-CSR is not built yet."""
+    from dgraph_tpu.store.schema import parse_schema
+    from dgraph_tpu.store.store import (PredicateData, Store,
+                                        _csr_from_pairs, build_indexes)
+    data = gen.generate(PARAMS, seed=3)
+    n = int(data["n_nodes"])
+    schema = parse_schema("link: [uid] @reverse .\n")
+    pd = PredicateData(schema=schema.get("link"))
+    pd.fwd = _csr_from_pairs(data["src"], data["dst"], n)
+    pd.rev = _csr_from_pairs(data["dst"], data["src"], n)
+    preds = {"link": pd}
+    build_indexes(preds)
+    return data, Store(uids=np.arange(1, n + 1, dtype=np.int64),
+                       schema=schema, preds=preds)
+
+
+@pytest.fixture
+def out_csr_spans():
+    """The names of the out-CSR's build and upload spans, as they close."""
+    from dgraph_tpu.utils import tracing
+    names = []
+
+    def sink(s):
+        if s.attrs.get("part") == "out_csr":
+            names.append(s.name)
+
+    tracing.add_sink(sink)
+    yield names
+    tracing.remove_sink(sink)
+
+
+def _tree_hops():
+    return [METRICS.get(f"kernel_hops_{k}_total", family="tree")
+            for k in ("run", "push")]
+
+
+def _link_dev(store):
+    from dgraph_tpu.engine.batch import _cache_host
+    return _cache_host(store, "link", False)._ell_devs[("link", False)]
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_khop_counts_push_their_first_hop(out_csr_spans, depth):
+    """A /query/batch-shaped group of k-hop counts rides one launch whose
+    recurse stage pushes the hops its frontier fits (hop 1 of seeds with
+    a few out-edges) and pulls the others: the counts are the host
+    engine's, the device's two counts of hops reach the counters, and
+    the relation's out-CSR is built and uploaded once, however many
+    launches read it."""
+    from dgraph_tpu.ops.bfs import push_caps
+
+    data, store = _store_with_reverse()
+    eng = Engine(store, device_threshold=10**9)
+    f_cap, e_cap, _chunk = push_caps(len(data["src"]))
+    rl = data["row_len"]
+    few = np.nonzero((rl > 0) & (rl <= e_cap // f_cap))[0]
+    assert f_cap >= 4 and len(few) >= 2 * f_cap
+    hubs = np.argsort(-rl)[:f_cap]               # their hop 1 is over a cap
+    for nodes, pushes in ((few[:f_cap], True), (few[f_cap:2 * f_cap], True),
+                          (hubs, False)):
+        qs = [COUNT % (hex(int(i) + 1), depth) for i in nodes]
+        run0, push0 = _tree_hops()
+        got, _plan = serve(store, qs)
+        assert got == [eng.query(q) for q in qs]
+        assert all(a["q"][0]["count"] > 1 for a in got)
+        run1, push1 = _tree_hops()
+        assert run1 - run0 == depth
+        assert (1 <= push1 - push0 < depth) if pushes else push1 == push0
+    assert out_csr_spans == ["batch.build_ell", "batch.upload_ell"]
+    assert _link_dev(store).out is not None
+
+
+def test_shortest_group_reuses_the_tree_groups_out_csr(out_csr_spans):
+    """A shortest group after a tree group over the same relation reads
+    the out-CSR the tree group brought: one DeviceEll.out, built once."""
+    data, store = _store_with_reverse()
+    eng = Engine(store, device_threshold=10**9)
+    rl = data["row_len"]
+    nodes = np.nonzero(rl > 0)[0][:4]
+    serve(store, [COUNT % (hex(int(i) + 1), 3) for i in nodes])
+    dev = _link_dev(store)
+    out = dev.out
+    assert out is not None
+    # targets two edges away, so that every lane is opened
+    rs, dst = data["row_start"], data["dst"]
+    pairs = []
+    for i in nodes:
+        mid = int(dst[rs[i]])
+        far = [int(d) for m in dst[rs[i]:rs[i] + rl[i]]
+               for d in dst[rs[m]:rs[m] + rl[m]]]
+        pairs.append((int(i), far[0] if far else mid))
+    qs = ['{ path as shortest(from: %s, to: %s) { link } '
+          'p(func: uid(path)) { uid } }' % (hex(a + 1), hex(b + 1))
+          for a, b in pairs]
+    launches = METRICS.get("kernel_group_launches_total", family="shortest")
+    plans, leftover = plan_batch_groups(store, [parse(q) for q in qs])
+    assert len(plans) == 1 and not leftover
+    assert not isinstance(plans[0][0], TreePlan)
+    assert run_batch(store, plans[0][0], 10**9) == [eng.query(q) for q in qs]
+    assert METRICS.get("kernel_group_launches_total",
+                       family="shortest") == launches + 1
+    assert dev.out is out
+    assert out_csr_spans == ["batch.build_ell", "batch.upload_ell"]

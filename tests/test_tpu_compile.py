@@ -70,3 +70,61 @@ def test_lane_sums_compile_for_the_v5e_at_graph500_22(one_chip, weighted):
                   lambda m, w=None: bfs._lane_sums(m, w, n, W, 32), *shapes)
     assert c.memory_analysis().temp_size_in_bytes < 64 << 20
     assert c.memory_analysis().output_size_in_bytes <= 4 * 32 * W + 1024
+
+
+def test_tree_program_with_the_push_compiles_for_the_v5e(one_chip):
+    """The k-hop count's program (one recurse stage of depth 3, 64 lanes)
+    at graph500-22's rows, out-edges and caps, so that a pushed hop takes
+    the real turn of 32,768 slots over the real 2.4 M rows (what XLA:TPU
+    does with a scatter depends on both). The pull's blocks are cut to one
+    degree class: the pull is not what this guards. The program keeps its
+    name and its hops a loop with the choice inside, sorts nothing, and
+    every index block and the out-CSR are parameters (a device array the
+    program closed over would be a constant of it)."""
+    import re
+
+    import numpy as np
+
+    from dgraph_tpu.ops import bfs
+    n, edges, dense = 2395982, 65242600, 1 << 20
+    described = {}
+
+    def standin(*shape):
+        """An array of the stage, for make_ell_tree to hold: the shape it
+        is compiled at is given at the lowering."""
+        a = jnp.zeros((1,) * len(shape), jnp.int32)
+        described[id(a)] = jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                sharding=one_chip)
+        return a
+
+    caps = bfs.push_caps(edges)
+    assert caps[2] == bfs.PUSH_CHUNK
+    stage = {"kind": "recurse",
+             "prepared": {"parts": [("chain", standin(dense, 4), dense),
+                                    ("zero", None, n - dense)],
+                          "tiles": None, "lvl2": [], "seg_rows": 0, "n": n},
+             "perm_in": standin(n + 1), "out_idx": standin(n + 1),
+             "out": (standin(n + 1), standin(edges), standin(n)),
+             "caps": caps, "parent": ("seed", 0), "filt": None, "depth": 3,
+             "keep_hops": False}
+    tree = bfs.make_ell_tree([stage], n, W)
+    held, = tree.args
+    c = tree.func.lower(
+        [described[id(a)] for a in held],
+        (jax.ShapeDtypeStruct((n + 1, W), jnp.uint32, sharding=one_chip),),
+        ()).compile()
+    hlo = c.as_text()
+    assert hlo.startswith("HloModule jit_tree")
+    assert " sort(" not in hlo
+    assert " scatter(" in hlo and " conditional(" in hlo
+    assert " while(" in hlo
+    width = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "s32": 4, "u32": 4,
+             "f32": 4}
+    constants = [
+        width.get(dtype, 8) * int(np.prod([int(d) for d in dims.split(",")
+                                           if d] or [1]))
+        for dtype, dims in re.findall(r"= (\w+)\[([\d,]*)\]\S* constant\(",
+                                      hlo)]
+    assert constants and max(constants) <= 4096
+    # the out-CSR's edges are among the parameters
+    assert c.memory_analysis().argument_size_in_bytes > 4 * edges
