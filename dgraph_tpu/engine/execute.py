@@ -545,9 +545,13 @@ class Executor:
             ranks_arr = np.asarray(ranks, np.int32)
             idx = np.searchsorted(col.subj, ranks_arr)
             idx_c = np.minimum(idx, max(len(col.subj) - 1, 0))
-            hit = (len(col.subj) > 0) & (col.subj[idx_c] == ranks_arr)
+            hit = np.atleast_1d(
+                (len(col.subj) > 0) & (col.subj[idx_c] == ranks_arr))
+            keys = _column_keys(col, idx_c, hit)
+            if keys is not None:
+                return keys, hit
             vals = [col.vals[i] if h else None
-                    for i, h in zip(idx_c.tolist(), np.atleast_1d(hit).tolist())]
+                    for i, h in zip(idx_c.tolist(), hit.tolist())]
         else:
             vals = []
             for r in ranks:
@@ -977,6 +981,33 @@ def _coerce_to(want, v):
     except ValueError:
         pass
     return want
+
+
+def _column_keys(col, idx: np.ndarray, has: np.ndarray):
+    """Keys that order the rows `idx` of a typed column as `_value_keys`'
+    value-by-value keys do, by whole arrays: what `_orderable` makes of
+    each value (for a column of `str`, its place among the column's
+    distinct strings: `ValueColumn.order_codes`), the first present
+    value's key where `has` is false. None where the column's values are
+    of a kind, or of mixed kinds, that only the value-by-value path
+    settles."""
+    vals = col.vals
+    if not has.any() or vals.dtype.kind not in "iufMbO":
+        return None
+    if vals.dtype.kind == "O":
+        codes = col.order_codes()
+        if codes is None:
+            return None
+        keys = codes[idx]
+    else:
+        keys = vals[idx]
+        if keys.dtype.kind == "M":
+            keys = keys.astype("datetime64[us]").astype("int64")
+        elif keys.dtype.kind == "b":
+            keys = keys.astype(np.int64)
+    if not has.all():
+        keys[~has] = keys[has][0]
+    return keys
 
 
 def _orderable(v):

@@ -26,11 +26,17 @@ algebra), then the standard renderer emits JSON. An @recurse stage that no
 block renders (a `var` block) rebuilds nothing: the launch keeps no hop
 masks, and each consumer of the stage's var is handed what it reads, from
 the device: a `count(uid)` over `uid(v)` its lane's population count of
-the reachable set, any other reader the lane's column of that set by one
-O(n) bit test (`tree_var_reads_total{by=}` says which). Either way batch
-results are bit-identical to the per-query engine, asserted by
-tests/test_treebatch.py against the LDBC IC goldens and by
-tests/test_khop.py against a plain breadth-first search.
+the reachable set; a block rooted at `uid(v)` under a filter that index
+lookups answer (the LDBC IC1 shape) the filter's candidates that the set
+holds, one bit test a candidate; any other reader the lane's column of
+that set by one O(n) bit test. Which it will be is decided once, when the
+query is planned, and recorded in the plan (`TreePlan.var_reads`): the
+launch copies `seen` back for the readers that need it, the per-query run
+answers each reader as recorded, and `tree_var_reads_total{by=}` counts it
+under the record's label. Either way batch results are bit-identical to
+the per-query engine, asserted by tests/test_treebatch.py against the LDBC
+IC goldens, by tests/test_khop.py against a plain breadth-first search and
+by tests/test_ic1_knows.py against a plain IC1.
 """
 
 from __future__ import annotations
@@ -66,6 +72,19 @@ class StageSpec:
     filt_shape: tuple | None = None   # structure-only filter canonical
 
 
+@dataclass(frozen=True)
+class VarRead:
+    """How one block's read of the var of an @recurse stage that no block
+    renders will be answered. `by` is the label `tree_var_reads_total`
+    counts the read under: `count` (the lane's population count, no node
+    named), `probe` (the root filter's candidates tested against the
+    lane's bit of `seen`), `column` (the lane's members listed)."""
+
+    block: int
+    stage: int
+    by: str
+
+
 @dataclass
 class TreePlan:
     """One kernel group: homogeneous stage structure, per-query params."""
@@ -75,7 +94,15 @@ class TreePlan:
     n_seeds: int
     seed_blocks: list[int]                 # slot s ← block seed_blocks[s]
     filt_paths: list[tuple]                # filt slot → owning stage path
+    var_reads: tuple = ()                  # VarRead, a (block, stage) each
     queries: list = field(default_factory=list)   # per-query parsed blocks
+
+    @property
+    def program_sig(self) -> tuple:
+        """What the device program is built from: the seeds and the
+        stages. How the host answers a reader afterwards (`var_reads`,
+        the signature's last part) compiles nothing anew."""
+        return self.sig[:2]
 
 
 # ---------------------------------------------------------------------------
@@ -121,18 +148,82 @@ def _root_uses_vars(sg: SubGraph) -> bool:
     return bool(uses)
 
 
-def _pure_chain_root(sg: SubGraph):
-    """uid(v) root with no other root-level processing → the var name,
-    else None. Such a block's level sets chain straight off the stage
-    that defines v, inside the kernel."""
+def _root_var(sg: SubGraph):
+    """`func: uid(v)`, one var and no literal uid → the var name, else
+    None."""
     f = sg.func
     if (f is None or f.name != "uid" or f.uids or len(f.args) != 1
             or not isinstance(f.args[0], str)):
         return None
+    return f.args[0]
+
+
+def _pure_chain_root(sg: SubGraph):
+    """uid(v) root with no other root-level processing → the var name,
+    else None. Such a block's level sets chain straight off the stage
+    that defines v, inside the kernel."""
     if (sg.filters is not None or sg.orders or sg.first or sg.offset
             or sg.after):
         return None
-    return f.args[0]
+    return _root_var(sg)
+
+
+def _counted_var(sg: SubGraph):
+    """`q(func: uid(v)) { count(uid) }` → v: a block that reads nothing
+    of v's set but how many it holds. Else None."""
+    if (not sg.children or sg.var_name or sg.recurse is not None
+            or sg.msgpass is not None
+            or not all(c.is_count and c.is_uid_leaf and not c.var_name
+                       for c in sg.children)):
+        return None
+    return _pure_chain_root(sg)
+
+
+def _var_reads(schema, blocks, stages) -> tuple:
+    """A VarRead for every block that reads the var of an @recurse stage
+    no block renders, in the order of (stage, block). Read off the parsed
+    query alone:
+
+      count   `q(func: uid(v)) { count(uid) }`
+      probe   a block rooted at `uid(v)` whose @filter is evaluable before
+              the launch (`_filter_ok`: index lookups, no `not`, no var or
+              uid reference; a level below the root takes no other either)
+      column  any other reader: no filter, a filter `_filter_ok` refuses,
+              a `uid(v)` inside a filter; the stage's own block where a
+              leaf binds a value var over every visited node; and any
+              reader of a name that another block binds anew, where only
+              the run knows which set the name stands for when it is
+              read"""
+    from dgraph_tpu.engine.varorder import collect_defs, collect_uses
+    reads = []
+    for i, s in enumerate(stages):
+        if s.kind != "recurse" or s.keep_hops:
+            continue
+        bi = s.path[0]
+        own = blocks[bi]
+        edges = [c for c in own.children if expands(schema, c)]
+        names = {n for n in (own.var_name, *(c.var_name for c in edges))
+                 if n}
+        rebound = names & set().union(
+            *(collect_defs(b) for bj, b in enumerate(blocks) if bj != bi))
+        for bj, sg in enumerate(blocks):
+            if bj == bi:
+                if any(c.var_name for c in own.children if c not in edges):
+                    reads.append(VarRead(bj, i, "column"))
+                continue
+            if not collect_uses(sg) & names:
+                continue
+            if rebound:
+                by = "column"
+            elif _counted_var(sg) in names:
+                by = "count"
+            elif (_root_var(sg) in names and sg.filters is not None
+                  and _filter_ok(sg.filters)):
+                by = "probe"
+            else:
+                by = "column"
+            reads.append(VarRead(bj, i, by))
+    return tuple(reads)
 
 
 def _bad_directives(sg: SubGraph) -> bool:
@@ -254,11 +345,16 @@ def _plan_tree(store, blocks):
 
     if not any_stage_block or not stages:
         raise _Ineligible
+    # how a reader of an unrendered stage's var is answered is structure,
+    # like a filter's shape: two queries share a launch only where their
+    # records agree
+    var_reads = _var_reads(schema, blocks, stages)
     sig = (len(seed_blocks), tuple(
         (s.kind, s.attr, s.reverse, s.parent, s.depth, s.keep_hops,
-         s.path, s.filt_shape) for s in stages))
+         s.path, s.filt_shape) for s in stages), var_reads)
     plan = TreePlan(sig=sig, stages=stages, n_seeds=len(seed_blocks),
-                    seed_blocks=seed_blocks, filt_paths=filt_paths)
+                    seed_blocks=seed_blocks, filt_paths=filt_paths,
+                    var_reads=var_reads)
     return sig, plan
 
 
@@ -303,26 +399,28 @@ class _Launch:
     hops: dict = field(default_factory=dict)        # rendered recurse:
     #                                                 host [depth, n+1, W]
     counts: dict = field(default_factory=dict)      # recurse: int32[lanes]
-    seen_dev: dict = field(default_factory=dict)    # recurse: device seen,
-    #                                                 permuted rows
-    perm_order: dict = field(default_factory=dict)  # recurse: permuted row
-    #                                                 → global rank
-    seen_host: dict = field(default_factory=dict)   # seen_dev, once read
+    seen: dict = field(default_factory=dict)        # recurse, where the plan
+    #                                                 records a probe or a
+    #                                                 column reader: host
+    #                                                 [n+1, W], permuted rows
+    ells: dict = field(default_factory=dict)        # recurse: the relation's
+    #                                                 ELL (its permutations)
 
     def column(self, stage_idx: int, lane: int) -> np.ndarray:
         """Lane's members of a recurse stage's reachable set, ascending
-        global ranks: its column of `seen`, one O(n) bit test. The mask
-        is copied back at the first such read of a batch, not before."""
-        from dgraph_tpu.utils import tracing
-        seen = self.seen_host.get(stage_idx)
-        if seen is None:
-            with tracing.span("batch.fetch_column", stage=stage_idx) as sp:
-                seen = self.seen_host[stage_idx] = np.asarray(
-                    self.seen_dev[stage_idx])
-                sp.attrs["bytes"] = seen.nbytes
-        rows = np.nonzero(seen[:-1, lane // 32]
+        global ranks: its column of `seen`, one O(n) bit test."""
+        rows = np.nonzero(self.seen[stage_idx][:-1, lane // 32]
                           & np.uint32(1 << (lane % 32)))[0]
-        return np.sort(self.perm_order[stage_idx][rows]).astype(np.int32)
+        return np.sort(
+            self.ells[stage_idx].perm_order[rows]).astype(np.int32)
+
+    def probe(self, stage_idx: int, lane: int,
+              ranks: np.ndarray) -> np.ndarray:
+        """Those of `ranks` (global) that lane's reachable set holds, in
+        the order given: one bit test a rank, at its permuted row."""
+        rows = self.ells[stage_idx].new_of_old[ranks]
+        return ranks[(self.seen[stage_idx][rows, lane // 32]
+                      & np.uint32(1 << (lane % 32))) != 0]
 
 
 def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
@@ -333,7 +431,9 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
     shortest route where the work is the same: `batch.seed` (roots,
     filter sets, packing, uploads), `batch.device_wait` (dispatch until
     the host holds the recurse stages' counts), `batch.fetch` (the masks
-    a rendered level needs), `batch.render` (the per-query runs)."""
+    a rendered level needs, and an unrendered @recurse stage's `seen`
+    where the plan records a reader of its members), `batch.render` (the
+    per-query runs)."""
     import jax
 
     from dgraph_tpu.engine.batch import _ell_for, _note_kernel_features
@@ -415,7 +515,7 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
                       padded_lanes=lanes - B) as ksp:
         with tracing.span("batch.device_wait", phase=True,
                           stages=len(plan.stages)):
-            with jit_call("treebatch.tree_kernel", (plan.sig, W, n)):
+            with jit_call("treebatch.tree_kernel", (plan.program_sig, W, n)):
                 outs = fn(seeds, filts)
             # the dispatch returns at once: the span ends when the host
             # holds what every recurse stage counted (two int32[lanes] a
@@ -426,19 +526,22 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
                 jax.block_until_ready(outs)
         with tracing.span("batch.fetch", phase=True) as sp:
             # bit tests against these masks rebuild the edge rows of the
-            # levels a block renders; a recurse stage's set stays on the
-            # device until a consumer asks for a column of it
+            # levels a block renders; an unrendered recurse stage's set
+            # stays on the device unless the plan records a reader of its
+            # members (a count reads the tallies alone)
+            listed = {r.stage for r in plan.var_reads if r.by != "count"}
             launch = _Launch()
             for i, (s, o) in enumerate(zip(plan.stages, outs)):
                 if s.kind == "recurse":
-                    launch.seen_dev[i] = o[0]
-                    launch.perm_order[i] = rels[s.attr, s.reverse].perm_order
+                    launch.ells[i] = rels[s.attr, s.reverse]
                     if s.keep_hops:
                         launch.hops[i] = np.asarray(o[4])
+                    elif i in listed:
+                        launch.seen[i] = np.asarray(o[0])
                 else:
                     launch.masks[i] = np.asarray(o)
             sp.attrs["bytes"] = sum(
-                m.nbytes for d in (launch.masks, launch.hops)
+                m.nbytes for d in (launch.masks, launch.hops, launch.seen)
                 for m in d.values())
     # launch count + dispatch gap are recorded by jit_call itself
     costprofile.add_kernel("tree", execute_us=ksp.dur_us)
@@ -456,9 +559,10 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
 
     with tracing.span("batch.render", phase=True, queries=B):
         out_json = []
+        read_by = {(r.block, r.stage): r.by for r in plan.var_reads}
         for q, blocks in enumerate(plan.queries):
             ex = _MaskedExecutor(store, q, idx_per_query[q], launch,
-                                 root_displays[q],
+                                 root_displays[q], read_by,
                                  device_threshold=device_threshold)
             results: dict[int, LevelNode] = {}
             for bi in execution_order(blocks):
@@ -502,7 +606,7 @@ def _tree_kernel_for(store, plan: TreePlan, rels, n: int, W: int):
 
     hosts = {_cache_host(store, a, r) for a, r in rels}
     host = hosts.pop() if len(hosts) == 1 else store
-    key = (plan.sig, W, pallas_enabled())
+    key = (plan.program_sig, W, pallas_enabled())
     # a relation that a recurse stage expands brings its out-CSR, for the
     # stage's pushed hops: the one the lane step of the same relation reads
     recursed = {(s.attr, s.reverse) for s in plan.stages
@@ -555,11 +659,15 @@ class _MaskedExecutor(Executor):
     bit-tested against the stage mask (filters already folded in on
     device), then ordering/pagination/vars/rendering run unchanged. The
     var of an @recurse stage that no block renders is bound to nothing
-    until a consumer reads it (`_stage_vars`)."""
+    until a consumer reads it (`_stage_vars`), and each block that reads
+    it is answered as the plan recorded (`read_by`: `TreePlan.var_reads`
+    by (block, stage))."""
 
     def __init__(self, store, lane: int, sidx: _StageIndex,
-                 launch: _Launch, root_displays=None, **kw):
+                 launch: _Launch, root_displays=None, read_by=None, **kw):
         super().__init__(store, **kw)
+        self._read_by = read_by or {}
+        self._counted: set[tuple] = set()    # (block, stage) reads counted
         self._lane = lane
         self._lane_word = lane // 32
         self._lane_bit = np.uint32(1 << (lane % 32))
@@ -596,10 +704,29 @@ class _MaskedExecutor(Executor):
             return None
         return stage_idx
 
+    def _read(self, stage_idx: int) -> str:
+        """How the plan recorded this block's read of the stage's var;
+        counts the read under that label, once a block."""
+        key = (self._path[0], stage_idx)
+        by = self._read_by[key]
+        if key not in self._counted:
+            self._counted.add(key)
+            METRICS.inc("tree_var_reads_total", by=by)
+        return by
+
+    def _root_read(self, sg: SubGraph) -> tuple:
+        """(stage, by) where the block is rooted at the var of an
+        unrendered stage, else (None, None)."""
+        var = _root_var(sg)
+        stage_idx = self._stage_of(var) if var is not None else None
+        if stage_idx is None:
+            return None, None
+        return stage_idx, self._read(stage_idx)
+
     def _var_ranks(self, name: str) -> np.ndarray:
         stage_idx = self._stage_of(name)
         if stage_idx is not None:
-            METRICS.inc("tree_var_reads_total", by="column")
+            self._read(stage_idx)
             if name not in self.uid_vars:
                 self.uid_vars[name] = self._stage_bound[name] = \
                     self._launch.column(stage_idx, self._lane)
@@ -607,16 +734,24 @@ class _MaskedExecutor(Executor):
             METRICS.inc("tree_var_reads_total", by="edge_walk")
         return super()._var_ranks(name)
 
+    def root_ranks(self, sg: SubGraph) -> np.ndarray:
+        # recorded `probe`: the filter's candidates first, the set asked
+        # about them; the var stays unbound (another block may list it)
+        stage_idx, by = self._root_read(sg)
+        if by != "probe":
+            return super().root_ranks(sg)
+        from dgraph_tpu.utils import tracing
+        with tracing.span("batch.probe", stage=stage_idx) as sp:
+            cands = self.filter_set(sg.filters)
+            sp.attrs["rows"] = len(cands)
+            METRICS.inc("tree_probe_rows_total", float(len(cands)))
+            return self._launch.probe(stage_idx, self._lane, cands)
+
     def _run_block(self, sg: SubGraph) -> LevelNode:
-        # `q(func: uid(v)) { count(uid) }` over an unrendered stage's set:
-        # the lane's count is the whole answer, and no node is named
-        var = _pure_chain_root(sg)
-        stage_idx = self._stage_of(var) if var is not None else None
-        if (stage_idx is not None and sg.children and not sg.var_name
-                and sg.recurse is None and sg.msgpass is None
-                and all(c.is_count and c.is_uid_leaf and not c.var_name
-                        for c in sg.children)):
-            METRICS.inc("tree_var_reads_total", by="count")
+        # recorded `count`: the lane's count is the whole answer, and no
+        # node is named
+        stage_idx, by = self._root_read(sg)
+        if by == "count":
             return LevelNode(
                 sg=sg, nodes=EMPTY, display=EMPTY,
                 leaf_sgs=list(sg.children),
@@ -729,7 +864,7 @@ class _MaskedExecutor(Executor):
         sg = root.sg
         data = split_children(self, sg, RecurseData(loop=False))
         if any(leaf.var_name for leaf in data.leaf_sgs):
-            METRICS.inc("tree_var_reads_total", by="column")
+            self._read(stage_idx)
             data.all_nodes = self._launch.column(stage_idx, self._lane)
             _bind_recurse_vars(self, root, data, sg)
             if sg.var_name:

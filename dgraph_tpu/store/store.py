@@ -97,6 +97,19 @@ class ValueColumn:
         """Sorted unique ranks that have a value."""
         return np.unique(self.subj)
 
+    def order_codes(self) -> np.ndarray | None:
+        """int32[k]: each value's place among the column's distinct
+        values, ascending: a key that orders rows as the values do (equal
+        values, equal codes) without a string compared per query. For a
+        column of `str` alone, else None. Computed once a column."""
+        if not hasattr(self, "_codes"):
+            self._codes = None
+            vals = self.vals.tolist() if self.vals.dtype.kind == "O" else ()
+            if vals and set(map(type, vals)) == {str}:
+                self._codes = np.unique(
+                    np.array(vals), return_inverse=True)[1].astype(np.int32)
+        return self._codes
+
 
 @dataclass
 class FacetCol:

@@ -160,7 +160,8 @@ def test_launch_returns_counts_and_no_hop_masks(g500):
 
 
 # ---------------------------------------------------------------------------
-# other consumers of the stage's var read its column
+# other consumers of the stage's var read its column, or, under a filter
+# that index lookups answer, ask it about the filter's candidates
 
 @pytest.fixture(scope="module")
 def scored():
@@ -185,6 +186,10 @@ COLUMN_SHAPES = {
         "{ N as var(func: uid(%s)) @recurse(depth: 3, loop: false) { link } "
         "q(func: uid(N), orderdesc: score, first: 7) "
         "@filter(le(score, 12)) { uid score } }",
+    "not_filter_order_page":
+        "{ N as var(func: uid(%s)) @recurse(depth: 3, loop: false) { link } "
+        "q(func: uid(N), orderdesc: score, first: 7) "
+        "@filter(not le(score, 12)) { uid score } }",
     "expanded_root":
         "{ N as var(func: uid(%s)) @recurse(depth: 2, loop: false) { link } "
         "q(func: uid(N)) { uid link (first: 2) { uid } } }",
@@ -206,12 +211,16 @@ def test_other_consumers_read_the_column(scored, shape):
     data, store = scored
     nodes = np.nonzero(data["row_len"] > 0)[0][:12]
     qs = [COLUMN_SHAPES[shape] % hex(int(i) + 1) for i in nodes]
-    before = {by: reads(by) for by in ("column", "edge_walk")}
+    # an evaluable filter on the reader's root: its candidates are probed
+    by, other = (("probe", "column") if shape == "filter_order_page"
+                 else ("column", "probe"))
+    before = {k: reads(k) for k in ("column", "probe", "edge_walk")}
     got, plan = serve(store, qs)
     assert not plan.stages[0].keep_hops
     eng = Engine(store, device_threshold=10**9)
     assert got == [eng.query(q) for q in qs]
-    assert reads("column") - before["column"] >= len(qs)
+    assert reads(by) - before[by] >= len(qs)
+    assert reads(other) == before[other]
     assert reads("edge_walk") == before["edge_walk"]
 
 
