@@ -453,6 +453,7 @@ def run_batch(store, plan, device_threshold: int) -> list:
     costprofile.add("bytes_gathered",
                     plan.depth * g.padded_edges
                     * (4 + mask0.shape[1] * 4))
+    note_pulls(g, "recurse", plan.depth)
     rel = store.rel(plan.attr, plan.reverse)
 
     root_nodes = [np.unique(s).astype(np.int32) for s in seeds]
@@ -745,6 +746,7 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
         # device knows and small beside a pull's: only the pulls are billed
         costprofile.add("bytes_gathered",
                         pulled * g.padded_edges * (4 + W * 4))
+        note_pulls(g, "shortest", pulled)
 
     # two passes over the queries, one span each: a span a query would
     # make the trace grow with the batch
@@ -1003,13 +1005,33 @@ def _ell_for(store, attr: str, reverse: bool):
                 costprofile.add("build_us", sp.dur_us)
                 costprofile.add_tablet_cost(attr, sp.dur_us)
                 cache[key] = g
-                # segment-CSR padding waste: padded slots / real edges
+                # segment-CSR padding waste: padded slots / the real edges
+                # they hold (the hub block's are in no slot)
                 METRICS.set_gauge("ell_padding_ratio",
-                                  g.padded_edges / max(g.nnz, 1) - 1.0,
+                                  g.padded_edges
+                                  / max(g.nnz - g.dense_edges, 1) - 1.0,
+                                  pred=attr, reverse=str(reverse))
+                rows, cols = (g.dense[0].shape if g.dense is not None
+                              else (0, 0))
+                METRICS.set_gauge("ell_dense_rows", float(rows),
+                                  pred=attr, reverse=str(reverse))
+                METRICS.set_gauge("ell_dense_cols", float(cols),
+                                  pred=attr, reverse=str(reverse))
+                METRICS.set_gauge("ell_dense_edges", float(g.dense_edges),
                                   pred=attr, reverse=str(reverse))
         out = cache[key]
     memgov.GOVERNOR.maybe_evict("host")
     return out
+
+
+def note_pulls(g, family: str, pulled: int) -> None:
+    """Count a launch's pulled hops in in-edges: every stored in-edge of
+    the relation answered once a pull, and the hub block's share of them
+    (0 where the relation has no block: the series exist all the same)."""
+    METRICS.inc("kernel_edges_pulled_total", float(pulled * g.nnz),
+                family=family)
+    METRICS.inc("kernel_edges_dense_total", float(pulled * g.dense_edges),
+                family=family)
 
 
 def _dev_for(store, attr: str, reverse: bool):

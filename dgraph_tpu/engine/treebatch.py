@@ -436,7 +436,8 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
     per-query runs)."""
     import jax
 
-    from dgraph_tpu.engine.batch import _ell_for, _note_kernel_features
+    from dgraph_tpu.engine.batch import (_ell_for, _note_kernel_features,
+                                         note_pulls)
     from dgraph_tpu.engine.outputnode import to_json
     from dgraph_tpu.utils import costprofile, deadline, tracing
     from dgraph_tpu.utils.jitcache import jit_call
@@ -552,10 +553,15 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
         METRICS.inc("kernel_hops_run_total", float(plan.stages[i].depth),
                     family="tree")
         METRICS.inc("kernel_hops_push_total", float(pushed), family="tree")
+        note_pulls(launch.ells[i], "tree",
+                   plan.stages[i].depth - int(pushed))
         # the north star's traversed edges: a lane's sum fits int32, the
         # lanes' sum need not
         METRICS.inc("kernel_edges_traversed_total",
                     float(edges[:B].astype(np.int64).sum()), family="tree")
+    for s in plan.stages:
+        if s.kind == "hop":         # a hop stage is one pull
+            note_pulls(rels[s.attr, s.reverse], "tree", 1)
 
     with tracing.span("batch.render", phase=True, queries=B):
         out_json = []

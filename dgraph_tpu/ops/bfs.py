@@ -17,6 +17,10 @@ in 279 ms, the pull of make_ell_step on the chip, PR 30; the ledger reads
 the same since PR 23), so widening each access to a B-bit lane row
 amortises the irregular-memory tax across B queries — the same shape the
 reference can't reach because its per-query goroutines share nothing.
+What a pull need not gather at all is cheaper still: the in-edges inside a
+relation's hub core are one 0/1 matrix product (the dense hub block below:
+a third of Graph500 scale 22's in-edges in 4.8 ms, the cell's pull 439 →
+298 ms on the chip, PR 38).
 """
 
 from __future__ import annotations
@@ -52,10 +56,15 @@ __all__ = ["EllGraph", "build_ell", "ell_recurse",
 # lanes: W words = word_bits·W queries per access). One hop is then pure
 # gathers + bitwise ORs — no scatter, no sort, fully static shapes — and
 # costs one gather for every stored in-edge whatever the frontier holds.
-# For a frontier of a few rows that is the waste: make_ell_step therefore
-# pushes, hop by hop, while the frontier's out-edges number under a 128th
-# of the relation's (the two costs cross at a twentieth), and pulls
-# otherwise; the recurse and tree families pull always.
+# For a frontier of a few rows that is the waste: make_ell_step and the
+# recurse stages of make_ell_tree therefore push, hop by hop, while the
+# frontier's out-edges number under a 128th of the relation's (the two
+# costs cross at a twentieth), and pull otherwise (_pull_or_push, PR 30 and
+# 35); make_ell_recurse and a tree's hop stages pull always. And a pull
+# need not gather every in-edge: the in-edges between the relation's
+# high-out-degree and high-in-degree rows, a quarter to a third of a
+# Kronecker or follower graph's, are a 0/1 matrix product (the dense hub
+# block below, PR 38).
 #
 # Layout (PR 7, FeatGraph-style degree buckets): nodes are RENUMBERED by
 # in-degree class so each class's output is a contiguous slice and the
@@ -80,6 +89,29 @@ SEG_MIN_DEG = 32      # dense-lane ELL up to this in-degree; heavier → tiles
 SEG_TILE = 8          # segment-CSR tile width (max padding per heavy row)
 CHAIN_MAX = 32        # widest unrolled gather-OR chain; beyond → reduce
 
+# The dense hub block (PR 38). The in-edges that run from the relation's
+# high-out-degree rows (columns C) to its high-in-degree rows (rows R) leave
+# the lists and are answered by one 0/1 matrix product a pulled hop
+# (_dense_hit): a gathered in-edge costs the chip its access, 5.8-6.2 ns and
+# its share of the second level, a cell of the block a byte of HBM and 64
+# multiply-adds of an MXU nothing else in the hop uses, 1.8-2.3 ps (on the
+# v5e, PR 38: 16,384 x 65,536 int8 against [65,536, 64] in 2.5 ms, 32,768 x
+# 65,536 in 3.8 ms; bf16 operands the same). The two cross near 3,000 cells
+# an edge. _choose_dense takes the block that saves most, an edge inside
+# counted as one gather saved, a row of the block as one spent and a cell
+# as 1/DENSE_CELLS_PER_EDGE of one: a sixth of the break-even, so that a
+# block the rule takes cannot cost more than it saves (a Kronecker or
+# follower graph's core stands at 100-130 cells an edge; LDBC's `knows`,
+# whose best block holds 6 % of the edges at 530, gets none). The int8
+# block may hold DENSE_MAX_BYTES, and is taken only if it saves
+# DENSE_MIN_EDGES gathers a pull: a product has a fixed price (a gather of
+# C rows, an unpack, a pack), and a graph of a few thousand edges builds
+# the ELL it always did. Rows and columns are padded to DENSE_PAD.
+DENSE_CELLS_PER_EDGE = 512
+DENSE_MAX_BYTES = 2 << 30
+DENSE_MIN_EDGES = 1 << 20
+DENSE_PAD = 128
+
 
 @dataclass
 class EllGraph:
@@ -89,7 +121,17 @@ class EllGraph:
     ("zero", None, rows) for the indeg-0 class, ("ell", [rows, K] int32,
     rows) per present degree K ≤ seg_min. `tiles`/`lvl2` hold the heavy
     tail's segment-CSR (tile matrix + per-tile-count combine indices);
-    heavy rows sit after all dense rows in the permutation."""
+    heavy rows sit after all dense rows in the permutation.
+
+    `dense` is the hub block, or None where the relation has no core worth
+    one (_choose_dense): (block [R, C] int8 0/1, cols int32[C]), block[j,
+    c] set iff the edge cols[c] → row j of the block is stored, cols the
+    block's columns in the permuted space, ascending, padded with the zero
+    sentinel row n. Those `dense_edges` in-edges are in no list; in their
+    place row j of the block lists ONE in-neighbour more, n + 1 + j: the
+    row of the block's product that _ell_hop appends to the frontier
+    after its sentinel, so that a row's share of the block is ORed in by
+    the gather that ORs its other in-neighbours."""
 
     n: int                                  # node count
     parts: list                             # dense blocks, permuted order
@@ -100,10 +142,13 @@ class EllGraph:
     perm_order: object                      # new rank -> old rank
     new_of_old: object                      # old rank -> new rank
     ks: list = field(default_factory=list)  # dense widths present
+    dense: object = None                    # (block, cols) | None
+    dense_edges: int = 0                    # in-edges the block holds
 
-    @property
+    @functools.cached_property
     def nnz(self) -> int:
-        return int(self.outdeg.sum())
+        """The relation's stored edges (exact: summed in float64)."""
+        return int(self.outdeg.sum(dtype="float64"))
 
     @property
     def padded_edges(self) -> int:
@@ -115,9 +160,76 @@ class EllGraph:
                         else 0)
 
 
+def _degree_ladder(deg, largest: int):
+    """Candidate sets of rows by a degree THRESHOLD: (thresholds
+    descending, set sizes ascending). For each target size DENSE_PAD·2^k
+    and half-way between, up to `largest`, the lowest threshold d whose set
+    {deg >= d} is no larger: whole degree classes, so a set is a function
+    of the degree sequence and not of the nodes' names."""
+    import numpy as np
+    d, cnt = np.unique(deg[deg > 0], return_counts=True)
+    atleast = np.cumsum(cnt[::-1])[::-1]            # rows of degree >= d[i]
+    doubles = DENSE_PAD << np.arange(
+        max(int(largest // DENSE_PAD), 1).bit_length(), dtype=np.int64)
+    targets = np.sort(np.concatenate([doubles, doubles + doubles // 2]))
+    at = np.searchsorted(-atleast, -targets)
+    at = np.unique(at[at < len(d)])[::-1]           # sizes ascending
+    return d[at], atleast[at]
+
+
+def _padded(size):
+    return -(-size // DENSE_PAD) * DENSE_PAD
+
+
+def _choose_dense(indeg, outdeg, src, dst, cells_per_edge: float,
+                  max_bytes: int, min_edges: int):
+    """The hub block's thresholds (d_R, d_C), or None: rows R = {indeg >=
+    d_R}, columns C = {outdeg >= d_C}. Of every pair of the two degree
+    ladders' sets whose padded int8 block fits `max_bytes`, the one that
+    saves most: an edge inside is one gather saved, a row of the block one
+    gather spent (its row of the product, one in-neighbour more) and a
+    cell 1 / `cells_per_edge` of one; None where the best saves under
+    `min_edges`. One pass over the edges counts the inside of every pair
+    (a 2-D histogram over the ladders, summed cumulatively). Everything it
+    reads is unchanged by a renaming of the nodes."""
+    import numpy as np
+    if len(dst) < min_edges:            # cannot save what it does not hold
+        return None
+    thr_r, size_r = _degree_ladder(indeg, max_bytes // DENSE_PAD)
+    thr_c, size_c = _degree_ladder(outdeg, max_bytes // DENSE_PAD)
+    if not len(thr_r) or not len(thr_c):
+        return None
+
+    def rung(deg, thr):
+        """The smallest set of the ladder that holds each row (len(thr):
+        none does); a ladder has under a hundred sets."""
+        return (len(thr) - np.searchsorted(thr[::-1], deg, side="right")
+                ).astype(np.uint8)
+
+    # one small key an edge: the tables it gathers from fit a cache
+    stride = len(thr_c) + 1
+    key = rung(indeg, thr_r)[dst].astype(np.uint16) * np.uint16(stride)
+    key += rung(outdeg, thr_c)[src]
+    inside = np.bincount(key, minlength=(len(thr_r) + 1) * stride
+                         ).reshape(len(thr_r) + 1, stride)[:-1, :-1]
+    inside = inside.cumsum(axis=0).cumsum(axis=1)
+    cells = np.outer(_padded(size_r), _padded(size_c))
+    saved = np.where(cells <= max_bytes,
+                     inside - size_r[:, None] - cells / cells_per_edge,
+                     -np.inf)
+    i, j = np.unravel_index(np.argmax(saved), saved.shape)
+    if saved[i, j] < min_edges:
+        return None
+    return int(thr_r[i]), int(thr_c[j])
+
+
 def build_ell(indptr, indices, seg_min: int = SEG_MIN_DEG,
-              seg_tile: int = SEG_TILE) -> EllGraph:
-    """Build the bucketed ELL + segment-CSR blocks from a CSR relation.
+              seg_tile: int = SEG_TILE, dense: tuple | None = None
+              ) -> EllGraph:
+    """Build the bucketed ELL + segment-CSR blocks from a CSR relation,
+    and the dense hub block beside them where the relation has a core
+    (`dense`: _choose_dense's (cells an edge, byte cap, edge floor); None
+    for the module's constants, tests pass their own).
 
     Host-side, once per (snapshot, predicate, direction) — every array is
     produced by whole-graph vectorized passes (one stable argsort for the
@@ -130,12 +242,31 @@ def build_ell(indptr, indices, seg_min: int = SEG_MIN_DEG,
     n = indptr.shape[0] - 1
     deg_out = np.diff(indptr).astype(np.int64)
     src = np.repeat(np.arange(n, dtype=np.int32), deg_out)
+    dst = indices
+    indeg = (np.bincount(dst, minlength=n).astype(np.int64) if n
+             else np.zeros(0, np.int64))
+    hub = _choose_dense(indeg, deg_out, src, dst, *(dense or (
+        DENSE_CELLS_PER_EDGE, DENSE_MAX_BYTES, DENSE_MIN_EDGES)))
+    hub_rows = np.zeros(0, np.int64)
+    dense_edges = 0
+    if hub is not None:
+        # the block's edges leave the lists; in their place each row of
+        # the block has ONE in-neighbour more, row n + 1 + j of the
+        # frontier as _ell_hop extends it: its own row of the product
+        in_r, in_c = indeg >= hub[0], deg_out >= hub[1]
+        inside = in_r[dst] & in_c[src]
+        hub_rows = np.nonzero(in_r)[0]
+        hub_dst, hub_src = dst[inside], src[inside]
+        dense_edges = len(hub_dst)
+        np.logical_not(inside, out=inside)          # now: the edges kept
+        src = np.concatenate([src[inside], n + 1 + np.arange(
+            len(hub_rows), dtype=np.int32)])
+        dst = np.concatenate([dst[inside], hub_rows.astype(dst.dtype)])
+        indeg = np.bincount(dst, minlength=n).astype(np.int64)
     # CSR transpose: in-neighbors grouped by destination, sources
     # ascending within each group (stable sort keeps src order)
-    order = np.argsort(indices, kind="stable")
+    order = np.argsort(dst, kind="stable")
     csrc = src[order]
-    indeg = (np.bincount(indices, minlength=n).astype(np.int64) if n
-             else np.zeros(0, np.int64))
     cindptr = np.concatenate([[0], np.cumsum(indeg)])
 
     small = indeg <= seg_min
@@ -156,7 +287,11 @@ def build_ell(indptr, indices, seg_min: int = SEG_MIN_DEG,
     perm_order = np.lexsort((first_nbr, sort_key))
     new_of_old = np.empty(n, np.int64)
     new_of_old[perm_order] = np.arange(n)
-    cnew = new_of_old[csrc] if len(csrc) else csrc.astype(np.int64)
+    # a source's place in the frontier the lists read: a node's permuted
+    # rank and, after the sentinel n, the rows of the block's product
+    place = np.concatenate([new_of_old,
+                            np.arange(n, n + 1 + len(hub_rows))])
+    cnew = place[csrc] if len(csrc) else csrc.astype(np.int64)
 
     def fill_rows(nodes, K):
         """[len(nodes), K] in-neighbor block (pad=n), one vector pass."""
@@ -220,10 +355,26 @@ def build_ell(indptr, indices, seg_min: int = SEG_MIN_DEG,
                 t2[np.repeat(np.arange(len(rows)), d2), ar2 - base2] = \
                     np.repeat(tile_start[rows], d2) + ar2 - base2
             lvl2.append(t2)
+    block = None
+    if hub is not None:
+        # rows in the nodes' own order, columns ascending in the permuted
+        # space; the padding rows are empty, the padding columns read the
+        # zero sentinel row
+        row_of = np.zeros(n, np.int64)
+        row_of[hub_rows] = np.arange(len(hub_rows))
+        cols = np.sort(new_of_old[in_c])
+        cells = np.zeros((_padded(len(hub_rows)), _padded(len(cols))),
+                         np.int8)
+        cells[row_of[hub_dst],
+              np.searchsorted(cols, new_of_old[hub_src])] = 1
+        block = (cells, np.concatenate(
+            [cols, np.full(cells.shape[1] - len(cols), n)]
+        ).astype(np.int32))
     return EllGraph(n=n, parts=parts, tiles=tiles, lvl2=lvl2,
                     seg_rows=seg_rows,
                     outdeg=deg_out[perm_order].astype(np.float32),
-                    perm_order=perm_order, new_of_old=new_of_old, ks=ks)
+                    perm_order=perm_order, new_of_old=new_of_old, ks=ks,
+                    dense=block, dense_edges=dense_edges)
 
 
 def pack_seed_masks(g: EllGraph, rank_lists,
@@ -273,6 +424,7 @@ class DeviceEll:
     tiles: object          # device [M, seg_tile] | None
     lvl2: list             # device [h_b, K2] blocks
     seg_rows: int
+    dense: object = None   # device (block, cols) of EllGraph.dense | None
     # the same edges by SOURCE row, for the pushed hops of make_ell_step
     # and of make_ell_tree's recurse stages (out_csr, on the device; its
     # third array is the rows' out-degrees). None until one of the two is
@@ -288,7 +440,8 @@ def device_ell(g: EllGraph) -> DeviceEll:
     return DeviceEll(
         n=g.n, parts=parts,
         tiles=jax.device_put(g.tiles) if g.tiles is not None else None,
-        lvl2=[jax.device_put(t) for t in g.lvl2], seg_rows=g.seg_rows)
+        lvl2=[jax.device_put(t) for t in g.lvl2], seg_rows=g.seg_rows,
+        dense=jax.device_put(g.dense) if g.dense is not None else None)
 
 
 def out_csr(g: EllGraph, indptr, indices) -> tuple:
@@ -347,7 +500,7 @@ def prepare_parts(dev: DeviceEll, W: int):
         else:
             tiles = ("chain", dev.tiles, dev.tiles.shape[0])
     return {"parts": parts, "tiles": tiles, "lvl2": list(dev.lvl2),
-            "seg_rows": dev.seg_rows, "n": dev.n}
+            "dense": dev.dense, "seg_rows": dev.seg_rows, "n": dev.n}
 
 
 # Sticky fail-safe: the first bucket_hop_pallas that fails to trace or
@@ -412,12 +565,36 @@ def _pallas_bucket_part(e, n_b, frontier):
                       lax.bitwise_or, (1,))[:n_b]
 
 
+def _dense_hit(dense, frontier, W, dtype):
+    """The hub block's share of a pull: [R, W] packed rows, bit q of row j
+    set iff some column of row j is a row of `frontier` with bit q. One
+    gather of the block's C columns, unpacked to one int8 a lane, and one
+    0/1 matrix product on the MXU: exact, an int32 sum of at most C ones."""
+    block, cols = dense
+    word_bits = jnp.dtype(dtype).itemsize * 8
+    shifts = jnp.arange(word_bits, dtype=dtype)
+    lanes = ((frontier[cols][:, :, None] >> shifts) & dtype(1)).astype(
+        jnp.int8).reshape(cols.shape[0], W * word_bits)
+    hit = lax.dot_general(block, lanes, (((1,), (0,)), ((), ())),
+                          preferred_element_type=jnp.int32) > 0
+    return lax.reduce(
+        hit.reshape(-1, W, word_bits).astype(dtype) << shifts,
+        dtype(0), lax.bitwise_or, (2,))
+
+
 def _ell_hop(prepared, frontier, W, dtype=jnp.uint32):
-    """next[v] = OR of frontier[u] over in-neighbors u — gathers only.
-    Dense degree classes run as gather-OR chains; the heavy tail runs
-    tile partials + the tiny second-level combine; "pallas" blocks ride
-    the explicit DMA-ring kernel (ops/pallas_hop.py), falling back to
-    the gather if it fails to trace/compile (_pallas_bucket_part)."""
+    """next[v] = OR of frontier[u] over in-neighbors u — gathers, and
+    first one matrix product where the relation has a hub block
+    (_dense_hit: its rows ride behind the frontier's sentinel, one more
+    in-neighbour of each row of the block). Dense degree classes run as
+    gather-OR chains; the heavy tail runs tile partials + the tiny
+    second-level combine; "pallas" blocks ride the explicit DMA-ring
+    kernel (ops/pallas_hop.py), falling back to the gather if it fails to
+    trace/compile (_pallas_bucket_part)."""
+    if prepared.get("dense") is not None:
+        # rows n + 1 + j: what the lists of the block's rows read of it
+        frontier = jnp.concatenate(
+            [frontier, _dense_hit(prepared["dense"], frontier, W, dtype)])
     outs = []
     for kind, e, rows in prepared["parts"]:
         if kind == "zero":
@@ -472,21 +649,43 @@ def _lane_sums(mask, weights, n, W, word_bits):
                          jnp.zeros((W * word_bits,), jnp.int32))
 
 
+def _as_arguments(blocks):
+    """(held, rebuild) of a pytree of index blocks: `held` its device
+    arrays, for a jitted program to take as an argument, and
+    rebuild(arrays) the pytree with `arrays` in their places. A device
+    array that a jitted function closes over is a constant of its
+    program: fetched to the host, compiled in and uploaded again, 15 s of
+    the first call for the out-CSR's 180 MB on the chip, and a second
+    copy on the device (PR 30); a hub block is ten times that."""
+    leaves, tree = jax.tree_util.tree_flatten(blocks)
+    held = [x for x in leaves if isinstance(x, jax.Array)]
+
+    def rebuild(arrays):
+        it = iter(arrays)
+        return tree.unflatten([next(it) if isinstance(x, jax.Array) else x
+                               for x in leaves])
+
+    return held, rebuild
+
+
 def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
                      count_edges: bool = True, word_bits: int = 32):
     """Compile a depth-parameterised loop=false @recurse over a DeviceEll
     already resident on device. Returns fn(mask0, depth[, keep_hops]) →
     (last[n+1,W], seen[n+1,W], edges[B] int32[, hops]). The seed mask is
     DONATED: the scan reuses its buffer for the frontier carry instead of
-    holding seed + frontier + seen live (callers re-put per launch)."""
-    prepared = prepare_parts(dev, W)
+    holding seed + frontier + seen live (callers re-put per launch). The
+    index blocks ride as arguments (_as_arguments)."""
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
-    if count_edges:
-        outdeg = jnp.asarray(outdeg).astype(jnp.int32)
+    held, rebuild = _as_arguments((
+        prepare_parts(dev, W),
+        jnp.asarray(outdeg).astype(jnp.int32) if count_edges else None))
 
-    @functools.partial(jax.jit, donate_argnums=(0,),
+    @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("depth", "keep_hops"))
-    def recurse(mask0, depth: int, keep_hops: bool = False):
+    def recurse(arrays, mask0, depth: int, keep_hops: bool = False):
+        prepared, outdeg = rebuild(arrays)
+
         def hop(carry, _):
             frontier, seen = carry
             nxt = _ell_hop(prepared, frontier, W, dtype)
@@ -509,7 +708,7 @@ def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
             return last, seen, edges, hops
         return last, seen, edges
 
-    return recurse
+    return functools.partial(recurse, held)
 
 
 # The pushed hop of make_ell_step takes a frontier whose rows with an
@@ -682,18 +881,8 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
     no use of `near`, and its caller passes None."""
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
     caps = caps or push_caps(int(dev.out[1].shape[0]))
-    # The index blocks ride as arguments. A device array that a jitted
-    # function closes over is a constant of its program: fetched to the
-    # host, compiled in and uploaded again, 15 s of the first call for the
-    # out-CSR's 180 MB on the chip, and a second copy on the device (PR 30)
-    leaves, tree = jax.tree_util.tree_flatten((prepare_parts(dev, W),
-                                               dev.out))
-    held = [x for x in leaves if isinstance(x, jax.Array)]
-
-    def blocks(arrays):
-        it = iter(arrays)
-        return tree.unflatten([next(it) if isinstance(x, jax.Array) else x
-                               for x in leaves])
+    # the index blocks ride as arguments (_as_arguments)
+    held, blocks = _as_arguments((prepare_parts(dev, W), dev.out))
 
     def or_over(x, axis):
         return lax.reduce(x, dtype(0), lax.bitwise_or, (axis,))
@@ -787,15 +976,12 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
     gather).
 
     The stages' index blocks and out-CSRs ride as arguments, as
-    make_ell_step's do: a device array that a jitted function closes
-    over is a constant of its program (15 s of a first call for every
-    180 MB, held twice; PR 30).
+    make_ell_step's do (_as_arguments).
     """
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
-    leaves, treedef = jax.tree_util.tree_flatten(
+    held, rebuild = _as_arguments(
         [(s["prepared"], s["perm_in"], s["out_idx"], s.get("out"))
          for s in stages])
-    held = [x for x in leaves if isinstance(x, jax.Array)]
     caps = [s.get("caps") or push_caps(int(s["out"][1].shape[0]))
             if s["kind"] == "recurse" else None for s in stages]
     # a recurse stage's set is translated to global space only for a
@@ -804,9 +990,7 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def tree(arrays, seeds, filts):
-        it = iter(arrays)
-        blocks = treedef.unflatten(
-            [next(it) if isinstance(x, jax.Array) else x for x in leaves])
+        blocks = rebuild(arrays)
         outs = []
         results = []
         for i, (s, (prepared, perm_in, out_idx, out)) in enumerate(

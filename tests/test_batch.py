@@ -536,3 +536,85 @@ def test_shortest_batch_mixes_pushed_and_pulled_hops():
         SHORTEST_STAGE)
     assert (run1 - run0, push1 - push0) == (want_run, want_push)
     assert 0 < want_push < want_run, "the batch must mix both kinds of hop"
+
+
+# -- the dense hub block under the batch routes (ops/bfs.py _choose_dense) ---
+
+def _hub_alpha():
+    """A skewed `follows`: 40 sources of high out-degree, 40 targets of
+    high in-degree, the core between them filled to seven tenths, and a
+    periphery of 520 nodes with three out-edges each."""
+    rng = np.random.default_rng(38)
+    a = Alpha(device_threshold=10**9)
+    a.alter(SCHEMA)
+    n = 600
+    lines = [f'_:p{i} <name> "p{i}" .\n_:p{i} <score> "{i % 23}"^^<xs:int> .'
+             for i in range(n)]
+    lines += [f"_:p{s} <follows> _:p{t} ." for s in range(40)
+              for t in range(40, 80) if rng.random() < 0.7]
+    for i in range(80, n):
+        lines += [f"_:p{i} <follows> _:p{j} ."
+                  for j in rng.choice(n, 3, replace=False) if i != j]
+    uids = a.mutate(set_nquads="\n".join(lines))["uids"]
+    return a, uids
+
+
+HUB_QUERIES = {
+    "shortest": lambda uids: [
+        '{ path as shortest(from: %s, to: %s) { follows } '
+        'p(func: uid(path)) { name } }' % (uids[f"_:p{i}"], uids[f"_:p{j}"])
+        for i, j in ((100, 41), (230, 77), (300, 555), (411, 60), (90, 5))],
+    "recurse": lambda uids: [
+        '{ q(func: eq(name, "p%d")) @recurse(depth: 3) { name follows } }'
+        % i for i in (100, 3, 230, 41, 300, 17)],
+    "tree": lambda uids: [
+        '{ N as var(func: uid(%s)) @recurse(depth: 3, loop: false) '
+        '{ follows } q(func: uid(N)) { count(uid) } }' % uids[f"_:p{i}"]
+        for i in (100, 3, 230, 41, 300, 17)],
+}
+
+
+@pytest.mark.parametrize("block", [True, False])
+@pytest.mark.parametrize("family", sorted(HUB_QUERIES))
+def test_batch_over_a_hub_block_answers_and_counts_its_pulls(
+        monkeypatch, family, block):
+    """Every batch route over a relation with a hub block answers what
+    the per-query engine answers, and counts each pulled hop in in-edges:
+    all the relation's, and the block's share of them (0, not nothing,
+    where the relation builds no block)."""
+    from dgraph_tpu.engine.batch import _cache_host
+    from dgraph_tpu.ops import bfs
+    from dgraph_tpu.utils.metrics import METRICS
+
+    if block:       # the rule at the size of 600 nodes (tests/test_bfs.py)
+        monkeypatch.setattr(bfs, "DENSE_CELLS_PER_EDGE", 1e9)
+        monkeypatch.setattr(bfs, "DENSE_MAX_BYTES", 256 * 128)
+        monkeypatch.setattr(bfs, "DENSE_MIN_EDGES", 50)
+    a, uids = _hub_alpha()
+    qs = HUB_QUERIES[family](uids)
+
+    def counters():
+        return [METRICS.get(f"kernel_{k}_total", family=family)
+                for k in ("edges_pulled", "edges_dense", "hops_run",
+                          "hops_push")]
+
+    before = counters()
+    got = a.query_batch(qs)
+    pulled, dense, run, push = (x - y for x, y in zip(counters(), before))
+    store = a.mvcc.read_view(a.oracle.read_only_ts())
+    eng = Engine(store, device_threshold=10**9)
+    assert json.dumps(got) == json.dumps([eng.query(q) for q in qs])
+    g = _cache_host(store, "follows", False)._ell_cache[("follows", False)]
+    assert (g.dense is not None) == block
+    assert g.nnz == store.rel("follows", False).nnz
+    pulls = 3 if family == "recurse" else run - push
+    assert pulls >= 2, "the batch must pull"
+    assert (pulled, dense) == (pulls * g.nnz, pulls * g.dense_edges)
+    assert (dense > 0) == block
+    text = METRICS.render()
+    rows, cols = g.dense[0].shape if block else (0, 0)
+    for gauge, value in (("ell_dense_rows", rows), ("ell_dense_cols", cols),
+                         ("ell_dense_edges", g.dense_edges)):
+        assert (f'{gauge}{{pred="follows",reverse="False"}} '
+                f'{float(value)}') in text.replace("dgraph_tpu_", "")
+    assert ('kernel_edges_dense_total{family="%s"}' % family) in text

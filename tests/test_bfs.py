@@ -758,3 +758,350 @@ def test_tree_recurse_stage_pushes_what_its_caps_hold(caps_name, lanes,
         assert np.array_equal(edges, p_edges)
         if keep_hops:
             assert np.array_equal(hops, p_hops)
+
+
+# -- the dense hub block beside the ELL (ops/bfs.py _choose_dense) -----------
+
+# the rule's (cells an edge, byte cap, edge floor) for a graph of 700 nodes:
+# no break-even to speak of, room for one block of 256 x 128, and a floor a
+# core of a few hundred edges passes
+HUB_RULE = (1e9, 256 * 128, 50)
+NO_BLOCK = (1.0, 0, 1 << 62)
+# caps of the pushed hop that hold 64 rows of the periphery (three out-edges
+# each) and no frontier after them
+HUB_CAPS = {"pull": (0, 0, 1), "mixed": (80, 300, 24)}
+
+
+@functools.lru_cache(maxsize=None)
+def _hub_pairs():
+    """(n, edges [E, 2]) of a skewed relation: 60 sources of high
+    out-degree S, 40 targets of high in-degree T, the core S x T filled to
+    seven tenths, and a periphery of low degree. Three rows of T take
+    every source: one takes nothing else (nothing of it is left to the
+    ELL), one takes 5 more in-edges (what is left falls to the dense
+    body's degrees), one 55 more (it stays heavy)."""
+    rng = np.random.default_rng(38)
+    n = 700
+    S, T, F, L = (np.arange(0, 60), np.arange(60, 100), np.arange(100, 160),
+                  np.arange(160, 700))
+    pairs = [(s, t) for s in S for t in T[3:] if rng.random() < 0.7]
+    pairs += [(s, t) for s in S for t in T[:3]]
+    pairs += [(f, T[1]) for f in F[:5]] + [(f, T[2]) for f in F[5:]]
+    for s in S:
+        pairs += [(s, x) for x in rng.choice(L, 10, replace=False)]
+    for x in L:
+        pairs += [(x, d) for d in rng.choice(n, 3, replace=False)]
+    return n, np.unique(np.array(pairs, np.int32), axis=0)
+
+
+def _hub_rel(names=None):
+    """The relation of _hub_pairs, node i named names[i]."""
+    from dgraph_tpu.store.store import _csr_from_pairs
+    n, pairs = _hub_pairs()
+    if names is not None:
+        pairs = np.asarray(names, np.int32)[pairs]
+    return _csr_from_pairs(pairs[:, 0], pairs[:, 1], n)
+
+
+def _block_rows(g):
+    """Old ranks of the block's rows, in the block's order: row j of the
+    product is in-neighbour n + 1 + j of its row, and of no other."""
+    rows, off = {}, 0
+
+    def note(e, row_of):
+        at, k = np.nonzero(e > g.n)
+        for i, v in zip(at, e[at, k]):
+            assert int(v) - g.n - 1 not in rows
+            rows[int(v) - g.n - 1] = int(g.perm_order[row_of[i]])
+
+    for _kind, e, count in g.parts:
+        if e is not None:
+            note(e, np.arange(off, off + count))
+        off += count
+    # a tile's row: the second level lists each heavy row's tiles
+    M = g.tiles.shape[0]
+    tile_row = np.zeros(M, np.int64)
+    for t2 in g.lvl2:
+        at, k = np.nonzero(t2 < M)
+        tile_row[t2[at, k]] = off + at
+        off += len(t2)
+    note(g.tiles, tile_row)
+    assert sorted(rows) == list(range(len(rows)))
+    return np.array([rows[j] for j in range(len(rows))])
+
+
+def _pull_reference(rel, mask):
+    """next[v] = OR of mask[u] over the stored edges u -> v, in numpy and
+    in the relation's own row space."""
+    out = np.zeros_like(mask)
+    src = np.repeat(np.arange(rel.indptr.shape[0] - 1),
+                    np.diff(rel.indptr))
+    np.bitwise_or.at(out, rel.indices, mask[src])
+    return out
+
+
+def _random_lanes(n, W, dt, seed, per_lane=4):
+    """[n, W] packed mask of `per_lane` random rows a lane."""
+    rng = np.random.default_rng(seed)
+    bits = 8 * np.dtype(dt).itemsize
+    m = np.zeros((n, W), dt)
+    for q in range(W * bits):
+        m[rng.integers(0, n, per_lane), q // bits] |= dt(1 << (q % bits))
+    return m
+
+
+def test_the_block_holds_the_core_and_the_ell_the_rest():
+    from dgraph_tpu.ops.bfs import DENSE_PAD, SEG_MIN_DEG, out_csr
+    rel = _hub_rel()
+    n = rel.indptr.shape[0] - 1
+    g = build_ell(rel.indptr, rel.indices, dense=HUB_RULE)
+    block, cols = g.dense
+    assert block.dtype == np.int8 and cols.dtype == np.int32
+    assert block.shape[0] % DENSE_PAD == 0 == block.shape[1] % DENSE_PAD
+    assert block.shape == (256, 128) and cols.shape == (128,)
+    real = cols < n
+    assert (np.diff(cols[real]) > 0).all() and (cols[~real] == n).all()
+    # whole degree classes on both sides
+    indeg = np.bincount(rel.indices, minlength=n)
+    outdeg = np.diff(rel.indptr)
+    R, C = _block_rows(g), g.perm_order[cols[real]]
+    assert set(R) == set(np.nonzero(indeg >= indeg[R].min())[0])
+    assert set(C) == set(np.nonzero(outdeg >= outdeg[C].min())[0])
+    # the block is the adjacency matrix between them, and nothing else
+    adj = np.zeros((n, n), np.int8)
+    adj[rel.indices, np.repeat(np.arange(n), outdeg)] = 1
+    assert np.array_equal(block[:len(R), :len(C)], adj[np.ix_(R, C)])
+    assert not block[len(R):].any() and not block[:, len(C):].any()
+    assert g.dense_edges == int(block.sum()) and 0 < g.dense_edges < rel.nnz
+    # and the ELL holds every other in-edge, once
+    # and the lists hold every other in-edge once, and one in-neighbour
+    # more for each row of the block: its row of the product
+    slots = np.concatenate([e.ravel() for kind, e, _ in g.parts
+                            if kind == "ell"] + [g.tiles.ravel()])
+    assert (slots < n).sum() == rel.nnz - g.dense_edges
+    assert (slots > n).sum() == len(R)
+    # a row of the block of which nothing else is left (a list of one),
+    # one left in the dense body's degrees, one still in the tail
+    left = indeg[R] - adj[np.ix_(R, C)].sum(axis=1)
+    assert (left == 0).any() and (left >= SEG_MIN_DEG).any()
+    assert ((left > 0) & (left < SEG_MIN_DEG)).any()
+    assert ((g.new_of_old[R] >= n - g.seg_rows) == (left >= SEG_MIN_DEG)
+            ).all()
+    assert 1 in g.ks
+    # what the push, the walk-back and the probe read is the whole relation
+    assert g.nnz == rel.nnz
+    assert np.array_equal(np.sort(g.perm_order), np.arange(n))
+    assert np.array_equal(g.perm_order[g.new_of_old], np.arange(n))
+    assert np.array_equal(g.outdeg, outdeg[g.perm_order])
+    ptr, idx, deg = out_csr(g, rel.indptr, rel.indices)
+    assert np.array_equal(deg, outdeg[g.perm_order])
+    assert np.array_equal(
+        g.perm_order[idx],
+        np.concatenate([rel.row(int(r)) for r in g.perm_order]))
+
+
+@pytest.mark.parametrize("W", [1, 2])
+@pytest.mark.parametrize("word_bits", [32, 64])
+def test_a_pull_with_the_block_equals_one_without(word_bits, W):
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from dgraph_tpu.ops.bfs import _ell_hop, device_ell, prepare_parts
+    rel = _hub_rel()
+    n = rel.indptr.shape[0] - 1
+    dt = np.uint32 if word_bits == 32 else np.uint64
+    mask = _random_lanes(n, W, dt, seed=word_bits + W)
+    want = _pull_reference(rel, mask)
+    with (jax.enable_x64(True) if word_bits == 64
+          else contextlib.nullcontext()):
+        for rule in (HUB_RULE, NO_BLOCK):
+            g = build_ell(rel.indptr, rel.indices, dense=rule)
+            assert (g.dense is None) == (rule is NO_BLOCK)
+            prepared = prepare_parts(device_ell(g), W)
+            frontier = np.zeros((n + 1, W), dt)
+            frontier[g.new_of_old] = mask
+            got = np.asarray(_ell_hop(prepared, jnp.asarray(frontier), W,
+                                      jnp.dtype(dt).type))
+            assert got.dtype == dt and not got[n].any()
+            assert np.array_equal(got[g.new_of_old], want)
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+def test_the_same_structure_under_other_names_builds_the_same_shapes(seed):
+    n, _pairs = _hub_pairs()
+    names = np.random.default_rng(seed).permutation(n)
+    a, b = (build_ell(rel.indptr, rel.indices, dense=HUB_RULE)
+            for rel in (_hub_rel(), _hub_rel(names)))
+
+    def shapes(g):
+        return ([(kind, rows, None if e is None else e.shape)
+                 for kind, e, rows in g.parts], g.tiles.shape,
+                [t.shape for t in g.lvl2], g.seg_rows, g.ks,
+                g.dense[0].shape, g.dense[1].shape, g.dense_edges,
+                g.padded_edges)
+
+    assert shapes(a) == shapes(b)
+    # the same rows and columns, under their other names
+    assert set(names[_block_rows(a)]) == set(_block_rows(b))
+    real = a.dense[1] < n
+    assert set(names[a.perm_order[a.dense[1][real]]]) == set(
+        b.perm_order[b.dense[1][real]])
+
+
+def _ell_digest(g):
+    import hashlib
+    h = hashlib.sha1()
+
+    def put(a):
+        a = np.ascontiguousarray(a)
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(a.tobytes())
+
+    for kind, e, rows in g.parts:
+        h.update(f"{kind}{rows}".encode())
+        if e is not None:
+            put(e)
+    h.update(f"{g.n} {g.seg_rows} {g.ks}".encode())
+    for a in [g.tiles] + list(g.lvl2) + [g.outdeg, g.perm_order,
+                                         g.new_of_old]:
+        if a is not None:
+            put(a)
+    return h.hexdigest()
+
+
+# (relation, the digest of what PR 37's build_ell made of it, its
+# padded_edges): a graph under the edge floor builds what it always did
+UNDER_THE_FLOOR = {
+    "powerlaw": (lambda: powerlaw_rel(500, 8.0, seed=4),
+                 "6041cb8e1c55a1b2bc72b05802ebf08c5e400ac3", 2551),
+    "uniform": (lambda: uniform_rel(64, 48, seed=3),
+                "16df1c837bd7f06ae9cc5de368d2e1ec2feac67e", 2380),
+    "powerlaw2000": (lambda: powerlaw_rel(2000, 10.0, seed=6),
+                     "95ac5efed6fe0eab767ec9d0f1b00f3ba17306ed", 17077),
+    "hub": (_hub_rel, None, None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(UNDER_THE_FLOOR))
+def test_a_graph_under_the_edge_floor_builds_no_block(name):
+    import dataclasses
+    maker, digest, padded = UNDER_THE_FLOOR[name]
+    rel = maker()
+    g = build_ell(rel.indptr, rel.indices)
+    assert g.dense is None and g.dense_edges == 0
+    if digest:
+        assert (_ell_digest(g), g.padded_edges) == (digest, padded)
+    off = build_ell(rel.indptr, rel.indices, dense=NO_BLOCK)
+    for f in dataclasses.fields(g):
+        a, b = getattr(g, f.name), getattr(off, f.name)
+        if f.name == "parts":
+            assert [(k, r) for k, _e, r in a] == [(k, r) for k, _e, r in b]
+            a, b = [e for _k, e, _r in a], [e for _k, e, _r in b]
+        if isinstance(a, list):
+            assert len(a) == len(b)
+            for x, y in zip(a, b):
+                assert np.array_equal(x, y), f.name
+        else:
+            assert np.array_equal(a, b), f.name
+
+
+@pytest.mark.parametrize("caps", ["mixed", "pull"])
+@pytest.mark.parametrize("lanes", [32, 64])
+def test_the_step_with_the_block_runs_the_hops_of_one_without(lanes, caps):
+    """make_ell_step over the hub relation, `near` given, hops pushed and
+    pulled: with the block and without, the same levels, the same seen,
+    the same count of hops run and pushed, the same lanes left open."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import device_ell, make_ell_step, out_csr
+    from dgraph_tpu.store.store import _csr_from_pairs
+    rel = _hub_rel()
+    n = rel.indptr.shape[0] - 1
+    rrel = _csr_from_pairs(rel.indices, np.repeat(
+        np.arange(n, dtype=np.int32), np.diff(rel.indptr)), n)
+    W = lanes // 32
+    rng = np.random.default_rng(lanes)
+    # sources of the periphery, three out-edges each: hop 1 fits the caps
+    srcs, dsts = rng.integers(160, n, lanes), rng.integers(0, n, lanes)
+    runs = []
+    for rule in (HUB_RULE, NO_BLOCK):
+        g = build_ell(rel.indptr, rel.indices, dense=rule)
+        dev = device_ell(g)
+        dev.out = jax.device_put(out_csr(g, rel.indptr, rel.indices))
+        mask0 = np.zeros((n + 1, W), np.uint32)
+        near = np.zeros((n + 1, W), np.uint32)
+        for q in range(lanes):
+            wq, bq = q // 32, np.uint32(1 << (q % 32))
+            mask0[g.new_of_old[srcs[q]], wq] |= bq
+            near[g.new_of_old[rrel.row(int(dsts[q]))], wq] |= bq
+        step = make_ell_step(dev, n, W, 4, caps=HUB_CAPS[caps])
+        f, s, hops, ran, open_, pushed = step(
+            jax.device_put(mask0), jax.device_put(mask0),
+            jax.device_put(near), _packed(range(lanes), W), 4)
+        ran, pushed = int(ran), int(pushed)
+        runs.append((ran, pushed, np.asarray(open_),
+                     np.asarray(s)[g.new_of_old],
+                     [np.asarray(h)[g.new_of_old] for h in hops[:ran]]))
+    (ran, pushed, open_, seen, hops), other = runs
+    assert ran >= 2 and (ran, pushed) == other[:2]
+    assert pushed == 0 if caps == "pull" else 0 < pushed < ran
+    assert np.array_equal(open_, other[2])
+    assert np.array_equal(seen, other[3])
+    for a, b in zip(hops, other[4]):
+        assert np.array_equal(a, b)
+    # and a level is what a plain pull of the one before makes of it
+    seeds = np.zeros((n, W), np.uint32)
+    for q in range(lanes):
+        seeds[srcs[q], q // 32] |= np.uint32(1 << (q % 32))
+    assert np.array_equal(hops[0], _pull_reference(rel, seeds) & ~seeds)
+
+
+@pytest.mark.parametrize("keep_hops", [False, True])
+@pytest.mark.parametrize("caps_name", ["pull", "mixed"])
+def test_the_tree_with_the_block_counts_what_one_without_counts(caps_name,
+                                                                keep_hops):
+    """make_ell_tree: a recurse stage of three hops and a hop stage over
+    its set, both over the hub relation. Counts, traversed edges, pushed
+    hops, sets and hop masks equal with the block and without."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import (device_ell, make_ell_tree, out_csr,
+                                    prepare_parts)
+    rel = _hub_rel()
+    n = rel.indptr.shape[0] - 1
+    W = 2
+    seeds = np.zeros((n + 1, W), np.uint32)
+    seeds[160:n] = _random_lanes(n - 160, W, np.uint32, seed=5, per_lane=1)
+    runs = []
+    for rule in (HUB_RULE, NO_BLOCK):
+        g = build_ell(rel.indptr, rel.indices, dense=rule)
+        dev = device_ell(g)
+        common = {
+            "prepared": prepare_parts(dev, W),
+            "perm_in": jax.device_put(np.concatenate(
+                [g.perm_order, [n]]).astype(np.int32)),
+            "out_idx": jax.device_put(np.concatenate(
+                [g.new_of_old, [n]]).astype(np.int32)),
+            "filt": None}
+        tree = make_ell_tree([
+            {**common, "kind": "recurse", "parent": ("seed", 0), "depth": 3,
+             "keep_hops": keep_hops, "caps": HUB_CAPS[caps_name],
+             "out": jax.device_put(out_csr(g, rel.indptr, rel.indices))},
+            {**common, "kind": "hop", "parent": ("stage", 0)}], n, W)
+        (seen, count, edges, pushed, hops), mask = tree(
+            (jax.device_put(seeds),), ())
+        runs.append((np.asarray(seen)[np.append(g.new_of_old, n)],
+                     np.asarray(count), np.asarray(edges), int(pushed),
+                     None if hops is None else np.asarray(hops),
+                     np.asarray(mask)))
+    with_block, without = runs
+    for a, b in zip(with_block, without):
+        assert np.array_equal(a, b)
+    seen, count, _edges, pushed, hops, mask = with_block
+    assert pushed == (0 if caps_name == "pull" else 1)
+    assert (hops is not None) == keep_hops
+    assert np.array_equal(mask[:n], _pull_reference(rel, seen[:n]))
+    assert count.sum() == sum(bin(int(w)).count("1")
+                              for w in seen[:n].ravel())

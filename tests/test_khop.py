@@ -160,6 +160,56 @@ def test_launch_returns_counts_and_no_hop_masks(g500):
 
 
 # ---------------------------------------------------------------------------
+# the Kronecker graph's hub core as a dense block beside the ELL
+
+@pytest.fixture(scope="module")
+def g500_hub(g500):
+    """g500 in a store of its own, whose `link` ELL is built under a rule
+    of its size (ops/bfs.py _choose_dense at 32,768 edges: no break-even
+    to speak of, a block of at most 256 x 512, a floor of 500 edges)."""
+    from dgraph_tpu.engine.batch import _ell_for
+    from dgraph_tpu.ops import bfs
+    from dgraph_tpu.store.schema import parse_schema
+    from dgraph_tpu.store.store import Store, build_indexes
+    data, _store, ref = g500
+    schema = parse_schema(gen.SCHEMA)
+    uids, preds = loader.build(data, schema)
+    build_indexes(preds)
+    store = Store(uids=uids, schema=schema, preds=preds)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(bfs, "DENSE_CELLS_PER_EDGE", 1e9)
+        mp.setattr(bfs, "DENSE_MAX_BYTES", 256 * 512)
+        mp.setattr(bfs, "DENSE_MIN_EDGES", 500)
+        g = _ell_for(store, "link", False)
+    assert g.dense is not None and g.dense_edges > g.nnz // 10
+    return data, store, ref
+
+
+@pytest.mark.parametrize("lanes", [32, 64])
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_counts_over_the_hub_block(g500_hub, depth, lanes):
+    """The same counts with a tenth and more of the in-edges answered by
+    the block's product, and the pulled hops counted in in-edges."""
+    from dgraph_tpu.engine.batch import _ell_for
+    data, store, _ref = g500_hub
+    g = _ell_for(store, "link", False)
+    sources = np.nonzero(data["row_len"] > 0)[0]
+    rng = np.random.default_rng([depth, lanes, 38])
+
+    def counters():
+        return [METRICS.get(f"kernel_{k}_total", family="tree")
+                for k in ("edges_pulled", "edges_dense", "hops_run",
+                          "hops_push")]
+
+    before = counters()
+    check_counts(g500_hub, rng.choice(sources, lanes, replace=False), depth)
+    pulled, dense, run, push = (x - y for x, y in zip(counters(), before))
+    assert run == depth
+    assert (pulled, dense) == ((run - push) * g.nnz,
+                               (run - push) * g.dense_edges)
+
+
+# ---------------------------------------------------------------------------
 # other consumers of the stage's var read its column, or, under a filter
 # that index lookups answer, ask it about the filter's candidates
 

@@ -128,3 +128,35 @@ def test_tree_program_with_the_push_compiles_for_the_v5e(one_chip):
     assert constants and max(constants) <= 4096
     # the out-CSR's edges are among the parameters
     assert c.memory_analysis().argument_size_in_bytes > 4 * edges
+
+
+@pytest.mark.parametrize("rows,cols", [(32640, 64896), (768256, 2048)])
+def test_pull_with_the_hub_block_compiles_for_the_v5e(one_chip, rows, cols):
+    """A pull whose hub block is the Graph500 cell's (32,640 x 64,896) or
+    the follower cell's (768,256 x 2,048) int8: the block's share is ONE
+    matrix product of int8 operands summed in int32 (the MXU's own, no
+    widened copy of the block), the block a parameter, and what the hop
+    holds besides its arguments stays small beside them."""
+    import re
+
+    from dgraph_tpu.ops import bfs
+    n, tiles, body = 2395982, 1 << 20, 1 << 20
+
+    def pull(block, cols_, e, te, t2, frontier):
+        prepared = {"parts": [("chain", e, body), ("zero", None, n - body
+                                                   - (1 << 16))],
+                    "tiles": ("chain", te, tiles), "lvl2": [t2],
+                    "dense": (block, cols_), "seg_rows": 1 << 16, "n": n}
+        return bfs._ell_hop(prepared, frontier, W)
+
+    c = _compiled(one_chip, pull, ((rows, cols), jnp.int8),
+                  ((cols,), jnp.int32), ((body, 4), jnp.int32),
+                  ((tiles, 8), jnp.int32), ((1 << 16, 16), jnp.int32),
+                  ((n + 1, W), jnp.uint32))
+    hlo = c.as_text()
+    products = re.findall(r"= (\w+)\[[\d,]*\]\S* convolution\(", hlo)
+    assert products == ["s32"]
+    assert f"s8[{rows},{cols}]" in hlo and f"bf16[{rows},{cols}]" not in hlo
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes > rows * cols
+    assert mem.temp_size_in_bytes < 1 << 30
