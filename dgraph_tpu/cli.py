@@ -33,12 +33,14 @@ def run_heartbeat_loop(kind: str, interval_s: float, step, log,
     ends the loop — tests drive it; the CLI never sets it."""
     import threading
 
+    from dgraph_tpu.utils import tracing
     from dgraph_tpu.utils.metrics import METRICS
     stop = stop or threading.Event()
     fails = 0
     while not stop.wait(interval_s):
         try:
-            step()
+            with tracing.background("heartbeat"):
+                step()
             if fails >= HEARTBEAT_ERROR_AFTER:
                 log.info("%s heartbeat recovered after %d failures",
                          kind, fails)
@@ -192,10 +194,13 @@ def cmd_alpha(args) -> int:
                  "admission/planning fall back to count + lane EMA")
     if cfg.slow_query_ms:
         log.info("slow-query log armed at %d ms", cfg.slow_query_ms)
+    # what takes a request's thread off its CPU is counted from here
+    # on: the collector's pauses, and the series no request feeds at 0
+    from dgraph_tpu.utils import tracing
+    tracing.arm()
     if cfg.trace_dir:
         # the default directory of on-demand device-timeline captures
         # (POST /debug/profile start/stop, tracing.profile_start)
-        from dgraph_tpu.utils import tracing
         tracing.enable_device_trace(cfg.trace_dir)
         log.info("device trace capture dir: %s", cfg.trace_dir)
     pusher = None

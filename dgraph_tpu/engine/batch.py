@@ -396,19 +396,22 @@ def run_batch(store, plan, device_threshold: int) -> list:
 
     from dgraph_tpu.ops.bfs import pack_seed_masks
 
-    g = _ell_for(store, plan.attr, plan.reverse)
-    if g is None:
-        return None
+    # the phases of the two other routes, under their names, once a request
+    with tracing.span("batch.seed", phase=True, queries=len(plan.blocks)):
+        g = _ell_for(store, plan.attr, plan.reverse)
+        if g is None:
+            return None
 
-    # root seed ranks per query (host index lookups, as run_block does).
-    # Lane words round UP to a power of two: padding lanes are zero-seeded
-    # and free, and bucketing bounds distinct kernel compiles at O(log B)
-    # instead of one multi-second XLA compile per client batch size.
-    ex0 = Executor(store, device_threshold=device_threshold)
-    seeds = [ex0.root_ranks(sg) for sg in plan.blocks]
-    B = _lane_count(len(seeds))
-    seed_lists = seeds + [np.zeros(0, np.int32)] * (B - len(seeds))
-    mask0 = pack_seed_masks(g, seed_lists)
+        # root seed ranks per query (host index lookups, as run_block
+        # does). Lane words round UP to a power of two: padding lanes are
+        # zero-seeded and free, and bucketing bounds distinct kernel
+        # compiles at O(log B) instead of one multi-second XLA compile
+        # per client batch size.
+        ex0 = Executor(store, device_threshold=device_threshold)
+        seeds = [ex0.root_ranks(sg) for sg in plan.blocks]
+        B = _lane_count(len(seeds))
+        seed_lists = seeds + [np.zeros(0, np.int32)] * (B - len(seeds))
+        mask0 = pack_seed_masks(g, seed_lists)
 
     # kernel launch gate: past here the fused multi-hop program is one
     # uninterruptible XLA dispatch — the budget check happens before
@@ -438,12 +441,20 @@ def run_batch(store, plan, device_threshold: int) -> list:
                 # undonated buffer) and let the scan reuse it
                 return fn(jax.device_put(mask0), plan.depth, True)
 
-        # allocation failure: evict-to-low + one retry; a second failure
-        # sticky-degrades this launch shape and OomDegraded propagates —
-        # api.query_batch's per-query fallback serves bit-identically
-        _last, _seen, _edges, hops = memgov.oom_retry(
-            "bfs.ell_recurse", lkey, _launch)
-        hops = np.asarray(hops)      # [depth, n+1, W] fresh masks
+        with tracing.span("batch.device_wait", phase=True,
+                          hops=plan.depth):
+            # allocation failure: evict-to-low + one retry; a second
+            # failure sticky-degrades this launch shape and OomDegraded
+            # propagates — api.query_batch's per-query fallback serves
+            # bit-identically
+            _last, _seen, _edges, hops = memgov.oom_retry(
+                "bfs.ell_recurse", lkey, _launch)
+            # the dispatch returns at once: the span ends when the
+            # device has run the hops
+            jax.block_until_ready(hops)
+        with tracing.span("batch.fetch", phase=True) as fsp:
+            hops = np.asarray(hops)      # [depth, n+1, W] fresh masks
+            fsp.attrs["bytes"] = hops.nbytes
     # launch count + dispatch gap are recorded by jit_call itself
     exec_us = sp.dur_us
     costprofile.add_kernel("recurse", execute_us=exec_us)
@@ -456,17 +467,19 @@ def run_batch(store, plan, device_threshold: int) -> list:
     note_pulls(g, "recurse", plan.depth)
     rel = store.rel(plan.attr, plan.reverse)
 
-    root_nodes = [np.unique(s).astype(np.int32) for s in seeds]
-    datas = _rebuild_recurse_batch(store, g, rel, hops, plan.blocks,
-                                   root_nodes)
-    out = []
-    for q, sg in enumerate(plan.blocks):
-        ex = Executor(store, device_threshold=device_threshold)
-        node = LevelNode(sg=sg, nodes=root_nodes[q],
-                         display=root_nodes[q])
-        _bind_recurse_vars(ex, node, datas[q], sg)
-        node.recurse_data = datas[q]
-        out.append(to_json(ex, [node]))
+    with tracing.span("batch.render", phase=True,
+                      queries=len(plan.blocks)):
+        root_nodes = [np.unique(s).astype(np.int32) for s in seeds]
+        datas = _rebuild_recurse_batch(store, g, rel, hops, plan.blocks,
+                                       root_nodes)
+        out = []
+        for q, sg in enumerate(plan.blocks):
+            ex = Executor(store, device_threshold=device_threshold)
+            node = LevelNode(sg=sg, nodes=root_nodes[q],
+                             display=root_nodes[q])
+            _bind_recurse_vars(ex, node, datas[q], sg)
+            node.recurse_data = datas[q]
+            out.append(to_json(ex, [node]))
     return out
 
 
@@ -589,23 +602,26 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
     levels: list[np.ndarray] = []      # [n+1, W] per hop, permuted space
     with tracing.span("batch.seed", phase=True,
                       queries=len(plan.queries)) as sp:
-        g = _ell_for(store, plan.attr, plan.reverse)
-        if g is None:
-            return None
-        rrel = store.rel(plan.attr, not plan.reverse)
-        if rrel.nnz == 0:
-            return None
-        n = g.n
-        B = len(plan.queries)
+        # four child spans, ring only: with the phase's own CPU and
+        # system time they say where a launch's preparation goes
+        with tracing.span("seed.ranks"):
+            g = _ell_for(store, plan.attr, plan.reverse)
+            if g is None:
+                return None
+            rrel = store.rel(plan.attr, not plan.reverse)
+            if rrel.nnz == 0:
+                return None
+            n = g.n
+            B = len(plan.queries)
 
-        src = store.rank_of(np.asarray(plan.src_uids, np.int64))
-        dst = store.rank_of(np.asarray(plan.dst_uids, np.int64))
-        lanes = _lane_count(B)
-        W = lanes // 32
+            src = store.rank_of(np.asarray(plan.src_uids, np.int64))
+            dst = store.rank_of(np.asarray(plan.dst_uids, np.int64))
+            lanes = _lane_count(B)
+            W = lanes // 32
 
-        # lanes needing a kernel at all: known endpoints, src != dst
-        active = [q for q in range(B)
-                  if src[q] >= 0 and dst[q] >= 0 and src[q] != dst[q]]
+            # lanes needing a kernel at all: known endpoints, src != dst
+            active = [q for q in range(B)
+                      if src[q] >= 0 and dst[q] >= 0 and src[q] != dst[q]]
         # the look-ahead (numpaths = 1): a lane closes at the hop that
         # reaches an in-neighbour of its target, so a path of d edges
         # takes d - 1 hops, and the depth cap, which counts edges, one
@@ -616,10 +632,11 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
         hop_cap = plan.depth - 1 if plan.first_visit else plan.depth
         opened, near_rows = active, {}
         if plan.first_visit:
-            for q in active:
-                preds = rrel.row(int(dst[q]))
-                if len(preds) and not (preds == src[q]).any():
-                    near_rows[q] = g.new_of_old[preds]
+            with tracing.span("seed.near"):
+                for q in active:
+                    preds = rrel.row(int(dst[q]))
+                    if len(preds) and not (preds == src[q]).any():
+                        near_rows[q] = g.new_of_old[preds]
             METRICS.inc("kernel_lanes_closed_total",
                         float(len(active) - len(near_rows)),
                         family="shortest", by="seed")
@@ -628,13 +645,14 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
         sp.attrs.update(lanes=lanes, active=len(active),
                         opened=len(opened))
         if opened:
-            mask0 = np.zeros((n + 1, W), np.uint32)
-            near = np.zeros_like(mask0) if plan.first_visit else None
-            for q in opened:
-                wq, bq = q // 32, np.uint32(1 << (q % 32))
-                mask0[g.new_of_old[int(src[q])], wq] |= bq
-                if plan.first_visit:
-                    near[near_rows[q], wq] |= bq
+            with tracing.span("seed.masks"):
+                mask0 = np.zeros((n + 1, W), np.uint32)
+                near = np.zeros_like(mask0) if plan.first_visit else None
+                for q in opened:
+                    wq, bq = q // 32, np.uint32(1 << (q % 32))
+                    mask0[g.new_of_old[int(src[q])], wq] |= bq
+                    if plan.first_visit:
+                        near[near_rows[q], wq] |= bq
             deadline.checkpoint("kernel")
             METRICS.inc("kernel_group_launches_total", family="shortest")
             METRICS.inc("kernel_group_queries_total", float(B),
@@ -644,16 +662,18 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
             _note_kernel_features(plan.attr, "shortest", lanes, lanes - B,
                                   plan.depth, B)
             costprofile.note_max("bucket_mix", len(g.parts))
-            step = _step_for(store, plan.attr, plan.reverse, W,
-                             plan.first_visit)
-            skey = (plan.attr, plan.reverse, W, plan.first_visit, n)
-            if memgov.GOVERNOR.is_degraded("bfs.ell_step", skey):
-                # sticky OOM degrade: the per-query path serves this shape
-                raise memgov.OomDegraded("bfs.ell_step", str(skey))
-            unresolved = {q: None for q in opened}   # lanes still open
-            frontier = jax.device_put(mask0)
-            seen = jax.device_put(mask0)
-            near = jax.device_put(near)
+            with tracing.span("seed.upload"):
+                step = _step_for(store, plan.attr, plan.reverse, W,
+                                 plan.first_visit)
+                skey = (plan.attr, plan.reverse, W, plan.first_visit, n)
+                if memgov.GOVERNOR.is_degraded("bfs.ell_step", skey):
+                    # sticky OOM degrade: the per-query path serves this
+                    # shape
+                    raise memgov.OomDegraded("bfs.ell_step", str(skey))
+                unresolved = {q: None for q in opened}   # lanes still open
+                frontier = jax.device_put(mask0)
+                seen = jax.device_put(mask0)
+                near = jax.device_put(near)
     if opened:
         with tracing.span("batch.shortest_kernel", attr=plan.attr,
                           depth=plan.depth, queries=B, lanes=lanes,

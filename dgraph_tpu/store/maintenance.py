@@ -297,13 +297,14 @@ class MaintenanceScheduler:
             with self._cv:
                 if self._stop:
                     return
-            job = None if self.paused else self._next_job()
+            with tracing.background("maintenance"):
+                job = None if self.paused else self._next_job()
+                if job is not None:
+                    self._run(job)
             if job is None:
                 with self._cv:
                     if not self._stop:
                         self._cv.wait(0.05)
-                continue
-            self._run(job)
 
     def _run(self, job: Job) -> None:
         with self._cv:

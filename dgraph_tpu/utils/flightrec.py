@@ -236,7 +236,8 @@ class Watchdog:
     def _loop(self) -> None:
         while not self._stop.wait(self.poll_s):
             try:
-                self._tick()
+                with tracing.background("flightrec"):
+                    self._tick()
             except Exception:  # noqa: BLE001 — the watchdog must outlive bugs
                 xlog.get("flightrec").exception("watchdog tick failed")
 

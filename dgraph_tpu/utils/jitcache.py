@@ -47,7 +47,7 @@ def jit_call(kernel: str, key: tuple):
     site reached, and the host-side gap since the previous launch in
     the same recorder frame lands in `launch_gap_us` (the dispatch-
     overhead baseline the whole-query fused path collapses to a single
-    launch)."""
+    launch). A cache hit also feeds `jit_dispatch_us{kernel=}`."""
     from dgraph_tpu.utils import costprofile
     with _lock:
         new = (kernel, key) not in _seen
@@ -73,7 +73,14 @@ def jit_call(kernel: str, key: tuple):
                 # model needs)
                 costprofile.add_kernel(kernel, compile_us=compile_us)
     finally:
-        costprofile.note_launch(t0, time.perf_counter())
+        t1 = time.perf_counter()
+        if not new:
+            # a seen key compiles nothing: from here to the jitted
+            # call's return is the dispatch, the host's part of a
+            # launch before any wait for the device begins
+            METRICS.observe("jit_dispatch_us", (t1 - t0) * 1e6,
+                            kernel=kernel)
+        costprofile.note_launch(t0, t1)
 
 
 def reset() -> None:

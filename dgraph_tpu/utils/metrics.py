@@ -67,12 +67,25 @@ class Registry:
         self._label_limits: dict[str, int] = {}  # per-name cap overrides
         self.max_label_sets = MAX_LABEL_SETS
         self._enabled = True
+        self._collectors: list = []
         locks.guarded(self, "metrics.registry")
 
     def set_enabled(self, flag: bool) -> None:
         """Disarm recording (render/snapshot still serve what exists) —
         the switch the <5% query-path overhead guard flips."""
         self._enabled = bool(flag)
+
+    def add_collector(self, fn) -> None:
+        """Run `fn()` before every read of the registry (`render` and
+        the snapshots), outside its lock: for a producer that may not
+        take a lock where its events happen (the collector-pause hook
+        of utils/tracing.py) and books them when somebody looks."""
+        if fn not in self._collectors:
+            self._collectors.append(fn)
+
+    def _collect(self) -> None:
+        for fn in tuple(self._collectors):
+            fn()
 
     def set_label_limit(self, name: str, n: int) -> None:
         """Per-name override of the label-set cardinality cap."""
@@ -136,6 +149,18 @@ class Registry:
             h[1] += value
             h[2] += 1
 
+    def declare_hist(self, name: str, buckets: tuple | None = None,
+                     **labels) -> None:
+        """Create a histogram series with no observation, so a reader
+        finds 0 where nothing has happened yet, and not nothing."""
+        if not self._enabled:
+            return
+        with self._lock:
+            k = (name, self._guard(name, _label_key(labels)))
+            bks = self._hist_buckets.setdefault(
+                name, tuple(buckets) if buckets else BUCKETS_US)
+            self._hists.setdefault(k, [[0] * (len(bks) + 1), 0.0, 0])
+
     def get(self, name: str, **labels) -> float:
         """Current counter value (0.0 when the series doesn't exist)."""
         with self._lock:
@@ -143,6 +168,7 @@ class Registry:
 
     def render(self) -> str:
         """Prometheus text exposition format."""
+        self._collect()
         out = []
         with self._lock:
             for kind, table in (("counter", self._counters),
@@ -176,6 +202,7 @@ class Registry:
     def snapshot(self) -> dict:
         """Flat dict view. Label-free series keep their bare name (the
         historical shape); labeled ones render as `name{k="v",...}`."""
+        self._collect()
         with self._lock:
             return {
                 "counters": {_series(n, lk): v
@@ -189,6 +216,7 @@ class Registry:
         series name → {"buckets": ladder, "counts": cumulative-free
         per-bucket counts (last slot = +Inf), "sum": Σvalues, "n": N}.
         Copies under the lock so the sampler diffs stable points."""
+        self._collect()
         with self._lock:
             return {
                 _series(n, lk): {"buckets": self._hist_buckets[n],

@@ -110,7 +110,8 @@ class TelemetryPusher:
                 self._last_cycle_mono = time.monotonic()
             if self._stop.wait(delay):
                 return
-            self._push_once()
+            with tracing.background("push"):
+                self._push_once()
             with self._lock:
                 self._last_cycle_mono = time.monotonic()
 

@@ -30,7 +30,7 @@ import threading
 import time
 from collections import deque
 
-from dgraph_tpu.utils import locks
+from dgraph_tpu.utils import locks, tracing
 from dgraph_tpu.utils.metrics import METRICS
 
 __all__ = ["Ring", "Window", "Forecast", "Sampler", "arm", "disarm",
@@ -447,7 +447,8 @@ class Sampler:
     def _loop(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.tick()
+                with tracing.background("timeseries"):
+                    self.tick()
             except Exception:
                 from dgraph_tpu.utils import logging as xlog
                 xlog.get("timeseries").exception("sampler tick failed")

@@ -343,8 +343,14 @@ def test_maintenance_job_joins_admin_trace(tmp_path):
         with urllib.request.urlopen(req, timeout=30) as r:
             doc = json.loads(r.read())
         assert doc["data"]["trace_id"] == tid
-        spans = tracing.trace_spans(tid)
-        names = [s.name for s in spans]
+        # the admin request's root closes after the response's last byte
+        deadline = time.monotonic() + 5.0
+        while True:
+            spans = tracing.trace_spans(tid)
+            names = [s.name for s in spans]
+            if "http.admin" in names or time.monotonic() > deadline:
+                break
+            time.sleep(0.005)
         # the admin request AND the scheduler-thread job are ONE trace
         assert "http.admin" in names
         assert "maintenance.job" in names

@@ -550,3 +550,333 @@ def test_query_path_overhead_under_5_percent(monkeypatch):
         f"observability costs {share:.1%} of the hot query path: "
         f"{spans} spans at {span_s * 1e6:.1f} us and {recordings} "
         f"recordings at {rec_s * 1e6:.1f} us in {off * 1e3:.2f} ms")
+
+
+# ---------------------------------------------------------------------------
+# what the thread was doing: CPU beside wall, and what takes it off its CPU
+# (counts and wide inequalities: no test here holds a clock to a budget)
+
+def _counter(name: str, **labels) -> float:
+    from dgraph_tpu.utils.metrics import METRICS
+    return METRICS.get(name, **labels)
+
+
+def _hist(name: str, **labels) -> tuple:
+    """(sum, count) of one histogram series, (0, 0) where it is not."""
+    from dgraph_tpu.utils.metrics import METRICS, _label_key, _series
+    h = METRICS.hist_snapshot().get(_series(name, _label_key(labels)))
+    return (h["sum"], h["n"]) if h else (0.0, 0)
+
+
+@pytest.fixture()
+def armed():
+    """`tracing.arm()` as `alpha` calls it, and the collector's hook
+    taken out again: the other tests' process stays as it was."""
+    import gc
+    tracing.arm()
+    yield
+    gc.callbacks.remove(tracing._gc_hook)
+    tracing._GC_PENDING.clear()
+    del tracing._GC_OPEN[:]
+
+
+def test_a_sleeping_phase_is_off_cpu_and_a_spinning_one_on():
+    with tracing.trace("http.t", endpoint="t_cpu"):
+        with tracing.span("t.sleeps", phase=True) as slept:
+            time.sleep(0.05)
+        with tracing.span("t.spins", phase=True) as spun:
+            t0 = time.thread_time_ns()
+            while time.thread_time_ns() - t0 < 30_000_000:
+                pass
+    assert slept.dur_us >= 50_000
+    assert slept.cpu_us + slept.sys_us < 10_000
+    off = _counter("phase_offcpu_us_total", span="t.sleeps",
+                   endpoint="t_cpu")
+    assert off >= 40_000
+    assert off == slept.dur_us - slept.cpu_us - slept.sys_us
+    # the spin ran 30 ms of thread time by its own reading, all of it
+    # inside the phase's wall time (the clocks are read just outside it)
+    on = spun.cpu_us + spun.sys_us
+    assert 30_000 <= on <= spun.dur_us + 1_000
+    assert (_counter("phase_cpu_us_total", span="t.spins",
+                     endpoint="t_cpu", mode="user"),
+            _counter("phase_cpu_us_total", span="t.spins",
+                     endpoint="t_cpu", mode="sys")) \
+        == (spun.cpu_us, spun.sys_us)
+    assert _counter("phase_offcpu_us_total", span="t.spins",
+                    endpoint="t_cpu") == max(spun.dur_us - on, 0)
+
+
+def test_a_coarse_clock_overdraws_a_phase_and_the_next_one_pays(
+        monkeypatch):
+    """The chip's machine counts a thread's CPU in ticks of 10 ms: the
+    phase a tick lands in is billed all of it. What that bills beyond
+    the phase's wall time is owed by the series' next off-CPU time, so
+    the window's sum is its wall less its CPU."""
+    ticks = iter([0, 10, 10, 10, 10, 20])      # ms of thread CPU so far
+    monkeypatch.setattr(
+        tracing, "_thread_clocks",
+        lambda: (next(ticks) * 1_000_000, 0, 0, 0, 0, 0, 0, 0))
+    spans = []
+    for sleep_s in (0.001, 0.012, 0.001):
+        with tracing.span("t.ticked", phase=True) as sp:
+            time.sleep(sleep_s)
+        spans.append(sp)
+    assert [s.cpu_us for s in spans] == [10_000, 0, 10_000]
+    wall = sum(s.dur_us for s in spans)
+    assert 14_000 <= wall < 20_000     # 14 ms slept: the last tick is owed
+    off = _counter("phase_offcpu_us_total", span="t.ticked", endpoint="")
+    # the first phase overdrew 10 ms less its own wall; the second paid
+    assert off == spans[0].dur_us + spans[1].dur_us - 10_000 > 0
+    assert tracing._OFFCPU_OWED["t.ticked", ""] \
+        == 10_000 - spans[2].dur_us
+
+
+def test_a_root_bills_its_request_and_feeds_no_phase_series():
+    from dgraph_tpu.utils.metrics import METRICS
+    with tracing.trace("http.t", endpoint="t_root") as tid:
+        with tracing.span("t.inner", phase=True):
+            time.sleep(0.002)            # one voluntary switch at least
+    root = tracing.trace_spans(tid)[-1]
+    assert root.name == "http.t" and root.cpu_us + root.sys_us > 0
+    assert _counter("request_ctx_switches_total", endpoint="t_root",
+                    kind="voluntary") >= 1
+    rendered = METRICS.render()
+    for series in ('request_ctx_switches_total{endpoint="t_root",'
+                   'kind="involuntary"}',
+                   'request_page_faults_total{endpoint="t_root",'
+                   'kind="minor"}',
+                   'request_page_faults_total{endpoint="t_root",'
+                   'kind="major"}',
+                   'request_other_cpu_us_total{endpoint="t_root"}'):
+        assert "dgraph_tpu_" + series + " " in rendered, series
+    # a root is no phase: only `t.inner` has phase series
+    assert 'span="http.t"' not in rendered
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_thread_clocks_are_read_at_a_phase_or_root_and_nowhere_else(
+        monkeypatch, enabled):
+    real, calls = tracing._thread_clocks, []
+
+    def counting():
+        calls.append(1)
+        return real()
+
+    monkeypatch.setattr(tracing, "_thread_clocks", counting)
+    tracing.set_enabled(enabled)
+    seen = []
+    with tracing.trace("http.t", endpoint="t_count"):
+        seen.append(len(calls))          # the root's open
+        with tracing.span("t.plain"):
+            with tracing.span("t.plain2", k=1):
+                pass
+        seen.append(len(calls))          # spans that are neither: none
+        with tracing.span("t.phase", phase=True):
+            seen.append(len(calls))      # the phase's open
+        seen.append(len(calls))          # its close
+    seen.append(len(calls))              # the root's close
+    with tracing.span("t.plain3"):
+        pass
+    seen.append(len(calls))
+    assert seen == ([1, 1, 2, 3, 4, 4] if enabled else [0] * 6)
+
+
+def test_gc_collect_inside_a_phase_is_counted_and_a_child_span(
+        armed, monkeypatch):
+    import gc
+    monkeypatch.setattr(tracing, "GC_SPAN_MIN_US", 0)
+    was = gc.isenabled()
+    gc.disable()                 # the one collection is the explicit one
+    try:
+        before = {(g, on): _hist("gc_pause_us", gen=g, on=on)
+                  for g in range(3) for on in ("request", "background")}
+        with tracing.trace("http.t", endpoint="t_gc") as tid:
+            with tracing.span("t.collects", phase=True) as ph:
+                gc.collect()
+        gc.collect(0)            # and one outside any request
+        after = {k: _hist("gc_pause_us", gen=k[0], on=k[1])
+                 for k in before}
+    finally:
+        if was:
+            gc.enable()
+    grew = {k: after[k][1] - before[k][1] for k in before}
+    assert grew == {**dict.fromkeys(before, 0), (2, "request"): 1,
+                    (0, "background"): 1}
+    spans = tracing.trace_spans(tid)
+    pauses = [s for s in spans if s.name == "gc.collect"]
+    assert len(pauses) == 1 and pauses[0].parent_id == ph.span_id
+    assert pauses[0].attrs["gen"] == 2 and "collected" in pauses[0].attrs
+    assert pauses[0].tid == ph.tid
+    assert after[2, "request"][0] - before[2, "request"][0] \
+        == pauses[0].dur_us <= ph.dur_us
+    # the background one is in the ring, outside any trace
+    assert [s.trace_id for s in tracing.recent(50)
+            if s.name == "gc.collect"] == [tid, ""]
+
+
+def test_a_short_pause_feeds_the_histogram_only_and_disarmed_nothing(
+        armed, monkeypatch):
+    import gc
+    monkeypatch.setattr(tracing, "GC_SPAN_MIN_US", 10**9)
+    n0 = _hist("gc_pause_us", gen=0, on="background")[1]
+    gc.collect(0)
+    tracing.set_enabled(False)
+    gc.collect(0)
+    tracing.set_enabled(True)
+    assert _hist("gc_pause_us", gen=0, on="background")[1] == n0 + 1
+    assert not [s for s in tracing.recent(50) if s.name == "gc.collect"]
+
+
+def test_a_collection_is_a_host_event_of_a_running_capture(
+        armed, fake_profiler, tmp_path):
+    import gc
+    gc.collect(0)
+    assert fake_profiler == []
+    tracing.profile_start(str(tmp_path))
+    del fake_profiler[:]
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        gc.collect(1)
+    finally:
+        if was:
+            gc.enable()
+    assert fake_profiler == [("enter", "gc.collect"),
+                             ("exit", "gc.collect")]
+
+
+def test_arm_creates_at_zero_what_no_request_feeds(armed):
+    from dgraph_tpu.utils.metrics import METRICS
+    rendered = METRICS.render()
+    for g in range(3):
+        for on in ("request", "background"):
+            assert (f'dgraph_tpu_gc_pause_us_count{{gen="{g}",on="{on}"}} '
+                    in rendered)
+    for thread in tracing.BACKGROUND_THREADS:
+        assert (f'dgraph_tpu_background_ticks_total{{thread="{thread}"}} '
+                in rendered)
+    import gc
+    assert gc.callbacks.count(tracing._gc_hook) == 1
+    tracing.arm()                        # twice is once
+    assert gc.callbacks.count(tracing._gc_hook) == 1
+
+
+def test_background_bills_one_tick_and_its_thread_time():
+    ticks = _counter("background_ticks_total", thread="t_daemon")
+    cpu = _counter("background_cpu_us_total", thread="t_daemon")
+    with tracing.background("t_daemon"):
+        t0 = time.thread_time_ns()
+        while time.thread_time_ns() - t0 < 2_000_000:
+            pass
+    assert _counter("background_ticks_total", thread="t_daemon") \
+        == ticks + 1
+    assert _counter("background_cpu_us_total", thread="t_daemon") \
+        >= cpu + 2_000
+    tracing.set_enabled(False)
+    with tracing.background("t_daemon"):
+        pass
+    assert _counter("background_ticks_total", thread="t_daemon") \
+        == ticks + 1
+
+
+def test_every_daemon_loop_bills_its_tick():
+    """The five call sites, by their label: each loop's tick runs inside
+    `tracing.background(<its name>)`."""
+    import inspect
+
+    from dgraph_tpu import cli
+    from dgraph_tpu.store import maintenance
+    from dgraph_tpu.utils import flightrec, push, timeseries
+    sites = {"flightrec": flightrec.Watchdog._loop,
+             "timeseries": timeseries.Sampler._loop,
+             "maintenance": maintenance.MaintenanceScheduler._loop,
+             "heartbeat": cli.run_heartbeat_loop,
+             "push": push.TelemetryPusher._run}
+    assert sorted(sites) == sorted(tracing.BACKGROUND_THREADS)
+    for thread, fn in sites.items():
+        assert f'tracing.background("{thread}")' in inspect.getsource(fn)
+    # and one of them driven: a heartbeat step is one tick
+    ticks = _counter("background_ticks_total", thread="heartbeat")
+    stop = threading.Event()
+    cli.run_heartbeat_loop("t", 0.001, stop.set, None, stop=stop)
+    assert _counter("background_ticks_total", thread="heartbeat") \
+        == ticks + 1
+
+
+def test_jit_call_on_a_seen_key_feeds_jit_dispatch_us():
+    from dgraph_tpu.utils import jitcache
+    key = ("t.kernel", 7)
+    with jitcache.jit_call("t.dispatch", key) as compiling:
+        assert compiling
+    assert _hist("jit_dispatch_us", kernel="t.dispatch") == (0.0, 0)
+    with jitcache.jit_call("t.dispatch", key) as compiling:
+        assert not compiling
+        time.sleep(0.002)
+    total, n = _hist("jit_dispatch_us", kernel="t.dispatch")
+    assert n == 1 and total >= 2_000
+
+
+def test_cpu_fields_survive_the_exports():
+    with tracing.trace("http.t", endpoint="t_export") as tid:
+        with tracing.span("t.phase", phase=True):
+            t0 = time.thread_time_ns()
+            while time.thread_time_ns() - t0 < 2_000_000:
+                pass
+        with tracing.span("t.plain"):
+            pass
+    spans = tracing.trace_spans(tid)
+    phase = next(s for s in spans if s.name == "t.phase")
+    assert phase.cpu_us + phase.sys_us >= 2_000
+    assert tracing.Span(**phase.to_dict()) == phase
+    by_name = {e["name"]: e["args"]
+               for e in tracing.to_chrome(spans)["traceEvents"]}
+    assert (by_name["t.phase"]["cpu_us"], by_name["t.phase"]["sys_us"]) \
+        == (phase.cpu_us, phase.sys_us)
+    assert "cpu_us" not in by_name["t.plain"]    # it read no clock
+    back = tracing.from_otlp(json.loads(json.dumps(tracing.to_otlp(spans))))
+    assert back == spans
+
+
+RECURSE_PHASES = ["batch.seed", "batch.device_wait", "batch.fetch",
+                  "batch.render"]
+
+
+def test_lane_recurse_route_opens_the_routes_phases_once_a_request():
+    alpha, _u = _chain_alpha(13)
+    qs = ['{ q(func: eq(name, "p%d")) @recurse(depth: 3) '
+          '{ name follows } }' % i for i in range(8)]
+    for attempt in ("cold", "warm"):
+        if attempt == "warm":            # new texts: a plan-cache miss
+            qs = [q.replace("q(func", "r(func") for q in qs]
+        with tracing.trace("http.query_batch",
+                           endpoint="query_batch") as tid:
+            out = alpha.query_batch(qs)
+        assert len(out) == 8 and all(o for o in out)
+        by_name = {}
+        for s in tracing.trace_spans(tid):
+            by_name.setdefault(s.name, []).append(s)
+        kernel = by_name["batch.recurse_kernel"][0]
+        for p in RECURSE_PHASES:
+            assert len(by_name.get(p, ())) == 1, (attempt, p)
+        for p in ("batch.device_wait", "batch.fetch"):
+            assert by_name[p][0].parent_id == kernel.span_id
+        assert by_name["batch.fetch"][0].attrs["bytes"] > 0
+        assert len(by_name["batch.plan"]) == 1
+
+
+def test_shortest_seed_names_its_four_parts():
+    alpha, u = _chain_alpha(11)
+    with tracing.trace("http.query_batch", endpoint="query_batch") as tid:
+        alpha.query_batch([_shortest(u[0], u[6]), _shortest(u[1], u[5]),
+                           _shortest(u[4], u[9]), _shortest(u[0], u[3])])
+    spans = tracing.trace_spans(tid)
+    seed = next(s for s in spans if s.name == "batch.seed")
+    parts = [s for s in spans if s.name.startswith("seed.")]
+    assert [s.name for s in parts] == ["seed.ranks", "seed.near",
+                                      "seed.masks", "seed.upload"]
+    assert all(s.parent_id == seed.span_id for s in parts)
+    assert sum(s.dur_us for s in parts) <= seed.dur_us
+    # children, not phases: no clock read, no series
+    assert all(s.cpu_us == s.sys_us == 0 for s in parts)
+    assert not _phase_series("seed.masks")
