@@ -52,12 +52,24 @@ MAX_KERNEL_DEPTH = 64
 # stops itself at the hop that closes its last open lane, so a short
 # path pays for its own hops only. Three rules close a lane (ops/bfs.py
 # make_ell_step; the host's scan applies the same): at the seed, before
-# any hop, when the target has no in-edge or the source is one of its
-# in-neighbours; ahead, at the hop whose fresh mask reaches an
-# in-neighbour of the target (numpaths = 1), so the hop that would show
-# the target itself is never run; exhausted, when the fresh mask holds
-# nothing of the lane. Mask carries are DONATED between stages.
+# any hop, when the target has no in-edge or the source is one or two
+# edges before it; ahead, at the hop whose fresh mask reaches an
+# in-neighbour of the target or an in-neighbour of one (numpaths = 1),
+# so the one or two hops that would show the target itself are never
+# run; exhausted, when the fresh mask holds nothing of the lane. Mask
+# carries are DONATED between stages.
 SHORTEST_STAGE = 8
+# the look-ahead's second level is read for a lane only if the in-edges
+# of its target's in-neighbours number at most this many: a hub target's
+# second level is most of the graph, and a hub is near every source, so
+# its lane does not set a launch's length. Chosen from counts of the
+# follower cell's graph and pairs (numpy, PERF.md section 6, PR 41) so
+# that no lane whose pair is 5 or more edges apart is left out: over 16
+# requests of 64 pairs the 659 such lanes read 34,693 in-edges at most
+# (median 1,521; the 108 six or more apart 5,824), over 64 more the
+# 2,752 read 54,997; the 17 lanes of all 5,120 over the constant
+# (70,393 to 377,837 in-edges) were 2 to 4 edges apart.
+NEAR2_MAX_EDGES = 65536
 
 
 class _BatchPlan:
@@ -623,25 +635,43 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
             active = [q for q in range(B)
                       if src[q] >= 0 and dst[q] >= 0 and src[q] != dst[q]]
         # the look-ahead (numpaths = 1): a lane closes at the hop that
-        # reaches an in-neighbour of its target, so a path of d edges
-        # takes d - 1 hops, and the depth cap, which counts edges, one
-        # hop fewer than it says. near_rows[q]: those in-neighbours'
-        # rows. Two kinds of lane are settled here and never opened: a
-        # target nobody leads to, and a source that is itself one of the
-        # in-neighbours (a path of one edge)
+        # reaches a row one or two edges before its target, so a path of
+        # d edges takes d - 2 hops (d - 1 where the second level is left
+        # out), and the depth cap, which counts edges, one hop fewer
+        # than it says (a lane left with one level may need it).
+        # near_old[q]: the target's in-neighbours, near_rows[q] their
+        # rows; near2[q]: THEIR in-neighbours' rows and, beside each,
+        # the in-neighbour it leads to. Settled here and never opened: a
+        # target nobody leads to, and a source that is itself in the
+        # first level (a path of one edge) or in the second (of two:
+        # ahead2[q] = (-1, the node between))
         hop_cap = plan.depth - 1 if plan.first_visit else plan.depth
-        opened, near_rows = active, {}
+        opened, near_old, near_rows, near2, ahead2 = active, {}, {}, {}, {}
         if plan.first_visit:
             with tracing.span("seed.near"):
                 for q in active:
                     preds = rrel.row(int(dst[q]))
                     if len(preds) and not (preds == src[q]).any():
+                        near_old[q] = preds
                         near_rows[q] = g.new_of_old[preds]
             METRICS.inc("kernel_lanes_closed_total",
-                        float(len(active) - len(near_rows)),
+                        float(len(active) - len(near_old)),
                         family="shortest", by="seed")
             # depth: 1 allows no hop at all: the seed settled what it can
-            opened = list(near_rows) if hop_cap else []
+            if hop_cap and near_old:
+                with tracing.span("seed.near2") as sp2:
+                    near2, ahead2, read = _second_level(g, rrel, near_old,
+                                                        src)
+                    sp2.attrs.update(read)
+                METRICS.inc("kernel_lanes_closed_total",
+                            float(len(ahead2)), family="shortest",
+                            by="seed2")
+                METRICS.inc("kernel_near2_edges_total",
+                            float(read["edges"]))
+                METRICS.inc("kernel_near2_capped_total",
+                            float(read["capped"]))
+            opened = [q for q in near_old if q not in ahead2] \
+                if hop_cap else []
         sp.attrs.update(lanes=lanes, active=len(active),
                         opened=len(opened))
         if opened:
@@ -652,7 +682,11 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                     wq, bq = q // 32, np.uint32(1 << (q % 32))
                     mask0[g.new_of_old[int(src[q])], wq] |= bq
                     if plan.first_visit:
+                        # one mask holds both levels: the device closes
+                        # a lane at the first hop that meets either
                         near[near_rows[q], wq] |= bq
+                        if q in near2:
+                            near[near2[q][0], wq] |= bq
             deadline.checkpoint("kernel")
             METRICS.inc("kernel_group_launches_total", family="shortest")
             METRICS.inc("kernel_group_queries_total", float(B),
@@ -728,17 +762,26 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                     # is the hop that closed the last open lane, so
                     # used == ran says the device's exit was exact
                     used = ran
-                    closed = {"ahead": 0, "exhausted": 0}
+                    closed = {"ahead": 0, "ahead2": 0, "exhausted": 0}
                     for h, lvl in enumerate(lvls):
                         levels.append(lvl)
                         alive = np.bitwise_or.reduce(lvl[:n], axis=0)
                         for q in list(unresolved):
                             wq, bq = q // 32, np.uint32(1 << (q % 32))
+                            # the inner level first: a row of both is one
+                            # edge from the target, not two
                             if not (alive[wq] & bq):
                                 by = "exhausted"
                             elif q in near_rows and \
                                     (lvl[near_rows[q], wq] & bq).any():
                                 by = "ahead"    # the walk-back finishes it
+                            elif q in near2 and (hit := np.flatnonzero(
+                                    lvl[near2[q][0], wq] & bq)).size:
+                                # the first row hit leads to the target's
+                                # first in-neighbour two edges on
+                                by = "ahead2"
+                                ahead2[q] = (len(levels) - 1,
+                                             int(near2[q][1][hit[0]]))
                             else:
                                 continue
                             unresolved.pop(q)
@@ -747,7 +790,8 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                             used = h + 1
                             break
                     sp.attrs.update(hops_used=used,
-                                    lanes_ahead=closed["ahead"])
+                                    lanes_ahead=closed["ahead"],
+                                    lanes_ahead2=closed["ahead2"])
                 for by, lanes_closed in closed.items():
                     METRICS.inc("kernel_lanes_closed_total",
                                 float(lanes_closed), family="shortest",
@@ -772,7 +816,8 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
     # make the trace grow with the batch
     with tracing.span("batch.walk_back", phase=True, queries=B):
         datas = [_shortest_path_data(store, plan, g, rrel, levels,
-                                     int(src[q]), int(dst[q]), q)
+                                     int(src[q]), int(dst[q]), q,
+                                     ahead2.get(q))
                  for q in range(B)]
     with tracing.span("batch.render", phase=True, queries=B):
         out = []
@@ -800,6 +845,45 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
     return out
 
 
+def _second_level(g, rrel, near_old: dict, src: np.ndarray):
+    """The look-ahead's second backward level, read from the host's
+    reverse CSR by whole arrays. `near_old[q]` is the in-neighbours of
+    lane q's target, ascending. A lane takes the level if those rows
+    have an in-edge and NEAR2_MAX_EDGES of them at most; its entries are
+    kept in the order read (in-neighbour after in-neighbour, each one's
+    in-edges ascending), so the first entry that sits on a level is the
+    walk-back's own choice: the first parent of the target's first
+    parent. Returns (near2, ahead2, read): near2[q] = (rows, via), the
+    entries' permuted rows and beside each the in-neighbour of the
+    target it leads to, for the lanes to open; ahead2[q] = (-1, via) for
+    a lane whose source is itself an entry (a path of two edges, never
+    opened); read: the `rows` whose in-edges were read, the `edges`
+    read, the lanes `capped`."""
+    from dgraph_tpu.engine.execute import csr_rows
+
+    lanes = np.fromiter(near_old, np.int64)
+    sizes = np.array([len(near_old[q]) for q in near_old])
+    preds = np.concatenate(list(near_old.values()))
+    deg = rrel.indptr[preds + 1] - rrel.indptr[preds]
+    edges = np.add.reduceat(deg, np.cumsum(sizes) - sizes)
+    over = edges > NEAR2_MAX_EDGES
+    take = (edges > 0) & ~over
+    lanes, edges = lanes[take], edges[take]
+    preds = preds[np.repeat(take, sizes)]
+    nbrs, seg, _pos = csr_rows(rrel, preds)
+    rows, via = g.new_of_old[nbrs], preds[seg]
+    cuts = np.concatenate(([0], np.cumsum(edges))).tolist()
+    near2, ahead2 = {}, {}
+    for q, lo, hi in zip(lanes.tolist(), cuts, cuts[1:]):
+        at_src = np.flatnonzero(nbrs[lo:hi] == src[q])
+        if at_src.size:
+            ahead2[q] = (-1, int(via[lo + at_src[0]]))
+        else:
+            near2[q] = (rows[lo:hi], via[lo:hi])
+    return near2, ahead2, {"rows": len(preds), "edges": int(edges.sum()),
+                           "capped": int(over.sum())}
+
+
 def _lane_mask(lanes, W: int) -> np.ndarray:
     """Packed uint32[W] mask with the bit of every lane in `lanes`."""
     q = np.fromiter(lanes, np.int64)
@@ -817,9 +901,11 @@ def _level_member(g, levels, lvl: int, ranks: np.ndarray, q: int):
 
 
 def _shortest_path_data(store, plan, g, rrel, levels, src: int,
-                        dst: int, q: int):
+                        dst: int, q: int, ahead2=None):
     """Rebuild one lane's PathData from the kernel levels — the exact
-    paths (and enumeration ORDER) the host loop produces."""
+    paths (and enumeration ORDER) the host loop produces. `ahead2`, for
+    a lane the look-ahead's second level closed: (h, p), the level it
+    closed at (-1: at the seed) and the target's first parent p."""
     from dgraph_tpu.engine.shortest import PathData
 
     blocks = plan.queries[q]
@@ -850,15 +936,24 @@ def _shortest_path_data(store, plan, g, rrel, levels, src: int,
         # the look-ahead's reading of the levels: dst is h + 2 edges
         # from src when one of its in-neighbours sits on level h (-1:
         # src itself), and the level that would show dst was never run.
-        # The depth cap counts edges, so h stops at depth - 2
-        found = next((h for h in range(-1, min(len(levels),
-                                               plan.depth - 1))
-                      if parents_of(dst, h)), None)
+        # The depth cap counts edges, so h stops at depth - 2. A lane
+        # closed on the second level at h is h + 3 edges long: none of
+        # dst's in-neighbours sits on a level up to h, so p, the first
+        # of them that has one there, is dst's first parent on the level
+        # h + 1 that was never run either, and the walk goes on from p
+        rev = [(dst, 0)]
+        cur = dst
+        if ahead2 is not None:
+            found = ahead2[0] if ahead2[0] + 3 <= plan.depth else None
+            cur = ahead2[1]
+            rev.append((cur, 0))
+        else:
+            found = next((h for h in range(-1, min(len(levels),
+                                                   plan.depth - 1))
+                          if parents_of(dst, h)), None)
         if found is not None:
             # walk back choosing each level's FIRST parent — first-visit
             # BFS makes that exactly the host fast path's plist[0]
-            rev = [(dst, 0)]
-            cur = dst
             for lvl in range(found, -2, -1):
                 ps = parents_of(cur, lvl)
                 cur = ps[0]

@@ -93,9 +93,11 @@ def csr_rows(rel, frontier: np.ndarray):
     if total == 0:
         return EMPTY, EMPTY, EMPTY64
     seg = np.repeat(np.arange(len(frontier), dtype=np.int32), deg)
-    base = np.repeat(np.cumsum(deg) - deg, deg)
-    pos = np.repeat(starts.astype(np.int64), deg) + \
-        (np.arange(total, dtype=np.int64) - base)
+    # edge e of row i sits at starts[i] + (e - the edges before row i):
+    # one repeat and one add in place, two arrays of `total` where the
+    # sum spelt out takes five (fresh pages are dear on a serving host)
+    pos = np.repeat(starts.astype(np.int64) - (np.cumsum(deg) - deg), deg)
+    pos += np.arange(total, dtype=np.int64)
     return rel.indices[pos], seg, pos
 
 

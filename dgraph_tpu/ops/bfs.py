@@ -851,16 +851,20 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
       * ahead (`first_visit` only): some row of the fresh mask carries
         the lane's bit in `near` too. `near` [n+1, W] (permuted space,
         sentinel row zero, not donated: every stage reads it) has bit q
-        of row r set iff r is an in-neighbour of lane q's target, so the
-        target is one edge beyond this hop and the hop that would show
-        it is never run: the caller finishes the path from the
-        in-neighbours, which it has to read for the walk back anyway;
+        of row r set iff r is one or two edges before lane q's target
+        (an in-neighbour of it or, where the caller read the second
+        level for the lane, an in-neighbour of one), so the target is
+        one or two edges beyond this hop and the hops that would show
+        it and its parent are never run: the caller finishes the path
+        from the in-neighbours, which it has to read for the walk back
+        anyway, and tells by the row that was hit which level closed
+        the lane;
       * at the seed, by the caller: a lane whose source is itself such
-        an in-neighbour, or whose target has none, is never opened.
-    With `near` whole, a target cannot show in a fresh mask while its
-    lane is open (an in-neighbour would have carried the bit a hop
-    sooner), so the program tests no target row. The rules are those of
-    engine/batch.py's host scan, which stays the authority on which
+        a row, or whose target has no in-neighbour, is never opened.
+    With the first level whole, a target cannot show in a fresh mask
+    while its lane is open (an in-neighbour would have carried the bit a
+    hop sooner), so the program tests no target row. The rules are those
+    of engine/batch.py's host scan, which stays the authority on which
     lane closed where. `hops` is a tuple of `levels` masks [n+1, W]
     (separate arrays, so a caller copies back only what was run):
     hops[h] is the fresh mask of this call's hop h+1 for h < `ran`, and

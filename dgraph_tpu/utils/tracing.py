@@ -523,6 +523,13 @@ def arm() -> None:
     for thread in BACKGROUND_THREADS:
         METRICS.inc("background_cpu_us_total", 0.0, thread=thread)
         METRICS.inc("background_ticks_total", 0.0, thread=thread)
+    # the shortest launch's look-ahead (engine/batch.py): a window in
+    # which no lane closed under a rule reads 0 for it
+    for by in ("seed", "seed2", "ahead", "ahead2", "exhausted"):
+        METRICS.inc("kernel_lanes_closed_total", 0.0, family="shortest",
+                    by=by)
+    METRICS.inc("kernel_near2_edges_total", 0.0)
+    METRICS.inc("kernel_near2_capped_total", 0.0)
 
 
 def add_sink(fn) -> None:

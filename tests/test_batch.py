@@ -356,65 +356,136 @@ def test_fold_carries_ell_cache(alpha):
 def chain():
     """p0 -> p1 -> ... -> p13 with a shortcut p0 -> p2: paths longer
     than one SHORTEST_STAGE, two routes to every node past p1, and no
-    way back."""
+    way back. Apart from it (p14 on), a ring c0 -> c1 -> ... -> c5 -> c0
+    with a tail c3 -> t0 <-> t1: every ring node lies on a cycle through
+    every other, and t1 is its own second level."""
     a = Alpha(device_threshold=10**9)
     a.alter("name: string @index(exact) .\nfollows: [uid] @reverse .")
-    lines = [f'_:p{i} <name> "p{i}" .' for i in range(14)]
+    lines = [f'_:p{i} <name> "p{i}" .' for i in range(22)]
     lines += [f"_:p{i} <follows> _:p{i + 1} ." for i in range(13)]
     lines.append("_:p0 <follows> _:p2 .")
+    lines += [f"_:p{14 + i} <follows> _:p{14 + (i + 1) % 6} ."
+              for i in range(6)]
+    lines += ["_:p17 <follows> _:p20 .", "_:p20 <follows> _:p21 .",
+              "_:p21 <follows> _:p20 ."]
     uids = a.mutate(set_nquads="\n".join(lines))["uids"]
-    return a, [uids[f"_:p{i}"] for i in range(14)]
+    return a, [uids[f"_:p{i}"] for i in range(22)]
 
+
+# pairs of the chain 1 to 6 edges apart (p0 -> p5 takes the shortcut)
+BY_LENGTH = [(0, 1), (3, 5), (1, 4), (0, 5), (1, 6), (1, 7)]
+C, T = 14, 20       # the ring's c0 and the tail's t0
 
 # (pairs, shortest's extra arguments, hops of each launch, lanes closed
 # by each rule): a launch stops at the hop that closes its last open
-# lane, and a numpaths = 1 lane closes a hop AHEAD of its target, at the
-# hop that reaches one of the target's in-neighbours: a path of d edges
-# takes d - 1 hops
+# lane, and a numpaths = 1 lane closes TWO hops ahead of its target, at
+# the hop that reaches an in-neighbour of one of the target's
+# in-neighbours: a path of d edges takes d - 2 hops, and d - 1 where the
+# lane was left with the first level alone
 STOPPING_GROUPS = {
-    # p0 -> p12 is 11 edges (p0 -> p2, then ten on): p11 shows at hop
-    # 10, a full stage and 2 more. p1 -> p4 closes at hop 2, p3 -> p5 at
-    # hop 1, p2 -> p9 at hop 6
-    "two-launches": ([(0, 12), (1, 4), (3, 5), (2, 9)], "", [8, 2],
-                     {"ahead": 4}),
+    # p0 -> p12 is 11 edges (p0 -> p2, then ten on): p10 shows at hop
+    # 9, a full stage and 1 more. p1 -> p4 closes at hop 1, p2 -> p9 at
+    # hop 5; p3 -> p5 is two edges, settled before the launch
+    "two-launches": ([(0, 12), (1, 4), (3, 5), (2, 9)], "", [8, 1],
+                     {"seed2": 1, "ahead2": 3}),
     # nothing leads to p0, so the searches from p13 and from p9 are
-    # settled before the launch; p0 -> p5 is 4 edges, closed at hop 3
-    # (p4 shows), p4 -> p6 at hop 1
-    "unreachable": ([(13, 0), (9, 0), (0, 5), (4, 6)], "", [3],
-                    {"seed": 2, "ahead": 2}),
+    # settled before the launch, as is p4 -> p6 (two edges); p0 -> p5 is
+    # 4 edges, closed at hop 2 (p3 shows)
+    "unreachable": ([(13, 0), (9, 0), (0, 5), (4, 6)], "", [2],
+                    {"seed": 2, "seed2": 1, "ahead2": 1}),
     # the level-DAG closes a lane only when nothing is left to expand:
     # from p0 every node is passed by hop 13, hop 14 is empty
     "numpaths-2": ([(0, 3), (1, 4), (0, 12), (5, 6)], ", numpaths: 2",
                    [8, 6], {"exhausted": 4}),
-    # p0 -> p1, p0 -> p2 and p5 -> p6 are edges: settled before the
-    # launch, which p3 -> p5 alone opens and closes at hop 1
-    "one-edge": ([(0, 1), (0, 2), (3, 5), (5, 6)], "", [1],
-                 {"seed": 3, "ahead": 1}),
+    # p0 -> p1, p0 -> p2 and p5 -> p6 are edges, p3 -> p5 is two:
+    # settled before a launch that is never made
+    "one-edge": ([(0, 1), (0, 2), (3, 5), (5, 6)], "", [],
+                 {"seed": 3, "seed2": 1}),
     # every target is p0, which nothing leads to: no lane is opened and
     # no launch made, as for a batch whose every source is its target
     "no-in-edge": ([(13, 0), (9, 0), (5, 0), (1, 0)], "", [], {"seed": 4}),
-    # the cap counts edges: p0 -> p5 and p1 -> p5 are 4 (p4 shows at
-    # hop 3, the last the cap allows), p0 -> p6 and p1 -> p6 are 5 and
-    # stay open: hop 4 would show p5 and is not run
+    # the cap counts edges: p0 -> p5 and p1 -> p5 are 4 (p3 shows at
+    # hop 2), p0 -> p6 and p1 -> p6 are 5: they close at hop 3, the last
+    # the cap allows, on a path of one edge too many, and answer nothing
     "depth-cap": ([(0, 5), (0, 6), (1, 5), (1, 6)], ", depth: 4", [3],
-                  {"ahead": 2}),
+                  {"ahead2": 4}),
     # a cap of one edge allows no hop: the three pairs one edge apart are
     # settled before the launch, p3 -> p5 (2 edges) is cut by the cap,
     # and no launch is made
     "depth-one": ([(0, 1), (0, 2), (3, 5), (5, 6)], ", depth: 1", [],
                   {"seed": 3}),
+    # one launch of every length: the pairs one and two edges apart are
+    # settled before it, the others close at hops 1, 2, 3 and 4
+    "lengths": (BY_LENGTH, "", [4], {"seed": 1, "seed2": 1, "ahead2": 4}),
+    # the same pairs under every cap: depth - 1 hops at most, and a lane
+    # that closes on a path of depth + 1 edges answers nothing
+    "lengths-depth-1": (BY_LENGTH, ", depth: 1", [], {"seed": 1}),
+    "lengths-depth-2": (BY_LENGTH, ", depth: 2", [1],
+                        {"seed": 1, "seed2": 1, "ahead2": 1}),
+    "lengths-depth-3": (BY_LENGTH, ", depth: 3", [2],
+                        {"seed": 1, "seed2": 1, "ahead2": 2}),
+    "lengths-depth-4": (BY_LENGTH, ", depth: 4", [3],
+                        {"seed": 1, "seed2": 1, "ahead2": 3}),
+    "lengths-depth-5": (BY_LENGTH, ", depth: 5", [4],
+                        {"seed": 1, "seed2": 1, "ahead2": 4}),
+    # NEAR2_MAX_EDGES patched to 1: p2, the one in-neighbour of p3, has
+    # two in-edges, so the lanes to p3 keep the first level alone and
+    # close a hop later than whole ones would (p1 -> p3, two edges, at
+    # hop 1 where it would be settled before the launch; p0 -> p3 too);
+    # p4 -> p6 and p1 -> p4 read one in-edge and are whole
+    "capped": ([(1, 3), (0, 3), (4, 6), (1, 4)], "", [1],
+               {"seed2": 1, "ahead": 2, "ahead2": 1}),
+    # p12 reaches p13 and nothing more, p13 nothing: both searches for
+    # p5 die out, at hops 2 and 1
+    "exhausted": ([(12, 5), (13, 5), (1, 4), (3, 5)], "", [2],
+                  {"seed2": 1, "ahead2": 1, "exhausted": 2}),
+    # p0, the one in-neighbour of p1, has no in-edge: the lanes to p1
+    # have no second level and run as with one, until they die out
+    "no-second-level": ([(12, 1), (13, 1), (11, 1), (0, 1)], "", [3],
+                        {"seed": 1, "exhausted": 3}),
+    # around the ring: c1 -> c0 is 5 edges and c0 -> c5 too (hop 3);
+    # c4 -> c0 is two, through c5; c0 -> t1 is 5 (c3 shows at hop 3),
+    # and t1, which t0 leads back to, sits in its own second level
+    "cycle": ([(C + 1, C), (C, C + 5), (C + 4, C), (C, T + 1)], "", [3],
+              {"seed2": 1, "ahead2": 3}),
+    # a source that its own target leads back to: t0 -> t1 and t1 -> t0
+    # are edges; c3 -> t1 is two; c2 -> t1 three
+    "cycle-of-two": ([(T, T + 1), (T + 1, T), (C + 3, T + 1),
+                      (C + 2, T + 1)], "", [1],
+                     {"seed": 2, "seed2": 1, "ahead2": 1}),
 }
+NEAR2_CAPS = {"capped": 1}
+FOUND = {      # nodes on each answer's path, where the case has a gap
+    "unreachable": [0, 0, 5, 3],
+    "one-edge": [2, 2, 3, 2],
+    "no-in-edge": [0, 0, 0, 0],
+    "depth-cap": [5, 0, 5, 0],
+    "depth-one": [2, 2, 0, 2],
+    "lengths": [2, 3, 4, 5, 6, 7],
+    **{f"lengths-depth-{k}": [d + 1 if d <= k else 0 for d in range(1, 7)]
+       for k in range(1, 6)},
+    "capped": [3, 3, 3, 4],
+    "exhausted": [0, 0, 4, 3],
+    "no-second-level": [0, 0, 0, 2],
+    "cycle": [6, 6, 3, 6],
+    "cycle-of-two": [2, 2, 3, 4],
+}
+CLOSED_BY = ("seed", "seed2", "ahead", "ahead2", "exhausted")
 
 
 @pytest.mark.parametrize("case", sorted(STOPPING_GROUPS))
-def test_shortest_batch_stops_with_its_last_lane(chain, case):
+def test_shortest_batch_stops_with_its_last_lane(chain, case, monkeypatch):
     """Answers are byte-equal to the per-query engine's, each launch runs
-    the hops some open lane needs and none after, and every lane that
+    the hops some open lane needs and none after (the host's scan reads
+    the device's exit: hops used are hops run), and every lane that
     closes is counted once, under the rule that closed it."""
+    from dgraph_tpu.engine import batch
     from dgraph_tpu.utils.metrics import METRICS
 
     alpha, u = chain
     pairs, extra, launches, closed = STOPPING_GROUPS[case]
+    if case in NEAR2_CAPS:
+        monkeypatch.setattr(batch, "NEAR2_MAX_EDGES", NEAR2_CAPS[case])
     qs = ['{ path as shortest(from: %s, to: %s%s) { follows } '
           'p(func: uid(path)) { name } }' % (u[i], u[j], extra)
           for i, j in pairs]
@@ -425,46 +496,41 @@ def test_shortest_batch_stops_with_its_last_lane(chain, case):
                 METRICS.get("jit_cache_hits_total", kernel="bfs.ell_step")
                 + METRICS.get("jit_compile_total", kernel="bfs.ell_step"),
                 METRICS.get("kernel_group_launches_total",
-                            family="shortest"))
+                            family="shortest"),
+                METRICS.get("kernel_near2_capped_total"))
 
     def lanes_closed():
         return {by: METRICS.get("kernel_lanes_closed_total",
                                 family="shortest", by=by)
-                for by in ("seed", "ahead", "exhausted")}
+                for by in CLOSED_BY}
 
     before, closed0 = hops(), lanes_closed()
     got = alpha.query_batch(qs)
     after, closed1 = hops(), lanes_closed()
     assert tuple(b - a for a, b in zip(before, after)) == \
-        (sum(launches), sum(launches), len(launches), bool(launches))
-    assert {by: closed1[by] - closed0[by] for by in closed0} == \
-        {"seed": 0, "ahead": 0, "exhausted": 0, **closed}
+        (sum(launches), sum(launches), len(launches), bool(launches),
+         2 * (case in NEAR2_CAPS))
+    assert {by: closed1[by] - closed0[by] for by in CLOSED_BY} == \
+        {**dict.fromkeys(CLOSED_BY, 0), **closed}
     eng = Engine(alpha.mvcc.read_view(alpha.oracle.read_only_ts()),
                  device_threshold=10**9)
     want = [eng.query(q) for q in qs]
     assert json.dumps(got) == json.dumps(want)
-    found = [len(o.get("p", [])) for o in got]
-    if case == "unreachable":
-        assert found == [0, 0, 5, 3]
-    elif case == "one-edge":
-        assert found == [2, 2, 3, 2]
-    elif case == "no-in-edge":
-        assert found == [0, 0, 0, 0]
-    elif case == "depth-cap":
-        assert found == [5, 0, 5, 0]
-    elif case == "depth-one":
-        assert found == [2, 2, 0, 2]
+    if case in FOUND:
+        assert [len(o.get("p", [])) for o in got] == FOUND[case]
 
 
-def _hops_pushed(store, attr, pairs, levels):
-    """The plain count behind `kernel_hops_push_total` for one launch of
-    first-visit lanes: a search a lane from each pair's source, but for
-    the pairs settled before the launch (a target with no in-edge, or
-    one edge from its source); a lane closes at the hop that reaches an
-    in-neighbour of its target, or nothing new; a hop pushes when the
-    rows that some lane reached last hop and that have an out-edge, the
-    sum of their out-degrees and the largest of them fit ops/bfs.py
-    push_caps. Returns (hops run, hops pushed)."""
+def _plain_launch(store, attr, pairs, levels, near2_max):
+    """The plain count behind a launch of first-visit lanes and its
+    counters: a search a lane from each pair's source, but for the pairs
+    settled before the launch (a target with no in-edge, or one or two
+    edges from its source); a lane closes at the hop that reaches an
+    in-neighbour of its target, or an in-neighbour of one where those
+    rows have `near2_max` in-edges at most, or nothing new; a hop pushes
+    when the rows that some lane reached last hop and that have an
+    out-edge, the sum of their out-degrees and the largest of them fit
+    ops/bfs.py push_caps. Returns (hops run, hops pushed, lanes closed
+    by each rule)."""
     from dgraph_tpu.ops.bfs import push_caps
 
     rel, rrel = store.rel(attr, False), store.rel(attr, True)
@@ -472,9 +538,18 @@ def _hops_pushed(store, attr, pairs, levels):
     f_cap, e_cap, chunk = push_caps(len(rel.indices))
     src = store.rank_of(np.asarray([a for a, _ in pairs], np.int64))
     dst = store.rank_of(np.asarray([b for _, b in pairs], np.int64))
-    near = [set(rrel.row(int(d)).tolist()) for d in dst]
-    open_ = {q for q in range(len(pairs))
-             if near[q] and int(src[q]) not in near[q]}
+    closed = dict.fromkeys(CLOSED_BY, 0)
+    near, near2, open_ = {}, {}, set()
+    for q, (s, d) in enumerate(zip(src.tolist(), dst.tolist())):
+        near[q] = set(rrel.row(d).tolist())
+        far = [v for u in sorted(near[q]) for v in rrel.row(u).tolist()]
+        near2[q] = set(far) if len(far) <= near2_max else set()
+        if not near[q] or s in near[q]:
+            closed["seed"] += 1
+        elif s in near2[q]:
+            closed["seed2"] += 1
+        else:
+            open_.add(q)
     fresh = [{int(s)} if q in open_ else set() for q, s in enumerate(src)]
     seen = [set(f) for f in fresh]
     ran = pushed = 0
@@ -488,9 +563,12 @@ def _hops_pushed(store, attr, pairs, levels):
             nxt = {int(v) for u in fresh[q] for v in rel.row(u)} - seen[q]
             fresh[q] = nxt
             seen[q] |= nxt
-            if not nxt or nxt & near[q]:
+            by = "exhausted" if not nxt else "ahead" if nxt & near[q] \
+                else "ahead2" if nxt & near2[q] else None
+            if by and q in open_:
                 open_.discard(q)
-    return ran, pushed
+                closed[by] += 1
+    return ran, pushed, closed
 
 
 def test_shortest_batch_mixes_pushed_and_pulled_hops():
@@ -499,7 +577,8 @@ def test_shortest_batch_mixes_pushed_and_pulled_hops():
     per-query path answers; the push counter moves by the hops pushed and
     stays under the hops run; and only the step program brings the
     out-CSR to the device: a @recurse batch on the same store does not."""
-    from dgraph_tpu.engine.batch import SHORTEST_STAGE, _cache_host
+    from dgraph_tpu.engine.batch import NEAR2_MAX_EDGES, SHORTEST_STAGE, \
+        _cache_host
     from dgraph_tpu.utils.metrics import METRICS
 
     rng = np.random.default_rng(11)
@@ -531,11 +610,68 @@ def test_shortest_batch_mixes_pushed_and_pulled_hops():
     assert dev.out is not None
     eng = Engine(store, device_threshold=10**9)
     assert json.dumps(got) == json.dumps([eng.query(q) for q in qs])
-    want_run, want_push = _hops_pushed(
+    want_run, want_push, _closed = _plain_launch(
         store, "follows", [(int(x, 16), int(y, 16)) for x, y in pairs],
-        SHORTEST_STAGE)
+        SHORTEST_STAGE, NEAR2_MAX_EDGES)
     assert (run1 - run0, push1 - push0) == (want_run, want_push)
     assert 0 < want_push < want_run, "the batch must mix both kinds of hop"
+
+
+@pytest.mark.parametrize("near2_max", [None, 12, 0])
+def test_two_level_look_ahead_is_the_plain_scan(alpha, monkeypatch,
+                                                near2_max):
+    """64 lanes over a random graph full of cycles, in one launch: the
+    answers (paths and their order) are the per-query engine's, and the
+    hops run, the hops pushed and the lanes closed under each rule are
+    the plain search's, whether every lane takes the second level (the
+    constant as it stands), some do (12 in-edges: about a third) or
+    none (0: the launch the first level alone gives)."""
+    from dgraph_tpu.engine import batch
+    from dgraph_tpu.utils.metrics import METRICS
+
+    if near2_max is None:
+        near2_max = batch.NEAR2_MAX_EDGES
+    else:
+        monkeypatch.setattr(batch, "NEAR2_MAX_EDGES", near2_max)
+    rng = np.random.default_rng(41)
+    store = alpha.mvcc.read_view(alpha.oracle.read_only_ts())
+    uid_of = {}
+    for i in set(rng.integers(0, 400, 128).tolist()):
+        uid_of[i] = _uid_of(alpha, f"p{i}")
+    names = sorted(uid_of)
+    pairs = [(uid_of[names[i]], uid_of[names[j]])
+             for i, j in rng.integers(0, len(names), (64, 2)) if i != j]
+    qs = ['{ path as shortest(from: %s, to: %s) { follows } '
+          'p(func: uid(path)) { name } }' % p for p in pairs]
+
+    def counters():
+        return ([METRICS.get(f"kernel_hops_{k}_total", family="shortest")
+                 for k in ("run", "used", "push")]
+                + [METRICS.get("kernel_lanes_closed_total",
+                               family="shortest", by=by)
+                   for by in CLOSED_BY]
+                + [METRICS.get("kernel_near2_capped_total")])
+
+    before = counters()
+    got = alpha.query_batch(qs)
+    run, used, push, *closed = (b - a for a, b in zip(before, counters()))
+    eng = Engine(store, device_threshold=10**9)
+    assert json.dumps(got) == json.dumps([eng.query(q) for q in qs])
+    want_run, want_push, want_closed = _plain_launch(
+        store, "follows", [(int(x, 16), int(y, 16)) for x, y in pairs],
+        batch.SHORTEST_STAGE, near2_max)
+    assert (run, used, push) == (want_run, want_run, want_push)
+    assert dict(zip(CLOSED_BY, closed)) == want_closed
+    capped = closed[-1]
+    if near2_max == 0:
+        assert not want_closed["seed2"] and not want_closed["ahead2"]
+        assert capped == len(pairs) - want_closed["seed"]
+    elif near2_max == 12:
+        assert 0 < capped < len(pairs) - want_closed["seed"]
+        assert want_closed["ahead"] and want_closed["ahead2"]
+    else:
+        assert not capped and not want_closed["ahead"]
+        assert want_closed["ahead2"] > len(pairs) // 2
 
 
 # -- the dense hub block under the batch routes (ops/bfs.py _choose_dense) ---

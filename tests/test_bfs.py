@@ -384,13 +384,18 @@ def _step_case(lanes, first_visit, acyclic, caps="mixed"):
               and srcs[q] not in rrel.row(dsts[q])}
     mask0 = np.zeros((n + 1, W), np.uint32)
     near = np.zeros((n + 1, W), np.uint32)
-    dst_rows, near_rows = {}, {}
+    dst_rows, near_rows, near2_rows = {}, {}, {}
     for q in active:
         wq, bq = q // 32, np.uint32(1 << (q % 32))
         mask0[g.new_of_old[srcs[q]], wq] |= bq
         dst_rows[q] = int(g.new_of_old[dsts[q]])
         near_rows[q] = g.new_of_old[rrel.row(dsts[q])]
         near[near_rows[q], wq] |= bq
+        # both levels of engine/batch.py's look-ahead: the target's
+        # in-neighbours and theirs
+        near2_rows[q] = np.concatenate(
+            [near_rows[q]] + [g.new_of_old[rrel.row(int(p))]
+                              for p in rrel.row(dsts[q])])
     assert not len(near_rows[0]) and not near[n].any()
 
     want_f, want_s = _scan_levels(prepare_parts(dev, W),
@@ -400,7 +405,8 @@ def _step_case(lanes, first_visit, acyclic, caps="mixed"):
                          caps=STEP_CAPS[caps])
     return types.SimpleNamespace(
         n=n, W=W, mask0=mask0, active=active, dst_rows=dst_rows,
-        near=near, near_rows=near_rows, step=step, want_f=want_f,
+        near=near, near_rows=near_rows, near2_rows=near2_rows, step=step,
+        want_f=want_f,
         want_s=want_s, dev=dev)
 
 
@@ -461,16 +467,20 @@ def test_step_stops_where_the_host_rule_closes_the_last_lane(
         assert 0 < pushed_all < done, "the case must mix both kinds of hop"
 
 
-@pytest.mark.parametrize("near", ["given", "none", "level-dag"])
+@pytest.mark.parametrize("near", ["given", "two-levels", "none",
+                                  "level-dag"])
 @pytest.mark.parametrize("lanes", [32, 64, 128])
 @pytest.mark.parametrize("caps", list(STEP_CAPS))
 def test_the_look_ahead_spares_a_launch_its_last_hop(caps, lanes, near):
     """A launch whose every open lane finds its target runs one hop fewer
     than the rule that waited for the target's own row: the lanes close
     at the level that reaches the target's in-neighbours, and the levels
-    up to there are the plain scan's, pushed or pulled. A lane with no
-    row in `near` closes when it dies out, as does every lane of the
-    level-DAG program, which makes no use of the argument."""
+    up to there are the plain scan's, pushed or pulled. Given the rows
+    two edges before the target in the same mask, the same program runs
+    two hops fewer (one hop at least: here a lane two edges long is
+    opened, which engine/batch.py settles before its launch). A lane
+    with no row in `near` closes when it dies out, as does every lane of
+    the level-DAG program, which makes no use of the argument."""
     import jax
 
     first_visit = near != "level-dag"
@@ -498,6 +508,15 @@ def test_the_look_ahead_spares_a_launch_its_last_hop(caps, lanes, near):
         assert 1 <= ahead == at_target - 1 < dies_out
         ran, open_after = run(c.near)
         assert ran == ahead and not open_after.any()
+    elif near == "two-levels":
+        at_target = _target_rule(levels, n, c.dst_rows, set(finders))
+        ahead2 = _host_rule(levels, n, c.near2_rows, set(finders), True)
+        assert 1 <= ahead2 == max(at_target - 2, 1) < dies_out
+        both = c.near.copy()
+        for q, rows in c.near2_rows.items():
+            both[rows, q // 32] |= np.uint32(1 << (q % 32))
+        ran, open_after = run(both)
+        assert ran == ahead2 and not open_after.any()
     else:
         ran, open_after = run(np.zeros_like(c.mask0) if first_visit
                               else None)

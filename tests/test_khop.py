@@ -406,14 +406,17 @@ def test_shortest_group_reuses_the_tree_groups_out_csr(out_csr_spans):
     dev = _link_dev(store)
     out = dev.out
     assert out is not None
-    # targets two edges away, so that every lane is opened
+    # targets three edges away and no nearer, so that every lane is
+    # opened (a pair one or two edges apart is settled before a launch)
     rs, dst = data["row_start"], data["dst"]
     pairs = []
     for i in nodes:
-        mid = int(dst[rs[i]])
-        far = [int(d) for m in dst[rs[i]:rs[i] + rl[i]]
-               for d in dst[rs[m]:rs[m] + rl[m]]]
-        pairs.append((int(i), far[0] if far else mid))
+        seen, far = {int(i)}, [int(i)]
+        for _ in range(3):
+            far = sorted({int(d) for m in far
+                          for d in dst[rs[m]:rs[m] + rl[m]]} - seen)
+            seen.update(far)
+        pairs.append((int(i), far[0] if far else int(dst[rs[i]])))
     qs = ['{ path as shortest(from: %s, to: %s) { link } '
           'p(func: uid(path)) { uid } }' % (hex(a + 1), hex(b + 1))
           for a, b in pairs]

@@ -372,7 +372,7 @@ def test_query_batch_opens_each_phase_once_and_they_cover_the_request():
             # the phases are a constant number of spans a request; one a
             # query is only `engine.block`, the companion block's own
             assert len(by_name["engine.block"]) == 64
-            assert len(spans) - 64 < 24
+            assert len(spans) - 64 < 25
             kernel = by_name["batch.shortest_kernel"][0]
             for p in ("batch.device_wait", "batch.fetch", "batch.scan"):
                 assert by_name[p][0].parent_id == kernel.span_id
@@ -399,7 +399,7 @@ def test_hops_used_is_the_longest_found_path_of_the_launch():
     from dgraph_tpu.engine.batch import SHORTEST_STAGE
     from dgraph_tpu.utils.metrics import METRICS
 
-    alpha, u = _chain_alpha(11)
+    alpha, u = _chain_alpha(12)
 
     def hops():
         return (METRICS.get("kernel_hops_run_total", family="shortest"),
@@ -414,14 +414,14 @@ def test_hops_used_is_the_longest_found_path_of_the_launch():
     assert lengths == [5, 2, 1, 1]
     run1, used1 = hops()
     # the launch stops itself at the hop that closes its last lane, the
-    # one that reaches an in-neighbour of the farthest target, a hop
-    # short of the path's edges: the device's count of hops run is the
-    # host's count of hops used
-    assert (run1 - run0, used1 - used0) == (max(lengths) - 1,
-                                            max(lengths) - 1)
-    # a lane still open at the stage's end uses the whole stage: p0 -> p10
-    # is 9 edges, p9 shows at the stage's last hop
-    alpha.query_batch([_shortest(u[0], u[10])] +
+    # one that reaches a row two edges before the farthest target, two
+    # hops short of the path's edges: the device's count of hops run is
+    # the host's count of hops used
+    assert (run1 - run0, used1 - used0) == (max(lengths) - 2,
+                                            max(lengths) - 2)
+    # a lane still open at the stage's end uses the whole stage: p0 -> p11
+    # is 10 edges, p9 shows at the stage's last hop
+    alpha.query_batch([_shortest(u[0], u[11])] +
                       [_shortest(u[i], u[i + 1]) for i in range(3)])
     run2, used2 = hops()
     assert (run2 - run1, used2 - used1) == (SHORTEST_STAGE, SHORTEST_STAGE)
@@ -865,7 +865,7 @@ def test_lane_recurse_route_opens_the_routes_phases_once_a_request():
         assert len(by_name["batch.plan"]) == 1
 
 
-def test_shortest_seed_names_its_four_parts():
+def test_shortest_seed_names_its_five_parts():
     alpha, u = _chain_alpha(11)
     with tracing.trace("http.query_batch", endpoint="query_batch") as tid:
         alpha.query_batch([_shortest(u[0], u[6]), _shortest(u[1], u[5]),
@@ -874,9 +874,14 @@ def test_shortest_seed_names_its_four_parts():
     seed = next(s for s in spans if s.name == "batch.seed")
     parts = [s for s in spans if s.name.startswith("seed.")]
     assert [s.name for s in parts] == ["seed.ranks", "seed.near",
-                                      "seed.masks", "seed.upload"]
+                                      "seed.near2", "seed.masks",
+                                      "seed.upload"]
     assert all(s.parent_id == seed.span_id for s in parts)
     assert sum(s.dur_us for s in parts) <= seed.dur_us
     # children, not phases: no clock read, no series
     assert all(s.cpu_us == s.sys_us == 0 for s in parts)
     assert not _phase_series("seed.masks")
+    # the chain's second levels: one row a lane, one in-edge each but
+    # p2's two (the shortcut), which is the last target's in-neighbour
+    near2 = parts[2].attrs
+    assert (near2["rows"], near2["edges"], near2["capped"]) == (4, 5, 0)
