@@ -728,13 +728,15 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                         with jit_call("bfs.ell_step",
                                       (plan.attr, plan.reverse, W,
                                        plan.first_visit, n)):
-                            frontier, seen, hops, ran, _open, pushed = step(
+                            (frontier, seen, hops, ran, _open, pushed,
+                             slots) = step(
                                 frontier, seen, near,
                                 _lane_mask(unresolved, W), np.int32(chunk))
                         # the dispatch returns at once: the span ends
-                        # when the device says how many hops it ran,
-                        # and how many of them pushed
-                        ran, pushed = map(int, jax.device_get((ran, pushed)))
+                        # when the device says how many hops it ran, how
+                        # many of them pushed and over how many slots
+                        ran, pushed, slots = map(int, jax.device_get(
+                            (ran, pushed, slots)))
                     except Exception as e:
                         if not memgov.is_alloc_failure(e):
                             raise
@@ -748,7 +750,8 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                         memgov.GOVERNOR.degrade("bfs.ell_step", skey)
                         raise memgov.OomDegraded("bfs.ell_step",
                                                  str(skey)) from e
-                    sp.attrs.update(hops_run=ran, hops_push=pushed)
+                    sp.attrs.update(hops_run=ran, hops_push=pushed,
+                                    push_slots=slots)
                 with tracing.span("batch.fetch", phase=True) as sp:
                     # only the levels the device ran are data
                     for lvl in hops[:ran]:
@@ -801,6 +804,8 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                 METRICS.inc("kernel_hops_used_total", float(used),
                             family="shortest")
                 METRICS.inc("kernel_hops_push_total", float(pushed),
+                            family="shortest")
+                METRICS.inc("kernel_push_slots_total", float(slots),
                             family="shortest")
                 done += ran
                 pulled += ran - pushed

@@ -520,9 +520,9 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
                 outs = fn(seeds, filts)
             # the dispatch returns at once: the span ends when the host
             # holds what every recurse stage counted (two int32[lanes] a
-            # stage, and how many of its hops pushed), or, where no stage
-            # counts, when the masks are done
-            tallies = jax.device_get([outs[i][1:4] for i in recurse])
+            # stage, how many of its hops pushed and over how many slots),
+            # or, where no stage counts, when the masks are done
+            tallies = jax.device_get([outs[i][1:5] for i in recurse])
             if not recurse:
                 jax.block_until_ready(outs)
         with tracing.span("batch.fetch", phase=True) as sp:
@@ -536,7 +536,7 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
                 if s.kind == "recurse":
                     launch.ells[i] = rels[s.attr, s.reverse]
                     if s.keep_hops:
-                        launch.hops[i] = np.asarray(o[4])
+                        launch.hops[i] = np.asarray(o[5])
                     elif i in listed:
                         launch.seen[i] = np.asarray(o[0])
                 else:
@@ -546,13 +546,14 @@ def run_tree_batch(store, plan: TreePlan, device_threshold: int) -> list:
                 for m in d.values())
     # launch count + dispatch gap are recorded by jit_call itself
     costprofile.add_kernel("tree", execute_us=ksp.dur_us)
-    for i, (count, edges, pushed) in zip(recurse, tallies):
+    for i, (count, edges, pushed, slots) in zip(recurse, tallies):
         launch.counts[i] = count
-        # the device's own count of the stage's hops that pushed over the
-        # frontier's out-edges, of the `depth` it ran
+        # the device's own counts of the stage's hops that pushed over the
+        # frontier's out-edges, of the `depth` it ran, and of those edges
         METRICS.inc("kernel_hops_run_total", float(plan.stages[i].depth),
                     family="tree")
         METRICS.inc("kernel_hops_push_total", float(pushed), family="tree")
+        METRICS.inc("kernel_push_slots_total", float(slots), family="tree")
         note_pulls(launch.ells[i], "tree",
                    plan.stages[i].depth - int(pushed))
         # the north star's traversed edges: a lane's sum fits int32, the

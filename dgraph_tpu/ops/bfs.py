@@ -58,13 +58,13 @@ __all__ = ["EllGraph", "build_ell", "ell_recurse",
 # costs one gather for every stored in-edge whatever the frontier holds.
 # For a frontier of a few rows that is the waste: make_ell_step and the
 # recurse stages of make_ell_tree therefore push, hop by hop, while the
-# frontier's out-edges number under a 128th of the relation's (the two
-# costs cross at a twentieth), and pull otherwise (_pull_or_push, PR 30 and
-# 35); make_ell_recurse and a tree's hop stages pull always. And a pull
-# need not gather every in-edge: the in-edges between the relation's
-# high-out-degree and high-in-degree rows, a quarter to a third of a
-# Kronecker or follower graph's, are a 0/1 matrix product (the dense hub
-# block below, PR 38).
+# frontier's out-edges cost less pushed than a pull of this relation costs
+# (push_caps: about a twentieth of its list slots), and pull otherwise
+# (_pull_or_push, PR 30, 35 and 44); make_ell_recurse and a tree's hop
+# stages pull always. And a pull need not gather every in-edge: the
+# in-edges between the relation's high-out-degree and high-in-degree rows,
+# a quarter to a third of a Kronecker or follower graph's, are a 0/1 matrix
+# product (the dense hub block below, PR 38).
 #
 # Layout (PR 7, FeatGraph-style degree buckets): nodes are RENUMBERED by
 # in-degree class so each class's output is a contiguous slice and the
@@ -112,6 +112,28 @@ DENSE_MAX_BYTES = 2 << 30
 DENSE_MIN_EDGES = 1 << 20
 DENSE_PAD = 128
 
+# What the v5e charges for a hop, each way (PR 44). _pull_or_push takes the
+# cheaper way by these, so they are the chip's readings and not knobs:
+#   * PULL_SLOT_NS, a list slot of a pull (a cell of a degree class's block
+#     or of a tile, padding included), with its share of the second level
+#     and of the mask's rebuild: a pull's device time over the relation's
+#     slots, 5.8 ns on the Kronecker graph (298 ms less the block's product
+#     over 51.8 M slots, PR 38), 6.2 on the follower graph (209.6 ms, 33.8
+#     M), 6.15-6.17 on LDBC's `knows` (431.5-432.9 ms over 70.19 M, PR 37
+#     and 44);
+#   * DENSE_CELL_NS, a cell of the hub block in a pull's one product: 1.8
+#     to 2.3 ps (PR 38, the readings above DENSE_CELLS_PER_EDGE);
+#   * PUSH_SLOT_NS, a slot of a pushed hop (an out-edge of the frontier: a
+#     gather of the source's row, 64 lane bytes, a scatter-max): 114-123
+#     ns where the byte accumulator is 83 MB (the follower graph's 1.3 M
+#     rows, PR 30), the same at 32 K, 64 K and 128 K slots a turn; 85-94
+#     on `knows` (40 MB, 200 out-edges a row: 141.4 ms for 1.61 M slots,
+#     PR 44). One price for every relation, so the dearer reading: a cap
+#     a little under a relation's own break-even never costs a pull.
+PULL_SLOT_NS = 6.1
+DENSE_CELL_NS = 0.002
+PUSH_SLOT_NS = 120.0
+
 
 @dataclass
 class EllGraph:
@@ -154,10 +176,14 @@ class EllGraph:
     def padded_edges(self) -> int:
         """Total level-1 gather slots (real edges + padding) — the device
         edge traffic per hop; `ell_padding_ratio` derives from it."""
-        dense = sum(int(e.size) for kind, e, _ in self.parts
-                    if kind == "ell")
-        return dense + (int(self.tiles.size) if self.tiles is not None
-                        else 0)
+        return _list_slots(self)
+
+
+def _list_slots(rel) -> int:
+    """The level-1 slots a pull of `rel` (an EllGraph or a DeviceEll)
+    gathers: every cell of its degree classes' blocks and of its tiles."""
+    return sum(int(e.size) for kind, e, _ in rel.parts if kind == "ell") \
+        + (int(rel.tiles.size) if rel.tiles is not None else 0)
 
 
 def _degree_ladder(deg, largest: int):
@@ -468,7 +494,8 @@ def prepare_parts(dev: DeviceEll, W: int):
     [rows, K, W] intermediate); under DGRAPH_TPU_PALLAS=1 dense blocks
     and the tile matrix are row-padded for the Pallas DMA-ring hop
     (ops/pallas_hop.py) instead — at the widths the compiled kernel
-    moves (whole 128-word rows); narrower masks keep the XLA hop."""
+    moves (whole 128-word rows); narrower masks keep the XLA hop. `caps`
+    is push_caps of the relation, for the programs that may push a hop."""
     import os
     use_pallas = os.environ.get("DGRAPH_TPU_PALLAS", "") == "1"
     if use_pallas:
@@ -500,7 +527,8 @@ def prepare_parts(dev: DeviceEll, W: int):
         else:
             tiles = ("chain", dev.tiles, dev.tiles.shape[0])
     return {"parts": parts, "tiles": tiles, "lvl2": list(dev.lvl2),
-            "dense": dev.dense, "seg_rows": dev.seg_rows, "n": dev.n}
+            "dense": dev.dense, "seg_rows": dev.seg_rows, "n": dev.n,
+            "caps": push_caps(dev)}
 
 
 # Sticky fail-safe: the first bucket_hop_pallas that fails to trace or
@@ -711,56 +739,74 @@ def make_ell_recurse(dev: DeviceEll, outdeg, n: int, W: int,
     return functools.partial(recurse, held)
 
 
-# The pushed hop of make_ell_step takes a frontier whose rows with an
-# out-edge number at most a PUSH_FANOUT-th of its slot cap, and whose
-# out-edges number at most the cap: the relation's edges over
-# PUSH_EDGE_SHARE, scaled from the edges so that a small graph keeps a pull
-# side. On the v5e a pushed slot costs 114-123 ns and a pulled in-edge 6.2,
-# so the two cross at a twentieth of the edges; the cap stands at a sixth of
-# that, where a push costs under a fifth of a pull (PR 30: at a 32nd the
-# follower cell's third hop, 0.7 to 3.7 M out-edges, pushed in two batches
-# of five, 4 % more queries a second, and a batch's time moved with its
-# pairs: runs spread by 1.3 % where they spread by 0.3). It expands them
-# PUSH_CHUNK slots a turn: XLA:TPU sorts the updates of a scatter of over
-# 2^16 to 2^17, and that sort alone takes longer to compile than the whole
-# pull. A turn takes at most a PUSH_TURN_FANOUT-th as many rows as slots (a
-# row of the follower cell's frontiers has 25 to 50 out-edges).
-PUSH_EDGE_SHARE = 128
+# A hop is pushed when a push is the cheaper way (PR 44): while the
+# frontier's out-edges number at most the slot cap, what a pull of THIS
+# relation costs (its list slots and, where it has one, its hub block's
+# cells, at the prices above) over a pushed slot's price. That is about a
+# twentieth of the list slots, less for a relation whose hub block makes
+# its pull cheaper than its edge count says, and scaled from the relation
+# so that a small graph keeps a pull side. (Until PR 44 the cap stood at a
+# 128th of the edges whatever the relation, a sixth of the break-even: a
+# `knows` launch pulled a hop of 1.2-1.9 M out-edges for 431.5 ms that a
+# push answers in 140-230.) The frontier's rows with an out-edge may number
+# a PUSH_FANOUT-th of the slot cap. A push expands them PUSH_CHUNK slots a
+# turn: XLA:TPU sorts the updates of a scatter of over 2^16 to 2^17, and
+# that sort alone takes longer to compile than the whole pull; so a row of
+# over PUSH_CHUNK out-edges sends its hop to the pull, whatever the caps. A
+# turn takes at most a PUSH_TURN_FANOUT-th as many rows as slots (a row of
+# the follower cell's frontiers has 25 to 50 out-edges).
 PUSH_FANOUT = 32
 PUSH_CHUNK = 1 << 15
 PUSH_TURN_FANOUT = 8
 
 
-def push_caps(edges: int) -> tuple:
-    """(row cap, slot cap, slots a turn) of the pushed hop for a relation
-    of `edges` edges."""
-    e_cap = edges // PUSH_EDGE_SHARE
+def push_caps(rel) -> tuple:
+    """(row cap, slot cap, slots a turn) of the pushed hop over `rel`, an
+    EllGraph or a DeviceEll: the slot cap is the frontier at which a push
+    costs what a pull of `rel` costs."""
+    pull_ns = _list_slots(rel) * PULL_SLOT_NS
+    if rel.dense is not None:
+        pull_ns += int(rel.dense[0].size) * DENSE_CELL_NS
+    e_cap = int(pull_ns / PUSH_SLOT_NS)
     return e_cap // PUSH_FANOUT, e_cap, min(PUSH_CHUNK, max(e_cap, 1))
 
 
 ROWS_BLK = 128        # _set_rows' block: one row gather finds a row's place
 
 
-def _set_rows(act, n: int, cap: int):
+def _set_rows(act, n: int, cap: int, step: int):
     """The first `cap` set positions of act[n], ascending, padded with n:
     jnp.nonzero(act, size=cap, fill_value=n) without its cumsum and
     scatter over all n (12.5 ms on the v5e at 1.3 M rows, and seconds of
     compile). Two levels: a search of the per-block counts finds the
     block of the k-th set row, a prefix sum of that block's flags (a
-    matmul with a triangle: 0/1 inputs, exact) its place inside."""
+    matmul with a triangle: 0/1 inputs, exact) its place inside. `step`
+    positions a turn of a loop, for as many turns as the set rows fill:
+    it costs the rows it finds, not the cap, so a hop of 64 seeds pays
+    for one turn whatever the relation's row cap (PR 44)."""
+    step = min(step, cap)
     nb = -(-n // ROWS_BLK)
     blk = jnp.concatenate(
         [act, jnp.zeros((nb * ROWS_BLK - n,), bool)]).reshape(nb, ROWS_BLK)
     cnt = blk.sum(axis=1, dtype=jnp.int32)
     ends = jnp.cumsum(cnt)
-    k = jnp.arange(cap, dtype=jnp.int32)
-    b = jnp.minimum(jnp.searchsorted(ends, k, side="right"),
-                    nb - 1).astype(jnp.int32)
-    nth = (k - (ends[b] - cnt[b])).astype(jnp.float32)   # within the block
-    upto = jnp.dot(blk[b].astype(jnp.float32),
-                   jnp.tri(ROWS_BLK, dtype=jnp.float32).T)
-    place = (upto <= nth[:, None]).sum(axis=1, dtype=jnp.int32)
-    return jnp.where(k < ends[-1], b * ROWS_BLK + place, n)
+    tri = jnp.tri(ROWS_BLK, dtype=jnp.float32).T
+
+    def some(i, rows):
+        k = i * step + jnp.arange(step, dtype=jnp.int32)
+        b = jnp.minimum(jnp.searchsorted(ends, k, side="right"),
+                        nb - 1).astype(jnp.int32)
+        nth = (k - (ends[b] - cnt[b])).astype(jnp.float32)  # in the block
+        upto = jnp.dot(blk[b].astype(jnp.float32), tri)
+        place = (upto <= nth[:, None]).sum(axis=1, dtype=jnp.int32)
+        return lax.dynamic_update_slice(
+            rows, jnp.where(k < ends[-1], b * ROWS_BLK + place, n),
+            (i * step,))
+
+    rows = lax.fori_loop(
+        0, -(-jnp.minimum(ends[-1], cap) // step), some,
+        jnp.full((-(-cap // step) * step,), n, jnp.int32))
+    return rows[:cap]
 
 
 def _push_hop(out, f, act, n, W, dtype, word_bits, f_cap, chunk):
@@ -780,7 +826,7 @@ def _push_hop(out, f, act, n, W, dtype, word_bits, f_cap, chunk):
     from dgraph_tpu.ops.hop import gather_edges
     indptr, indices, deg = out
     win = min(f_cap, max(chunk // PUSH_TURN_FANOUT, 1))
-    rows = _set_rows(act, n, f_cap)
+    rows = _set_rows(act, n, f_cap, win)
     ends = jnp.cumsum(jnp.take(deg, rows, mode="fill", fill_value=0))
     # (both bounds hold by the caller's check; the loop ends without it)
     count = jnp.minimum(act.sum(dtype=jnp.int32), f_cap)
@@ -812,27 +858,28 @@ def _push_hop(out, f, act, n, W, dtype, word_bits, f_cap, chunk):
 def _pull_or_push(prepared, out, f, caps, n, W, dtype, word_bits):
     """One hop of a lane program, computed one of two exact ways chosen
     on the device from the frontier `f` it is handed: (next mask [n+1, W],
-    whether it was pushed). A PUSH over the frontier's own out-edges
-    (`out`, _push_hop) when `caps` (row cap, slot cap, slots a turn) hold
-    its rows with a bit and an out-edge, the sum of their out-degrees and
-    the largest of them, else the PULL over every stored in-edge
-    (_ell_hop). Caps that hold no row compile no push: every hop pulls."""
+    whether it was pushed, the slots it pushed: 0 for a pull). A PUSH over
+    the frontier's own out-edges (`out`, _push_hop) when `caps` (row cap,
+    slot cap, slots a turn) hold its rows with a bit and an out-edge, the
+    sum of their out-degrees (its slots) and the largest of them, else the
+    PULL over every stored in-edge (_ell_hop). Caps that hold no row
+    compile no push: every hop pulls."""
     f_cap, e_cap, chunk = caps
     pull = functools.partial(_ell_hop, prepared, f, W, dtype)
     if not f_cap:
-        return pull(), False
+        return pull(), False, 0
     outdeg = out[2]
     act = (f[:n] != 0).any(axis=1) & (outdeg > 0)
     degs = jnp.where(act, outdeg, 0)
-    fits = ((act.sum(dtype=jnp.int32) <= f_cap)
-            & (degs.sum(dtype=jnp.int32) <= e_cap)
+    slots = degs.sum(dtype=jnp.int32)
+    fits = ((act.sum(dtype=jnp.int32) <= f_cap) & (slots <= e_cap)
             & (degs.max() <= chunk))
     nxt = lax.cond(
         fits,
         lambda: _push_hop(out, f, act, n, W, dtype, word_bits, f_cap,
                           chunk),
         pull)
-    return nxt, fits
+    return nxt, fits, jnp.where(fits, slots, 0)
 
 
 def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
@@ -840,7 +887,7 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
                   caps: tuple | None = None):
     """Compile a RESUMABLE hop block that stops itself:
     fn(frontier, seen, near, open_lanes, limit) →
-    (frontier', seen', hops, ran, open_lanes', pushed).
+    (frontier', seen', hops, ran, open_lanes', pushed, slots).
 
     It runs hops until no lane is open or `limit` (a traced scalar, at
     most `levels`) is reached, and at least one a call, so a staged
@@ -875,8 +922,9 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
 
     Each hop computes the same next mask one of two exact ways, chosen
     on the device from the frontier it is handed (_pull_or_push over
-    `dev.out`). `pushed` counts the hops of this call that pushed. `caps`
-    is push_caps of the relation's edges; tests pass their own.
+    `dev.out`). `pushed` counts the hops of this call that pushed and
+    `slots` the out-edges they expanded (both int32). `caps` is push_caps
+    of the relation; tests pass their own.
 
     `first_visit=False` drops the seen-masking: hops[h] is then the FULL
     set reachable in exactly h+1 hops (the level-DAG the k-shortest
@@ -884,9 +932,10 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
     lane closes only when its frontier is exhausted: that program makes
     no use of `near`, and its caller passes None."""
     dtype = jnp.uint32 if word_bits == 32 else jnp.uint64
-    caps = caps or push_caps(int(dev.out[1].shape[0]))
+    prepared = prepare_parts(dev, W)
+    caps = caps or prepared["caps"]
     # the index blocks ride as arguments (_as_arguments)
-    held, blocks = _as_arguments((prepare_parts(dev, W), dev.out))
+    held, blocks = _as_arguments((prepared, dev.out))
 
     def or_over(x, axis):
         return lax.reduce(x, dtype(0), lax.bitwise_or, (axis,))
@@ -896,14 +945,14 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
         prepared, out = blocks(arrays)
 
         def more(carry):
-            _f, _s, _buf, ran, _pushed, open_ = carry
+            _f, _s, _buf, ran, _pushed, _slots, open_ = carry
             return (ran == 0) | ((ran < limit) & (open_ != 0).any())
 
         def hop(carry):
-            f, s, buf, ran, pushed, open_ = carry
-            nxt, fits = _pull_or_push(prepared, out, f, caps, n, W, dtype,
-                                      word_bits)
-            pushed = pushed + fits
+            f, s, buf, ran, pushed, slots, open_ = carry
+            nxt, fits, pushed_slots = _pull_or_push(
+                prepared, out, f, caps, n, W, dtype, word_bits)
+            pushed, slots = pushed + fits, slots + pushed_slots
             if first_visit:
                 fresh = nxt & ~s
                 s = s | fresh
@@ -914,14 +963,15 @@ def make_ell_step(dev: DeviceEll, n: int, W: int, levels: int,
             open_ = open_ & or_over(fresh, 0)
             if first_visit:
                 open_ = open_ & ~or_over(fresh & near, 0)
-            return fresh, s, buf, ran + 1, pushed, open_
+            return fresh, s, buf, ran + 1, pushed, slots, open_
 
         buf = jnp.zeros((levels,) + frontier.shape, dtype)
-        f, s, buf, ran, pushed, open_ = lax.while_loop(
+        f, s, buf, ran, pushed, slots, open_ = lax.while_loop(
             more, hop,
-            (frontier, seen, buf, jnp.int32(0), jnp.int32(0), open_lanes))
+            (frontier, seen, buf, jnp.int32(0), jnp.int32(0), jnp.int32(0),
+             open_lanes))
         return (f, s, tuple(buf[h] for h in range(levels)), ran, open_,
-                pushed)
+                pushed, slots)
 
     return functools.partial(step, held)
 
@@ -953,8 +1003,8 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
                 rows' out-degrees [n] int32), for the pushed hops and
                 the traversed-edge count
       caps      recurse only: (row cap, slot cap, slots a turn) of a
-                pushed hop; None for push_caps of the relation's edges
-                (tests pass their own)
+                pushed hop; None for push_caps of the relation, which
+                `prepared` holds (tests pass their own)
       parent    ("seed", slot) | ("stage", idx earlier in the list)
       filt      filter-mask slot index | None  (global space, ANDed in)
       depth     recurse only: hop count (static)
@@ -968,13 +1018,14 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
 
     Returns fn(seeds: tuple, filts: tuple) → tuple with one entry per
     stage: hop → mask [n+1, W]; recurse → (seen, count, edges, pushed,
-    hops): `seen` [n+1, W] the reachable set incl. seeds, in the stage's
+    slots, hops): `seen` [n+1, W] the reachable set incl. seeds, in the stage's
     PERMUTED space (a consumer that wants a lane's members tests its
     column and maps the rows through perm_order: no translation is run
     for a set nobody reads); `count` int32[lanes] its population count a
     lane; `edges` int32[lanes] the out-degree mass of the rows expanded
     (seen less the last hop's fresh rows), the lane's traversed edges;
-    `pushed` int32, how many of the stage's `depth` hops pushed; `hops`
+    `pushed` int32, how many of the stage's `depth` hops pushed, and
+    `slots` int32, the out-edges those hops expanded; `hops`
     [depth, n+1, W] global-space first-visit masks when keep_hops, else
     None. The seed and filter masks are DONATED (consumed by the first
     gather).
@@ -986,7 +1037,7 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
     held, rebuild = _as_arguments(
         [(s["prepared"], s["perm_in"], s["out_idx"], s.get("out"))
          for s in stages])
-    caps = [s.get("caps") or push_caps(int(s["out"][1].shape[0]))
+    caps = [s.get("caps") or s["prepared"]["caps"]
             if s["kind"] == "recurse" else None for s in stages]
     # a recurse stage's set is translated to global space only for a
     # later stage that expands it
@@ -1017,24 +1068,25 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
 
             def hop(carry, _, _prep=prepared, _out=out, _caps=caps[i],
                     _filt_p=filt_p, _keep=keep_hops):
-                frontier, seen, pushed = carry
-                nxt, fits = _pull_or_push(_prep, _out, frontier, _caps, n,
-                                          W, dtype, word_bits)
+                frontier, seen, pushed, slots = carry
+                nxt, fits, pushed_slots = _pull_or_push(
+                    _prep, _out, frontier, _caps, n, W, dtype, word_bits)
                 fresh = nxt & ~seen
                 if _filt_p is not None:
                     fresh = fresh & _filt_p
                 seen = seen | fresh
-                return (fresh, seen, pushed + fits), (
+                return (fresh, seen, pushed + fits, slots + pushed_slots), (
                     fresh if _keep else None)
 
-            (last, seen_p, pushed), hops_p = lax.scan(
-                hop, (pm, pm, jnp.int32(0)), None, length=s["depth"])
+            (last, seen_p, pushed, slots), hops_p = lax.scan(
+                hop, (pm, pm, jnp.int32(0), jnp.int32(0)), None,
+                length=s["depth"])
             outs.append(seen_p[out_idx] if i in chained else None)
             results.append((
                 seen_p,
                 _lane_sums(seen_p, None, n, W, word_bits),
                 _lane_sums(seen_p & ~last, out[2], n, W, word_bits),
-                pushed,
+                pushed, slots,
                 hops_p[:, out_idx] if keep_hops else None))
         return tuple(results)
 

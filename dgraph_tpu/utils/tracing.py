@@ -530,6 +530,10 @@ def arm() -> None:
                     by=by)
     METRICS.inc("kernel_near2_edges_total", 0.0)
     METRICS.inc("kernel_near2_capped_total", 0.0)
+    # the out-edges the launches' pushed hops expanded (ops/bfs.py
+    # _pull_or_push): a window whose every hop pulled reads 0
+    for family in ("shortest", "tree"):
+        METRICS.inc("kernel_push_slots_total", 0.0, family=family)
 
 
 def add_sink(fn) -> None:

@@ -529,13 +529,14 @@ def _plain_launch(store, attr, pairs, levels, near2_max):
     rows have `near2_max` in-edges at most, or nothing new; a hop pushes
     when the rows that some lane reached last hop and that have an
     out-edge, the sum of their out-degrees and the largest of them fit
-    ops/bfs.py push_caps. Returns (hops run, hops pushed, lanes closed
-    by each rule)."""
+    ops/bfs.py push_caps of the relation's ELL. Returns (hops run, hops
+    pushed, the slots they pushed, lanes closed by each rule)."""
+    from dgraph_tpu.engine.batch import _ell_for
     from dgraph_tpu.ops.bfs import push_caps
 
     rel, rrel = store.rel(attr, False), store.rel(attr, True)
     deg = np.diff(rel.indptr)
-    f_cap, e_cap, chunk = push_caps(len(rel.indices))
+    f_cap, e_cap, chunk = push_caps(_ell_for(store, attr, False))
     src = store.rank_of(np.asarray([a for a, _ in pairs], np.int64))
     dst = store.rank_of(np.asarray([b for _, b in pairs], np.int64))
     closed = dict.fromkeys(CLOSED_BY, 0)
@@ -552,12 +553,14 @@ def _plain_launch(store, attr, pairs, levels, near2_max):
             open_.add(q)
     fresh = [{int(s)} if q in open_ else set() for q, s in enumerate(src)]
     seen = [set(f) for f in fresh]
-    ran = pushed = 0
+    ran = pushed = slots = 0
     while open_ and ran < levels:
         rows = np.array(sorted(set().union(*fresh)), np.int64)
         rows = rows[deg[rows] > 0]
-        pushed += bool(f_cap) and len(rows) <= f_cap and \
-            deg[rows].sum() <= e_cap and deg[rows].max(initial=0) <= chunk
+        if f_cap and len(rows) <= f_cap and deg[rows].sum() <= e_cap \
+                and deg[rows].max(initial=0) <= chunk:
+            pushed += 1
+            slots += int(deg[rows].sum())
         ran += 1
         for q in range(len(pairs)):
             nxt = {int(v) for u in fresh[q] for v in rel.row(u)} - seen[q]
@@ -568,15 +571,16 @@ def _plain_launch(store, attr, pairs, levels, near2_max):
             if by and q in open_:
                 open_.discard(q)
                 closed[by] += 1
-    return ran, pushed, closed
+    return ran, pushed, slots, closed
 
 
 def test_shortest_batch_mixes_pushed_and_pulled_hops():
     """On a graph large enough that a launch's first hop fits the pushed
     hop's caps and its later hops do not, the batch answers what the
-    per-query path answers; the push counter moves by the hops pushed and
-    stays under the hops run; and only the step program brings the
-    out-CSR to the device: a @recurse batch on the same store does not."""
+    per-query path answers; the push counters move by the hops pushed and
+    their slots, and stay under the hops run; and only the step program
+    brings the out-CSR to the device: a @recurse batch on the same store
+    does not."""
     from dgraph_tpu.engine.batch import NEAR2_MAX_EDGES, SHORTEST_STAGE, \
         _cache_host
     from dgraph_tpu.utils.metrics import METRICS
@@ -584,7 +588,7 @@ def test_shortest_batch_mixes_pushed_and_pulled_hops():
     rng = np.random.default_rng(11)
     a = Alpha(device_threshold=10**9)
     a.alter(SCHEMA)
-    n = 1200            # 4,800 edges: the caps hold one row, not its 4
+    n = 1200            # 4,800 edges: the caps hold 7 rows, not hop 3's 16
     lines = [f'_:p{i} <name> "p{i}" .' for i in range(n)]
     for i in range(n):
         lines += [f"_:p{i} <follows> _:p{j} ."
@@ -592,8 +596,8 @@ def test_shortest_batch_mixes_pushed_and_pulled_hops():
     uids = a.mutate(set_nquads="\n".join(lines))["uids"]
 
     def counters():
-        return [METRICS.get(f"kernel_hops_{k}_total", family="shortest")
-                for k in ("run", "push")]
+        return [METRICS.get(f"kernel_{k}_total", family="shortest")
+                for k in ("hops_run", "hops_push", "push_slots")]
 
     store = a.mvcc.read_view(a.oracle.read_only_ts())
     a.query_batch(_queries(6, depth=2))
@@ -604,16 +608,16 @@ def test_shortest_batch_mixes_pushed_and_pulled_hops():
              for i, j in ((1, 240), (1, 77), (1, 950), (1, 123))]
     qs = ['{ path as shortest(from: %s, to: %s) { follows } '
           'p(func: uid(path)) { name } }' % p for p in pairs]
-    run0, push0 = counters()
+    before = counters()
     got = a.query_batch(qs)
-    run1, push1 = counters()
+    moved = tuple(b - a for a, b in zip(before, counters()))
     assert dev.out is not None
     eng = Engine(store, device_threshold=10**9)
     assert json.dumps(got) == json.dumps([eng.query(q) for q in qs])
-    want_run, want_push, _closed = _plain_launch(
+    want_run, want_push, want_slots, _closed = _plain_launch(
         store, "follows", [(int(x, 16), int(y, 16)) for x, y in pairs],
         SHORTEST_STAGE, NEAR2_MAX_EDGES)
-    assert (run1 - run0, push1 - push0) == (want_run, want_push)
+    assert moved == (want_run, want_push, want_slots)
     assert 0 < want_push < want_run, "the batch must mix both kinds of hop"
 
 
@@ -622,7 +626,8 @@ def test_two_level_look_ahead_is_the_plain_scan(alpha, monkeypatch,
                                                 near2_max):
     """64 lanes over a random graph full of cycles, in one launch: the
     answers (paths and their order) are the per-query engine's, and the
-    hops run, the hops pushed and the lanes closed under each rule are
+    hops run, the hops pushed, their slots and the lanes closed under
+    each rule are
     the plain search's, whether every lane takes the second level (the
     constant as it stands), some do (12 in-edges: about a third) or
     none (0: the launch the first level alone gives)."""
@@ -645,8 +650,9 @@ def test_two_level_look_ahead_is_the_plain_scan(alpha, monkeypatch,
           'p(func: uid(path)) { name } }' % p for p in pairs]
 
     def counters():
-        return ([METRICS.get(f"kernel_hops_{k}_total", family="shortest")
-                 for k in ("run", "used", "push")]
+        return ([METRICS.get(f"kernel_{k}_total", family="shortest")
+                 for k in ("hops_run", "hops_used", "hops_push",
+                           "push_slots")]
                 + [METRICS.get("kernel_lanes_closed_total",
                                family="shortest", by=by)
                    for by in CLOSED_BY]
@@ -654,13 +660,15 @@ def test_two_level_look_ahead_is_the_plain_scan(alpha, monkeypatch,
 
     before = counters()
     got = alpha.query_batch(qs)
-    run, used, push, *closed = (b - a for a, b in zip(before, counters()))
+    run, used, push, slots, *closed = (
+        b - a for a, b in zip(before, counters()))
     eng = Engine(store, device_threshold=10**9)
     assert json.dumps(got) == json.dumps([eng.query(q) for q in qs])
-    want_run, want_push, want_closed = _plain_launch(
+    want_run, want_push, want_slots, want_closed = _plain_launch(
         store, "follows", [(int(x, 16), int(y, 16)) for x, y in pairs],
         batch.SHORTEST_STAGE, near2_max)
-    assert (run, used, push) == (want_run, want_run, want_push)
+    assert (run, used, push, slots) == (want_run, want_run, want_push,
+                                        want_slots)
     assert dict(zip(CLOSED_BY, closed)) == want_closed
     capped = closed[-1]
     if near2_max == 0:

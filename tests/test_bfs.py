@@ -338,11 +338,17 @@ def _fits(rows, deg, caps):
         deg[act].sum() <= caps[1] and deg[act].max(initial=0) <= caps[2]
 
 
+def _slots(rows, deg, caps):
+    """The slots a hop pushes: the out-edges of its frontier's `rows`
+    where the caps hold them, 0 where it pulls."""
+    return int(deg[rows].sum()) if _fits(rows, deg, caps) else 0
+
+
 def _pushes(frontier, dev, n, caps):
-    """Whether the step pushes this frontier (packed, in the ELL's row
-    space)."""
-    return _fits((frontier[:n] != 0).any(axis=1), np.asarray(dev.out[2]),
-                 caps)
+    """(whether, over how many slots) the step pushes this frontier
+    (packed, in the ELL's row space)."""
+    rows, deg = (frontier[:n] != 0).any(axis=1), np.asarray(dev.out[2])
+    return _fits(rows, deg, caps), _slots(rows, deg, caps)
 
 
 @functools.lru_cache(maxsize=None)
@@ -419,7 +425,8 @@ def test_step_stops_where_the_host_rule_closes_the_last_lane(
         caps, lanes, first_visit, acyclic, limit):
     """Every hop's level, `seen`, `ran` and the open lanes equal the plain
     scan of pulls, whichever of its hops the step pushes; and it pushes
-    exactly the hops whose frontier its caps hold."""
+    exactly the hops whose frontier its caps hold, and counts their
+    out-edges."""
     import jax
 
     c = _step_case(lanes, first_visit, acyclic, caps)
@@ -432,7 +439,7 @@ def test_step_stops_where_the_host_rule_closes_the_last_lane(
         open_before = _packed(unresolved, W)
         closing = _host_rule(want_f[done:done + lim], n, c.near_rows,
                              unresolved, first_visit)
-        f, s, hops, ran, open_after, pushed = step(
+        f, s, hops, ran, open_after, pushed, slots = step(
             jax.device_put(frontier), jax.device_put(seen),
             c.near if first_visit else None, open_before, np.int32(lim))
         ran = int(ran)
@@ -441,8 +448,8 @@ def test_step_stops_where_the_host_rule_closes_the_last_lane(
         for h in range(ran):
             assert np.array_equal(np.asarray(hops[h]), want_f[done + h])
         expanded = [frontier] + list(want_f[done:done + ran - 1])
-        assert int(pushed) == sum(
-            _pushes(fr, dev, n, STEP_CAPS[caps]) for fr in expanded)
+        assert (int(pushed), int(slots)) == tuple(map(sum, zip(*(
+            _pushes(fr, dev, n, STEP_CAPS[caps]) for fr in expanded))))
         pushed_all += int(pushed)
         done += ran
         frontier, seen = np.asarray(f), np.asarray(s)
@@ -492,7 +499,7 @@ def test_the_look_ahead_spares_a_launch_its_last_hop(caps, lanes, near):
     assert len(finders) > (lanes // 4 if first_visit else 0)
 
     def run(near_mask):
-        _f, _s, hops, ran, open_after, _pushed = c.step(
+        _f, _s, hops, ran, open_after, _pushed, _slots = c.step(
             jax.device_put(c.mask0), jax.device_put(c.mask0), near_mask,
             _packed(finders, W), np.int32(STEP_LEVELS))
         for h in range(int(ran)):
@@ -529,17 +536,17 @@ def test_the_look_ahead_spares_a_launch_its_last_hop(caps, lanes, near):
 
 def _one_hop(dev, n, W, mask0, caps, first_visit=True):
     """One hop of a fresh step program under `caps`, every lane open and
-    none with a row to look ahead to: (level, seen, pushed)."""
+    none with a row to look ahead to: (level, seen, pushed, slots)."""
     import jax
 
     from dgraph_tpu.ops.bfs import make_ell_step
     step = make_ell_step(dev, n, W, 1, first_visit=first_visit, caps=caps)
-    _f, s, hops, ran, _open, pushed = step(
+    _f, s, hops, ran, _open, pushed, slots = step(
         jax.device_put(mask0), jax.device_put(mask0),
         np.zeros_like(mask0) if first_visit else None,
         np.full(W, 0xFFFFFFFF, np.uint32), np.int32(1))
     assert int(ran) == 1
-    return np.asarray(hops[0]), np.asarray(s), int(pushed)
+    return np.asarray(hops[0]), np.asarray(s), int(pushed), int(slots)
 
 
 @pytest.mark.parametrize("first_visit", [True, False])
@@ -561,10 +568,76 @@ def test_a_frontier_over_a_cap_takes_the_pull(over, first_visit):
             "one_row": (rows - 1, edges, widest),
             "one_edge": (rows, edges - 1, widest),
             "one_slot": (rows, edges, widest - 1)}[over]
-    level, seen, pushed = _one_hop(dev, n, W, mask0, caps, first_visit)
-    assert pushed == (over == "fits")
+    level, seen, pushed, slots = _one_hop(dev, n, W, mask0, caps,
+                                          first_visit)
+    assert (pushed, slots) == ((1, edges) if over == "fits" else (0, 0))
     assert np.array_equal(level, want_f[0])
     assert np.array_equal(seen, want_s[0])
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_rows_graph():
+    """A relation whose every row has 40 to 56 out-edges, so that its own
+    caps (push_caps of its structure) bind by their slots, not their
+    rows: a row of PUSH_FANOUT out-edges or more is what the slot cap
+    was priced for."""
+    from dgraph_tpu.store.store import _csr_from_pairs
+    rng = np.random.default_rng(44)
+    n = 2000
+    src = np.repeat(np.arange(n, dtype=np.int32), rng.integers(40, 57, n))
+    dst = rng.integers(0, n, src.size).astype(np.int32)
+    rel = _csr_from_pairs(src, dst, n)
+    return (n, *_device_ell_with_out(rel))
+
+
+@pytest.mark.parametrize("first_visit", [True, False])
+@pytest.mark.parametrize("frontier", ["just_under", "just_over"])
+def test_the_relations_own_caps_push_up_to_the_break_even(frontier,
+                                                          first_visit):
+    """Under push_caps of the relation (no caps passed), a frontier whose
+    out-edges number the slot cap at most is pushed and counted, one row
+    more is pulled, and the level is the pull-only program's each way."""
+    from dgraph_tpu.ops.bfs import PUSH_FANOUT, push_caps
+    n, g, dev = _wide_rows_graph()
+    f_cap, e_cap, chunk = push_caps(g)
+    assert push_caps(dev) == (f_cap, e_cap, chunk)
+    deg = np.asarray(dev.out[2])
+    assert deg.min() >= PUSH_FANOUT and deg.max() <= chunk
+    # rows in the ELL's order while their out-edges fit the slot cap
+    rows = int(np.searchsorted(np.cumsum(deg), e_cap, side="right"))
+    assert 4 <= rows < f_cap and e_cap - deg.max() < deg[:rows].sum()
+    rows += frontier == "just_over"
+    W = 2
+    mask0 = np.zeros((n + 1, W), np.uint32)
+    lane = np.arange(rows) % 64
+    mask0[np.arange(rows), lane // 32] = np.uint32(1) << (lane % 32
+                                                         ).astype(np.uint32)
+    level, seen, pushed, slots = _one_hop(dev, n, W, mask0, None,
+                                          first_visit)
+    assert (pushed, slots) == ((1, int(deg[:rows].sum()))
+                               if frontier == "just_under" else (0, 0))
+    want, want_seen, *_ = _one_hop(dev, n, W, mask0, (0, 0, 1),
+                                   first_visit)
+    assert np.array_equal(level, want) and level.any()
+    assert np.array_equal(seen, want_seen)
+
+
+@pytest.mark.parametrize("n,cap,share,step", [
+    (1000, 64, 0.01, 4096), (50000, 700, 0.01, 256), (50000, 700, 0.5, 256),
+    (300001, 5000, 0.1, 1024), (129, 200, 0.9, 64), (70000, 100, 0.0, 32),
+    (70000, 1000, 1.0, 300)])
+def test_set_rows_lists_the_first_set_rows_a_turn_at_a_time(n, cap, share,
+                                                            step):
+    """The first `cap` set rows ascending, padded with n: fewer rows than
+    a turn, many turns, more set rows than the cap, a cap that is no
+    whole number of turns, none set, all set."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import _set_rows
+    act = np.random.default_rng(cap).random(n) < share
+    got = np.asarray(jax.jit(lambda a: _set_rows(a, n, cap, step))(act))
+    want = np.nonzero(act)[0][:cap]
+    assert got.tolist() == want.tolist() + [n] * (cap - len(want))
 
 
 @pytest.mark.parametrize("chunk", [5, 64, 1024])
@@ -577,8 +650,9 @@ def test_a_pushed_hop_leaves_the_sentinel_row_zero(chunk):
     deg = np.asarray(dev.out[2])
     edges = int(deg[(mask0[:n] != 0).any(axis=1)].sum())
     assert edges % chunk, "the last turn must hold slots with no edge"
-    level, seen, pushed = _one_hop(dev, n, W, mask0, (n, 600, chunk))
-    assert pushed == 1
+    level, seen, pushed, slots = _one_hop(dev, n, W, mask0,
+                                          (n, 600, chunk))
+    assert (pushed, slots) == (1, edges)
     assert not level[n].any() and not seen[n].any()
     assert np.array_equal(level, want_f[0])
 
@@ -599,9 +673,9 @@ def test_a_hub_ors_the_lane_bits_of_its_many_sources(lanes, chunk):
     mask0[g.new_of_old[np.arange(fans)], 0] |= np.uint32(1)       # lane 0
     mask0[g.new_of_old[np.arange(3)], 0] |= np.uint32(2)          # lane 1
     mask0[g.new_of_old[7], W - 1] |= np.uint32(1 << 31)           # the last
-    pulled, _s, p0 = _one_hop(dev, n, W, mask0, (0, 0, 1))
-    pushed, _s, p1 = _one_hop(dev, n, W, mask0, (n, fans + 2, chunk))
-    assert (p0, p1) == (0, 1)
+    pulled, _s, *p0 = _one_hop(dev, n, W, mask0, (0, 0, 1))
+    pushed, _s, *p1 = _one_hop(dev, n, W, mask0, (n, fans + 2, chunk))
+    assert (p0, p1) == ([0, 0], [1, fans])
     want = np.zeros((n + 1, W), np.uint32)
     want[g.new_of_old[hub], 0] = 3
     want[g.new_of_old[hub], W - 1] |= np.uint32(1 << 31)
@@ -612,7 +686,7 @@ def test_a_hub_ors_the_lane_bits_of_its_many_sources(lanes, chunk):
 # -- the tree program's recurse stage (ops/bfs.py make_ell_tree) -------------
 
 TREE_DEPTH = 3
-TREE_N, TREE_EDGES = 3000, 20000   # push_caps: 4 rows, 156 slots, 156 a turn
+TREE_N, TREE_EDGES = 3000, 20000  # push_caps: 31 rows, 1,015 slots, a turn
 
 
 @functools.lru_cache(maxsize=None)
@@ -679,10 +753,12 @@ def _tree_run(caps_name, lanes, filtered, keep_hops):
     rng = np.random.default_rng(lanes + 2 * filtered)
     starts = np.nonzero((deg > 0) & (deg <= 8))[0]
     if caps_name == "default":
-        # caps scaled from 20,000 edges hold four rows: the lanes share
-        # four seeds, so hop 1 fits them and hop 2 does not
-        pool = rng.choice(starts, 4, replace=False)
-        seeds = [pool[q % 4:q % 4 + 1] for q in range(lanes - 3)]
+        # the relation's own caps hold 31 rows: the lanes share as many
+        # seeds as the row cap, so hop 1 just fits and hop 2 does not
+        rows = push_caps(g)[0]
+        pool = rng.choice(starts, rows, replace=False)
+        seeds = [pool[q % rows::lanes - 3] for q in range(lanes - 3)]
+        assert len(np.unique(np.concatenate(seeds))) == rows >= 4
     else:
         seeds = [rng.choice(starts, 1 + q % 2, replace=False)
                  for q in range(lanes - 3)]      # the last three: padding
@@ -693,7 +769,7 @@ def _tree_run(caps_name, lanes, filtered, keep_hops):
     caps = {
         "pull": (0, 0, 1),                       # none hold a row
         "push": (TREE_N, TREE_EDGES, int(deg.max())),    # every hop fits
-        "default": push_caps(len(rel.indices)),
+        "default": push_caps(g),
         "hop1": (rows1, slots1, widest1),        # hop 2 is over the slots
         "turn": (TREE_N, TREE_EDGES, widest1 - 1),   # a seed over a turn
     }[caps_name]
@@ -716,11 +792,11 @@ def _tree_run(caps_name, lanes, filtered, keep_hops):
         "parent": ("seed", 0), "filt": 0 if filtered else None,
         "depth": TREE_DEPTH, "keep_hops": keep_hops}
     fn = make_ell_tree([stage], n, W)
-    (seen, count, edges, pushed, hops), = fn(
+    (seen, count, edges, pushed, slots, hops), = fn(
         (jax.device_put(seed_mask),),
         (jax.device_put(filt_mask),) if filtered else ())
     got = (_unpacked(seen, n)[g.new_of_old], np.asarray(count),
-           np.asarray(edges), int(pushed),
+           np.asarray(edges), (int(pushed), int(slots)),
            None if hops is None else
            np.stack([_unpacked(h, n) for h in np.asarray(hops)]))
     assert not np.asarray(seen)[n].any(), "the sentinel row stays zero"
@@ -738,7 +814,8 @@ def test_tree_recurse_stage_pushes_what_its_caps_hold(caps_name, lanes,
     `edges` and `hops` equal the pull-only program's and a plain
     breadth-first search's, and `pushed` counts the hops whose frontier
     the caps hold: rows with a bit and an out-edge, their out-degrees'
-    sum, and the largest of them against a turn."""
+    sum (`slots` adds it up over those hops), and the largest of them
+    against a turn."""
     rel, _g, _dev = _tree_graph()
     deg = np.diff(rel.indptr)
     (seen, count, edges, pushed, hops), seeds, allowed, caps = _tree_run(
@@ -755,8 +832,10 @@ def test_tree_recurse_stage_pushes_what_its_caps_hold(caps_name, lanes,
     first = np.zeros_like(want_seen)
     for q, s in enumerate(seeds):
         first[s, q] = True
-    flags = _tree_pushes([first, *want_hops[:-1]], deg, caps)
-    assert pushed == sum(flags)
+    frontiers = [first, *want_hops[:-1]]
+    flags = _tree_pushes(frontiers, deg, caps)
+    assert pushed == (sum(flags), sum(_slots(f.any(axis=1), deg, caps)
+                                      for f in frontiers))
     if caps_name == "turn":
         # rows and slots fit: the widest seed alone sends hop 1 to the pull
         assert not flags[0] and _tree_pushes(
@@ -771,7 +850,7 @@ def test_tree_recurse_stage_pushes_what_its_caps_hold(caps_name, lanes,
     if caps_name not in ("pull", "default"):
         (p_seen, p_count, p_edges, p_pushed, p_hops), *_ = _tree_run(
             "pull", lanes, filtered, keep_hops)
-        assert p_pushed == 0
+        assert p_pushed == (0, 0)
         assert np.array_equal(seen, p_seen)
         assert np.array_equal(count, p_count)
         assert np.array_equal(edges, p_edges)
@@ -789,6 +868,47 @@ NO_BLOCK = (1.0, 0, 1 << 62)
 # caps of the pushed hop that hold 64 rows of the periphery (three out-edges
 # each) and no frontier after them
 HUB_CAPS = {"pull": (0, 0, 1), "mixed": (80, 300, 24)}
+
+
+@pytest.mark.parametrize("relation", ["no_block", "hub_block", "tiny"])
+def test_push_caps_are_where_a_push_costs_what_a_pull_costs(relation):
+    """The slot cap is the relation's pull, priced by the chip's readings
+    (its list slots, its hub block's cells), over a pushed slot's price:
+    about a twentieth of the slots. A hub block lowers it, since the
+    edges it holds are in no list; a graph of a few hundred edges keeps a
+    pull side, and one of a few dozen never pushes."""
+    from dgraph_tpu.ops import bfs
+    from dgraph_tpu.store.store import _csr_from_pairs
+    if relation == "tiny":
+        rng = np.random.default_rng(3)
+        rel = _csr_from_pairs(rng.integers(0, 30, 60).astype(np.int32),
+                              rng.integers(0, 30, 60).astype(np.int32), 30)
+        g = build_ell(rel.indptr, rel.indices)
+    else:
+        rel = _hub_rel()
+        g = build_ell(rel.indptr, rel.indices,
+                      dense=HUB_RULE if relation == "hub_block"
+                      else NO_BLOCK)
+    slots = g.padded_edges
+    cells = 0 if g.dense is None else g.dense[0].size
+    assert (cells > 0) == (relation == "hub_block")
+    f_cap, e_cap, chunk = bfs.push_caps(g)
+    assert e_cap == int((slots * bfs.PULL_SLOT_NS
+                         + cells * bfs.DENSE_CELL_NS) / bfs.PUSH_SLOT_NS)
+    assert (f_cap, chunk) == (e_cap // bfs.PUSH_FANOUT,
+                              min(bfs.PUSH_CHUNK, max(e_cap, 1)))
+    # a pull side whatever the graph: at most a tenth of its edges pushed
+    assert slots >= g.nnz - g.dense_edges and e_cap <= g.nnz // 10
+    if relation == "tiny":
+        assert f_cap == 0, "caps that hold no row compile no push"
+    else:
+        assert f_cap >= 1 and e_cap >= slots // 25
+    if relation == "hub_block":
+        plain = bfs.push_caps(build_ell(rel.indptr, rel.indices,
+                                        dense=NO_BLOCK))
+        assert e_cap < plain[1]
+        # by what the block took out of the lists, less its own price
+        assert plain[1] - e_cap >= (g.dense_edges - len(g.dense[0])) // 25
 
 
 @functools.lru_cache(maxsize=None)
@@ -1031,7 +1151,8 @@ def test_a_graph_under_the_edge_floor_builds_no_block(name):
 def test_the_step_with_the_block_runs_the_hops_of_one_without(lanes, caps):
     """make_ell_step over the hub relation, `near` given, hops pushed and
     pulled: with the block and without, the same levels, the same seen,
-    the same count of hops run and pushed, the same lanes left open."""
+    the same count of hops run, pushed and of slots pushed, the same
+    lanes left open."""
     import jax
 
     from dgraph_tpu.ops.bfs import device_ell, make_ell_step, out_csr
@@ -1056,16 +1177,18 @@ def test_the_step_with_the_block_runs_the_hops_of_one_without(lanes, caps):
             mask0[g.new_of_old[srcs[q]], wq] |= bq
             near[g.new_of_old[rrel.row(int(dsts[q]))], wq] |= bq
         step = make_ell_step(dev, n, W, 4, caps=HUB_CAPS[caps])
-        f, s, hops, ran, open_, pushed = step(
+        f, s, hops, ran, open_, pushed, slots = step(
             jax.device_put(mask0), jax.device_put(mask0),
             jax.device_put(near), _packed(range(lanes), W), 4)
-        ran, pushed = int(ran), int(pushed)
+        ran, pushed = int(ran), (int(pushed), int(slots))
         runs.append((ran, pushed, np.asarray(open_),
                      np.asarray(s)[g.new_of_old],
                      [np.asarray(h)[g.new_of_old] for h in hops[:ran]]))
     (ran, pushed, open_, seen, hops), other = runs
     assert ran >= 2 and (ran, pushed) == other[:2]
-    assert pushed == 0 if caps == "pull" else 0 < pushed < ran
+    # a pushed hop of the periphery's sources: three out-edges a row
+    assert pushed == (0, 0) if caps == "pull" else \
+        0 < pushed[0] < ran and pushed[1] >= 3
     assert np.array_equal(open_, other[2])
     assert np.array_equal(seen, other[3])
     for a, b in zip(hops, other[4]):
@@ -1083,7 +1206,8 @@ def test_the_tree_with_the_block_counts_what_one_without_counts(caps_name,
                                                                 keep_hops):
     """make_ell_tree: a recurse stage of three hops and a hop stage over
     its set, both over the hub relation. Counts, traversed edges, pushed
-    hops, sets and hop masks equal with the block and without."""
+    hops and their slots, sets and hop masks equal with the block and
+    without."""
     import jax
 
     from dgraph_tpu.ops.bfs import (device_ell, make_ell_tree, out_csr,
@@ -1109,17 +1233,20 @@ def test_the_tree_with_the_block_counts_what_one_without_counts(caps_name,
              "keep_hops": keep_hops, "caps": HUB_CAPS[caps_name],
              "out": jax.device_put(out_csr(g, rel.indptr, rel.indices))},
             {**common, "kind": "hop", "parent": ("stage", 0)}], n, W)
-        (seen, count, edges, pushed, hops), mask = tree(
+        (seen, count, edges, pushed, slots, hops), mask = tree(
             (jax.device_put(seeds),), ())
         runs.append((np.asarray(seen)[np.append(g.new_of_old, n)],
-                     np.asarray(count), np.asarray(edges), int(pushed),
+                     np.asarray(count), np.asarray(edges),
+                     (int(pushed), int(slots)),
                      None if hops is None else np.asarray(hops),
                      np.asarray(mask)))
     with_block, without = runs
     for a, b in zip(with_block, without):
         assert np.array_equal(a, b)
     seen, count, _edges, pushed, hops, mask = with_block
-    assert pushed == (0 if caps_name == "pull" else 1)
+    # hop 1: the out-edges of the periphery's sources that carry a lane
+    first = int(np.diff(rel.indptr)[(seeds[:n] != 0).any(axis=1)].sum())
+    assert pushed == ((0, 0) if caps_name == "pull" else (1, first))
     assert (hops is not None) == keep_hops
     assert np.array_equal(mask[:n], _pull_reference(rel, seen[:n]))
     assert count.sum() == sum(bin(int(w)).count("1")

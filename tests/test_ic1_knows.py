@@ -342,6 +342,107 @@ def test_seen_comes_back_with_the_launch_or_not_at_all(knows):
             if s.name == "batch.fetch"] == [0]
 
 
+def _hop_frontiers(rel, ranks, depth):
+    """The rows a launch's hops expand: for each hop, the union over the
+    lanes of the rows a lane first reached the hop before (a search a
+    lane from its start person, in numpy sets)."""
+    fresh = [{int(r)} for r in ranks]
+    seen = [set(f) for f in fresh]
+    out = []
+    for _ in range(depth):
+        out.append(np.array(sorted(set().union(*fresh)), np.int64))
+        for q, f in enumerate(fresh):
+            fresh[q] = {int(v) for u in f for v in rel.row(u)} - seen[q]
+            seen[q] |= fresh[q]
+    return out
+
+
+@pytest.mark.parametrize("batch", ["a_few_persons", "sixty_four"])
+def test_hop_two_is_pushed_where_a_push_is_the_cheaper_way(knows, batch):
+    """The caps of a pushed hop are what a pull of `knows` costs
+    (ops/bfs.py push_caps), not a 128th of its edges: a batch of as few
+    start persons as put hop 2's frontier over the old cap and under the
+    new runs hops 1 and 2 pushed and hop 3 pulled, `kernel_push_slots_
+    total` rises by the two frontiers' out-edges, and a batch of 64,
+    whose second frontier is over the new caps too, pushes hop 1 alone."""
+    from dgraph_tpu.engine.batch import MIN_BATCH, _ell_for
+    from dgraph_tpu.ops.bfs import push_caps
+    data, store, ref, names = knows
+    rel = store.rel("knows", False)
+    deg = np.diff(rel.indptr)
+    f_cap, e_cap, chunk = push_caps(_ell_for(store, "knows", False))
+    old_cap = len(rel.indices) // 128
+    assert (len(rel.indices), old_cap) == (72000, 562) and \
+        e_cap > 4 * old_cap
+
+    def slots_pushed(frontier):
+        """A hop's slots where the caps hold its frontier, else None."""
+        rows = frontier[deg[frontier] > 0]
+        fits = len(rows) <= f_cap and deg[rows].sum() <= e_cap \
+            and deg[rows].max(initial=0) <= chunk
+        return int(deg[rows].sum()) if fits else None
+
+    order = np.random.default_rng(44).permutation(int(data["n_nodes"]))
+    if batch == "sixty_four":
+        persons = order[:64]
+    else:
+        # the fewest persons (a group is MIN_BATCH queries at least)
+        # whose friends' out-edges pass the old cap
+        persons = next(
+            order[:k] for k in range(MIN_BATCH, 64) if deg[_hop_frontiers(
+                rel, store.rank_of(order[:k] + 1), 2)[1]].sum() > old_cap)
+    hops = [slots_pushed(f) for f in _hop_frontiers(
+        rel, store.rank_of(persons + 1), 3)]
+    if batch == "sixty_four":
+        assert hops[0] is not None and hops[1:] == [None, None]
+    else:
+        assert len(persons) < 8 and hops[2] is None
+        assert hops[0] < old_cap < hops[1] <= e_cap
+    ms = metas(persons + 1, [names[k % 5] for k in range(len(persons))])
+
+    def counters():
+        return [METRICS.get(f"kernel_{k}_total", family="tree")
+                for k in ("hops_run", "hops_push", "push_slots")]
+
+    before = counters()
+    got = serve(store, [ic1(m["person"], m["first_name"]) for m in ms],
+                probe=1)
+    assert got == [ref.answer(m) for m in ms]
+    pushed = [h for h in hops if h is not None]
+    assert [b - a for a, b in zip(before, counters())] == [
+        3, len(pushed), sum(pushed)]
+    assert len(pushed) == (1 if batch == "sixty_four" else 2)
+
+
+@pytest.mark.parametrize("program", ["this_one", "the_parents"])
+def test_the_benchmark_reads_the_slots_pushed_a_query(knows, program):
+    """`benchmark/layer_metrics/push_slots_per_query.batch.json` through
+    the benchmark's own reader, over the registry's exposition before and
+    after a batch of eight: the slots hop 1 pushed (the start persons'
+    out-edges; eight persons' friends are over the row cap) over the
+    eight queries; and nothing, not 0, from an exposition without the
+    series, which is what the parent's program gives the same file."""
+    from harness.server import parse_prom
+    from readers import prom_ratio
+    data, store, _ref, names = knows
+    with open(os.path.join(BENCH, "layer_metrics",
+                           "push_slots_per_query.batch.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "prom_ratio"
+    persons = np.arange(1, 9) * 211
+    before = parse_prom(METRICS.render())
+    serve(store, [ic1(int(p), names[0]) for p in persons], probe=1)
+    after = parse_prom(METRICS.render())
+    if program == "the_parents":
+        before, after = ([x for x in series if not x[0].startswith(
+            "dgraph_tpu_kernel_push_slots")] for series in (before, after))
+    got = prom_ratio.read({"prom_before": before, "prom_after": after},
+                          **spec["args"])
+    deg = np.diff(store.rel("knows", False).indptr)
+    assert got == (None if program == "the_parents" else
+                   deg[store.rank_of(persons)].sum() / 8)
+
+
 # ---------------------------------------------------------------------------
 # an order's keys by whole arrays
 

@@ -144,9 +144,10 @@ def test_launch_returns_counts_and_no_hop_masks(g500):
     rels = {("link", False): _ell_for(store, "link", False)}
     fn = _tree_kernel_for(store, plan, rels, n, 1)
     seeds = _pack_global(n, [np.array([i], np.int32) for i in nodes], 32)
-    (seen, count, edges, pushed, hops), = fn((seeds,), ())
+    (seen, count, edges, pushed, slots, hops), = fn((seeds,), ())
     assert hops is None and seen.shape == (n + 1, 1)
     assert pushed.dtype == np.int32 and 0 <= int(pushed) <= 3
+    assert slots.dtype == np.int32 and bool(slots) == bool(pushed)
     assert count.dtype == np.int32 and count.shape == (32,)
     assert count.tolist() == [ref.within(int(i), 3) for i in nodes]
     # traversed edges: the out-degrees of everything within 2 hops
@@ -355,8 +356,8 @@ def out_csr_spans():
 
 
 def _tree_hops():
-    return [METRICS.get(f"kernel_hops_{k}_total", family="tree")
-            for k in ("run", "push")]
+    return [METRICS.get(f"kernel_{k}_total", family="tree")
+            for k in ("hops_run", "hops_push", "push_slots")]
 
 
 def _link_dev(store):
@@ -369,14 +370,15 @@ def test_khop_counts_push_their_first_hop(out_csr_spans, depth):
     """A /query/batch-shaped group of k-hop counts rides one launch whose
     recurse stage pushes the hops its frontier fits (hop 1 of seeds with
     a few out-edges) and pulls the others: the counts are the host
-    engine's, the device's two counts of hops reach the counters, and
-    the relation's out-CSR is built and uploaded once, however many
-    launches read it."""
+    engine's, the device's two counts of hops and its count of the
+    slots pushed reach the counters, and the relation's out-CSR is built
+    and uploaded once, however many launches read it."""
+    from dgraph_tpu.engine.batch import _ell_for
     from dgraph_tpu.ops.bfs import push_caps
 
     data, store = _store_with_reverse()
     eng = Engine(store, device_threshold=10**9)
-    f_cap, e_cap, _chunk = push_caps(len(data["src"]))
+    f_cap, e_cap, _chunk = push_caps(_ell_for(store, "link", False))
     rl = data["row_len"]
     few = np.nonzero((rl > 0) & (rl <= e_cap // f_cap))[0]
     assert f_cap >= 4 and len(few) >= 2 * f_cap
@@ -384,13 +386,16 @@ def test_khop_counts_push_their_first_hop(out_csr_spans, depth):
     for nodes, pushes in ((few[:f_cap], True), (few[f_cap:2 * f_cap], True),
                           (hubs, False)):
         qs = [COUNT % (hex(int(i) + 1), depth) for i in nodes]
-        run0, push0 = _tree_hops()
+        run0, push0, slots0 = _tree_hops()
         got, _plan = serve(store, qs)
         assert got == [eng.query(q) for q in qs]
         assert all(a["q"][0]["count"] > 1 for a in got)
-        run1, push1 = _tree_hops()
+        run1, push1, slots1 = _tree_hops()
         assert run1 - run0 == depth
         assert (1 <= push1 - push0 < depth) if pushes else push1 == push0
+        # hop 1's slots are the seeds' own out-edges
+        assert slots1 - slots0 >= rl[nodes].sum() if pushes \
+            else slots1 == slots0
     assert out_csr_spans == ["batch.build_ell", "batch.upload_ell"]
     assert _link_dev(store).out is not None
 

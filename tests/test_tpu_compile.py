@@ -14,6 +14,27 @@ import pytest
 
 N, E, W = 1303125, 44919214, 2      # follower-tw2010-32nd, 64 lanes
 
+# The three cells' relations as a pull sees them (PERF.md §4; the slots to
+# the nearest 0.1 M): rows, list slots, the hub block. push_caps of each
+# is the caps its programs are compiled at on the chip.
+CELLS = {"follower": (N, 33_800_000, (768256, 2048)),
+         "graph500": (2395982, 51_800_000, (32640, 64896)),
+         "knows": (633432, 70_190_000, None)}
+
+
+def _cell_caps(cell):
+    """(rows, push_caps) of a cell's relation, from a DeviceEll of shapes
+    alone: push_caps reads sizes, not data."""
+    from dgraph_tpu.ops import bfs
+    n, slots, block = CELLS[cell]
+    shape = jax.ShapeDtypeStruct
+    dev = bfs.DeviceEll(
+        n=n, parts=[("ell", shape((slots // 8, 8), jnp.int32), slots // 8)],
+        tiles=None, lvl2=[], seg_rows=0,
+        dense=block and (shape(block, jnp.int8),
+                         shape(block[1:], jnp.int32)))
+    return n, bfs.push_caps(dev)
+
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -32,17 +53,24 @@ def _compiled(one_chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-def test_pushed_hop_compiles_for_the_v5e_without_a_sort(one_chip):
+@pytest.mark.parametrize("cell,edges,slot_cap", [
+    ("follower", E, 1_700_000), ("knows", 68371494, 3_500_000)])
+def test_pushed_hop_compiles_for_the_v5e_without_a_sort(one_chip, cell,
+                                                        edges, slot_cap):
+    """At the caps of the follower cell's relation and of the `knows`
+    one's, the break-even of each one's pull (PR 44)."""
     from dgraph_tpu.ops import bfs
-    f_cap, _e_cap, chunk = bfs.push_caps(E)
+    n, (f_cap, e_cap, chunk) = _cell_caps(cell)
+    assert abs(e_cap - slot_cap) < 100_000 and chunk == bfs.PUSH_CHUNK
+    assert f_cap == e_cap // bfs.PUSH_FANOUT
 
     def push(indptr, indices, deg, frontier, act):
-        return bfs._push_hop((indptr, indices, deg), frontier, act, N, W,
+        return bfs._push_hop((indptr, indices, deg), frontier, act, n, W,
                              jnp.uint32, 32, f_cap, chunk)
 
-    c = _compiled(one_chip, push, ((N + 1,), jnp.int32), ((E,), jnp.int32),
-                  ((N,), jnp.int32), ((N + 1, W), jnp.uint32),
-                  ((N,), jnp.bool_))
+    c = _compiled(one_chip, push, ((n + 1,), jnp.int32),
+                  ((edges,), jnp.int32), ((n,), jnp.int32),
+                  ((n + 1, W), jnp.uint32), ((n,), jnp.bool_))
     hlo = c.as_text()
     assert " sort(" not in hlo
     assert " scatter(" in hlo                    # the text is the HLO's
@@ -50,12 +78,19 @@ def test_pushed_hop_compiles_for_the_v5e_without_a_sort(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-@pytest.mark.parametrize("cap", [64, 8192, 65536])
+@pytest.mark.parametrize("cap", [64, 8192, 65536, "graph500", "knows"])
 def test_set_rows_compiles_for_the_v5e_without_a_sort(one_chip, cap):
+    """At a few caps over the follower graph's rows, and at the row caps
+    of the two cells whose caps are larger (83 K and 111 K rows)."""
     from dgraph_tpu.ops import bfs
-    c = _compiled(one_chip, lambda act: bfs._set_rows(act, N, cap),
-                  ((N,), jnp.bool_))
+    n = N
+    if not isinstance(cap, int):
+        n, (cap, _slots, _turn) = _cell_caps(cap)
+    c = _compiled(one_chip, lambda act: bfs._set_rows(act, n, cap, 4096),
+                  ((n,), jnp.bool_))
     assert " sort(" not in c.as_text()
+    # a turn's rows' flags as float32 and their prefix sums, not the cap's
+    assert c.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 @pytest.mark.parametrize("weighted", [False, True])
@@ -86,7 +121,7 @@ def test_tree_program_with_the_push_compiles_for_the_v5e(one_chip):
     import numpy as np
 
     from dgraph_tpu.ops import bfs
-    n, edges, dense = 2395982, 65242600, 1 << 20
+    edges, dense = 65242600, 1 << 20
     described = {}
 
     def standin(*shape):
@@ -97,7 +132,7 @@ def test_tree_program_with_the_push_compiles_for_the_v5e(one_chip):
                                                 sharding=one_chip)
         return a
 
-    caps = bfs.push_caps(edges)
+    n, caps = _cell_caps("graph500")
     assert caps[2] == bfs.PUSH_CHUNK
     stage = {"kind": "recurse",
              "prepared": {"parts": [("chain", standin(dense, 4), dense),

@@ -756,6 +756,16 @@ def test_arm_creates_at_zero_what_no_request_feeds(armed):
     for thread in tracing.BACKGROUND_THREADS:
         assert (f'dgraph_tpu_background_ticks_total{{thread="{thread}"}} '
                 in rendered)
+    # the launches' own counts: the look-ahead's (PR 41) and the slots of
+    # the pushed hops (PR 44), which a window of pulls alone leaves at 0
+    for by in ("seed", "seed2", "ahead", "ahead2", "exhausted"):
+        assert ('dgraph_tpu_kernel_lanes_closed_total{by="%s",'
+                'family="shortest"} ' % by in rendered)
+    for name in ("near2_edges", "near2_capped"):
+        assert f"dgraph_tpu_kernel_{name}_total " in rendered
+    for family in ("shortest", "tree"):
+        assert ('dgraph_tpu_kernel_push_slots_total{family="%s"} '
+                % family in rendered)
     import gc
     assert gc.callbacks.count(tracing._gc_hook) == 1
     tracing.arm()                        # twice is once
