@@ -13,7 +13,7 @@ import sys
 import numpy as np
 import pytest
 
-from conftest import BENCH, HERE, ROOT
+from conftest import BENCH, HERE, ROOT, holds_entries
 from generators import graph500 as gen
 from harness.server import parse_prom
 from readers import lane_hop_roofline, prom_ratio
@@ -145,13 +145,7 @@ def test_the_traffic_is_the_same_places_under_every_seed(data):
 def test_the_cell_s_entries_are_what_the_benchmark_holds():
     bench = load(ROOT, "BENCHMARK.json")
     ent = load(HERE, "data", CELL + ".entries.json")
-    for group in ("configs", "workloads", "per_layer"):
-        for e in ent[group]:
-            assert e in bench[group]
-    assert bench["per_layer"][-4:] == ent["per_layer"]
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if m["name"] in ent["also_in"]:
-            assert m["workloads"][-1] == CELL
+    holds_entries(bench, ent, CELL)
     cfg = load(ROOT, ent["configs"][0]["file"])
     assert cfg["source"] == ent["configs"][0]["source"]
     assert len(cfg["source"]) <= 200 and cfg["reduced"] == []
@@ -268,8 +262,8 @@ def test_a_traced_run_reports_the_per_layer_metrics():
     want = set(load(HERE, "data", CELL + ".rehearsal.json")["per_layer"])
     assert want <= set(out["metrics"]) <= names
     # the device's readers find no device plane on a CPU
-    assert names - want == {"device_ms_per_query.batch",
-                            "lane_tree_roofline.batch"}
+    assert {"device_ms_per_query.batch", "lane_tree_roofline.batch"} <= (
+        names - set(out["metrics"]))
     m = {k: v["value"] for k, v in out["metrics"].items()}
     assert m["tree_queries_per_launch.batch"] == 64
     assert m["tree_device_count_share.batch"] == 100

@@ -177,21 +177,58 @@ def test_structure_seed_fixes_the_shapes_and_the_seed_the_labels():
     assert len(np.unique(c["knows"], axis=0)) == len(c["knows"])
 
 
-def test_every_run_asks_for_the_same_places_dealt_another_way():
+@pytest.mark.parametrize("draw", [None, 1, 2])
+def test_every_run_asks_for_the_same_places_dealt_another_way(draw):
+    """No `draw_requests`: the same set of places over the call, grouped
+    another way a seed. A draw of d requests: the same set d requests at a
+    time, so with 1 (the mix as it stands in `traffic/`) request i holds
+    the same places in every run, on other lanes."""
     p = {"nodes": 5000, "mean_out_degree": 35, "structure_seed": 4}
+    params = PAIRS if draw is None else {**PAIRS, "draw_requests": draw}
     places = []
     for seed in (1, 2):
         data = follower.generate(p, seed)
-        mix = shortest_pairs.make(data, PAIRS, seed)
+        mix = shortest_pairs.make(data, params, seed)
         place_of = np.argsort(data["node_of_structure"])
         places.append([[(int(place_of[m["a"] - 1]), int(place_of[m["b"] - 1]))
-                        for m in r["meta"]] for r in mix.requests(3)])
-    # another batch for the same place in the queue, the same set in all
+                        for m in r["meta"]] for r in mix.requests(4)])
+    # another lane order for the same place in the queue in every case
     assert places[0][1] != places[1][1]
-    assert sorted(sum(places[0], [])) == sorted(sum(places[1], []))
-    # and another draw of requests shares nothing with it
-    other = shortest_pairs.make(data, PAIRS, 2).requests(3, stream=1)
-    assert other[0]["body"] != mix.requests(3)[0]["body"]
+    sets = [[sorted(sum(reqs[i:i + (draw or 4)], []))
+             for i in range(0, 4, draw or 4)] for reqs in places]
+    assert sets[0] == sets[1]
+    # and no smaller group of requests holds the same places in both
+    if draw != 1:
+        assert sorted(places[0][0]) != sorted(places[1][0])
+    # another draw of requests shares nothing with it
+    other = shortest_pairs.make(data, params, 2).requests(4, stream=1)
+    assert not set(sum(places[1], [])) & {
+        (int(place_of[m["a"] - 1]), int(place_of[m["b"] - 1]))
+        for r in other for m in r["meta"]}
+    if draw == 1:
+        with open(os.path.join(BENCH, "traffic", "shortest-batch.json")) as f:
+            assert json.load(f) == {
+                "kind": "shortest_pairs", "endpoint": "/query/batch",
+                "loop": "closed", "clients": 1, "batch": 64,
+                "draw_requests": 1, "warm_requests": 4,
+                "schedule_seed": 20260927}
+
+
+def test_without_the_key_the_deal_is_the_one_it_always_was():
+    """The tests' use of the kind (no `draw_requests`) sends what it sent
+    before the key existed: one permutation of the call's pairs."""
+    p = {"nodes": 5000, "mean_out_degree": 35, "structure_seed": 4}
+    data = follower.generate(p, 7)
+    mix = shortest_pairs.make(data, PAIRS, 7)
+    for count, stream in ((3, 0), (16, 1100)):
+        rng = np.random.default_rng([PAIRS["schedule_seed"], stream])
+        a, b = mix._pairs(rng, count * 64)
+        deal = np.random.default_rng([7, 3, stream]).permutation(count * 64)
+        want = [(int(x), int(y)) for x, y in zip(a[deal], b[deal])]
+        got = [(m["a"], m["b"]) for r in mix.requests(count, stream)
+               for m in r["meta"]]
+        assert got == want
+    assert mix.requests(0) == []
 
 
 # -- the tree of first visits (`references/recurse_tree.py`) ----------------

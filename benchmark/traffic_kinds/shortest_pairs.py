@@ -6,10 +6,19 @@ ride in one request, a JSON list to `/query/batch`. Every request carries
 pairs never sent before.
 
 The pairs are places in the graph's structure, drawn from the mix's
-`schedule_seed`, 16 requests' worth at a time; the run's seed deals each
-such draw into its requests and names the places (the uids). So every run
-asks for the same set of paths, which pairs ride together and in which
-order differs from seed to seed, and how far apart a pair is does not.
+`schedule_seed` and the call's `stream`, as many as the call's requests
+hold; the run's seed deals them into requests and names the places (the
+uids). With `draw_requests` in the traffic the deal stays within each
+draw of that many requests and never crosses two: with a draw of one
+request every run sends the same requests in the same order, and the seed
+picks which lane a pair rides and what everyone is called. Without the
+key the deal is over all the pairs of the call (`run.py` calls for
+`CHUNK` requests at a time): every run asks for the same set of paths,
+grouped another way a seed. How far apart a pair is does not depend on
+the seed either way; which pairs ride together sets what a launch costs
+(its farthest pair its hops, the union of its frontiers whether a hop is
+pushed), which is why the cell fixes the groups (`khop_seeds` and
+`recurse_roots` have the reasons for a draw of one request).
 """
 
 from __future__ import annotations
@@ -28,6 +37,8 @@ class Mix:
     def __init__(self, data: dict, params: dict, seed: int):
         self.n = int(data["n_nodes"])
         self.batch = int(params["batch"])
+        # pairs a deal may cross: a draw's, or (no key) all of a call's
+        self.draw = int(params.get("draw_requests", 0)) * self.batch
         self.params, self.seed = params, seed
         self.node_of = np.asarray(data["node_of_structure"], np.int64)
         # places of the structure that follow someone
@@ -44,10 +55,13 @@ class Mix:
     def requests(self, count: int, stream: int = 0) -> list:
         rng = np.random.default_rng(
             [int(self.params["schedule_seed"]), stream])
-        a, b = self._pairs(rng, count * self.batch)
-        deal = np.random.default_rng([self.seed, 3, stream]).permutation(
-            count * self.batch)
-        a, b = a[deal], b[deal]
+        total = count * self.batch
+        a, b = self._pairs(rng, total)
+        deal = np.random.default_rng([self.seed, 3, stream])
+        cuts = list(range(0, total, self.draw or max(total, 1))) + [total]
+        for lo, hi in zip(cuts, cuts[1:]):
+            lanes = lo + deal.permutation(hi - lo)
+            a[lo:hi], b[lo:hi] = a[lanes], b[lanes]
         out = []
         for i in range(count):
             sl = slice(i * self.batch, (i + 1) * self.batch)
