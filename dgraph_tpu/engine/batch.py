@@ -7,11 +7,16 @@ structurally-compatible `@recurse` queries into the bit-lanes of
 batch (the north-star kernel, reached from the SERVING path, not just
 the bench). Ineligible queries fall back to the per-query engine.
 
-Three kernel families ride the lanes (PR 7 widened the set):
+Four kernel families ride the lanes (PR 7 widened the set, PR 46 added
+the last):
   * unfiltered single-block @recurse — the dedicated recurse path here;
   * level trees / filtered recurse / var chains — engine/treebatch.py;
-  * unweighted `shortest` blocks (LDBC IC13/IC14 shapes) — lane-BFS with
-    host walk-back, staged through donated mask buffers (this module).
+  * unweighted `shortest` blocks (LDBC IC13 shapes) — lane-BFS with
+    host walk-back, staged through donated mask buffers (this module);
+  * facet-weighted `shortest` blocks (the literal IC14, `knows
+    @facets(weight)`) — a lane program that carries an integer distance a
+    lane and node and relaxes it over the in-edge lists, one launch a
+    batch, with a host walk-back over tight edges (_run_weighted_batch).
 
 Batch PLANS are memoized by (schema fingerprint, query texts) riding
 utils/jitcache.Memo: a repeated query template skips parsing and
@@ -21,6 +26,8 @@ snapshot.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 
@@ -70,6 +77,16 @@ SHORTEST_STAGE = 8
 # 2,752 read 54,997; the 17 lanes of all 5,120 over the constant
 # (70,393 to 377,837 in-edges) were 2 to 4 edges apart.
 NEAR2_MAX_EDGES = 65536
+# the weighted lane program (ops/bfs.py make_ell_relax) runs at most this
+# many rounds for every edge the block's depth allows: `depth` rounds to
+# reach a target and as many past it, for a cheaper path of more edges. A
+# lane still open then is walked on the host (counted, reason "rounds"),
+# so the cap bounds one uninterruptible dispatch and the distance type,
+# never an answer.
+RELAX_ROUNDS_PER_DEPTH = 2
+# distances the walk-back reads from the device a gather: (row, lane)
+# pairs, padded to this many, so one compiled gather serves every step
+WALK_FETCH = 1 << 14
 
 
 class _BatchPlan:
@@ -81,14 +98,17 @@ class _BatchPlan:
 
 
 class _ShortestPlan:
-    """One shortest-path kernel group: same predicate/direction/depth
-    cap/numpaths/weight bounds across the batch; per-query (blocks,
-    shortest block index, src uid, dst uid)."""
+    """One shortest-path kernel group: same family ("shortest": hops
+    count; "weighted": one integer facet's values do), predicate,
+    direction, depth cap, numpaths and weight bounds across the batch;
+    per-query (blocks, shortest block index, src uid, dst uid)."""
 
     def __init__(self, sig, items):
         self.sig = sig
-        (_tag, self.attr, self.reverse, self.depth, self.k,
-         self.minw, self.maxw, self.first_visit) = sig
+        (self.family, self.attr, self.reverse, self.depth, self.k,
+         self.minw, self.maxw, self.first_visit, *facet) = sig
+        # the weighted family's signature ends in its facet key
+        self.weight_key = facet[0] if facet else None
         self.queries = [blocks for blocks, _bi, _s, _d in items]
         self.block_idx = [bi for _b, bi, _s, _d in items]
         self.src_uids = [s for _b, _bi, s, _d in items]
@@ -130,14 +150,16 @@ def _eligible_shortest(store, blocks):
     """(signature, (blocks, shortest block idx, src uid, dst uid)) when
     the query's `shortest` block fits the lane-BFS kernel, else None.
 
-    Kernel-eligible shapes: UNWEIGHTED shortest over exactly one edge
-    predicate, no filters/facets on the edge, and a reverse CSR
-    available for the host walk-back (path reconstruction follows
-    in-edges of the found levels). numpaths == 1 rides the first-visit
-    BFS; numpaths > 1 / weight bounds ride the level-DAG variant.
-    Facet-weighted relaxation (the literal IC14 `@facets(weight)`)
-    stays on the host path — the batched Bellman-Ford kernel is the
-    ROADMAP follow-on."""
+    Kernel-eligible shapes: shortest over exactly one edge predicate,
+    no filters on the edge, and a reverse CSR available for the host
+    walk-back (path reconstruction follows in-edges). Unweighted,
+    numpaths == 1 rides the first-visit BFS; numpaths > 1 / weight
+    bounds ride the level-DAG variant. Facet-weighted (the literal IC14
+    `knows @facets(weight)`), the block rides the weighted family, a
+    signature of its own that ends in the facet key, where
+    weighted_refusal finds nothing against it; else it stays on the host
+    (engine/shortest.py _weighted_shortest, which counts it under
+    `weighted_host_fallbacks_total{reason=}`)."""
     from dgraph_tpu.engine.shortest import MAX_PATH_DEPTH
 
     sidx = [i for i, b in enumerate(blocks) if b.shortest is not None]
@@ -146,9 +168,15 @@ def _eligible_shortest(store, blocks):
     bi = sidx[0]
     sg = blocks[bi]
     a = sg.shortest
-    if a.weight_facet:
-        return None
     edge_sgs = [c for c in sg.children if _expands(store, c)]
+    if any(c.facet_keys for c in edge_sgs):
+        if weighted_refusal(store, sg) is not None:
+            return None
+        e = edge_sgs[0]
+        sig = ("weighted", e.attr, e.is_reverse,
+               a.depth or MAX_PATH_DEPTH, 1, float("-inf"), float("inf"),
+               False, e.facet_keys[0][1])
+        return sig, (blocks, bi, a.from_uid, a.to_uid)
     if len(edge_sgs) != 1:
         return None
     e = edge_sgs[0]
@@ -179,6 +207,64 @@ def _eligible_shortest(store, blocks):
     return sig, (blocks, bi, a.from_uid, a.to_uid)
 
 
+def weighted_refusal(store, sg) -> str | None:
+    """Why the weighted lane family does not take this facet-weighted
+    `shortest` block, as the `reason` of
+    `weighted_host_fallbacks_total`; None where it does. The family
+    answers ONE cheapest path over one edge block weighted by one integer
+    facet, exactly: Yen's spurs (`numpaths` > 1) and bounded counting
+    stay on the host, and so does a column the integer distances cannot
+    stand for (a float or a string among the values, a negative weight,
+    costs past int32 within the block's rounds). The hub block has no
+    min-plus product and takes edges out of the lists, so a relation
+    whose ELL holds one is left to the host (no admitted configuration's
+    weighted relation has one: LDBC's `knows` gets none, ops/bfs.py)."""
+    from dgraph_tpu.engine.shortest import MAX_PATH_DEPTH
+    from dgraph_tpu.ops.bfs import relax_dtype
+
+    a = sg.shortest
+    edge_sgs = [c for c in sg.children if _expands(store, c)]
+    if len(edge_sgs) != 1:
+        return "edge_blocks"
+    e = edge_sgs[0]
+    if len(e.facet_keys or ()) != 1:
+        return "facet_keys"
+    if (e.filters is not None or e.facet_filter is not None
+            or e.facet_orders or e.children or e.first or e.offset
+            or e.after or e.orders or e.var_name or e.lang):
+        return "edge_args"
+    if a.numpaths > 1:
+        return "numpaths"
+    if a.minweight > float("-inf") or a.maxweight < float("inf"):
+        return "bounds"
+    depth = a.depth or MAX_PATH_DEPTH
+    if depth < 1 or depth > MAX_KERNEL_DEPTH:
+        return "depth"
+    try:
+        if not (store.rel(e.attr, False).nnz
+                and store.rel(e.attr, True).nnz):
+            return "no_edges"                # walk-back needs in-edges
+        pd = store.preds.get(e.attr)
+        col = pd.efacets.get(e.facet_keys[0][1]) if pd else None
+    except Exception:  # noqa: BLE001 — foreign/routed tablet miss
+        return "no_edges"
+    least, largest = 1, 1                    # an edge without the facet
+    if col is not None and len(col.pos):
+        span = col.int_range()
+        if span is None:
+            return "facet_type"
+        least, largest = span[0], max(span[1], 1)
+    if least < 0:
+        return "negative"
+    if relax_dtype(largest, RELAX_ROUNDS_PER_DEPTH * depth) is None:
+        return "range"
+    g = (getattr(_cache_host(store, e.attr, e.is_reverse), "_ell_cache",
+                 None) or {}).get((e.attr, e.is_reverse))
+    if g is not None and g.dense is not None:
+        return "hub_block"
+    return None
+
+
 def plan_batch(store, queries_blocks):
     """Inspect parsed queries; a plan comes back only when EVERY query
     fits one lane-kernel launch (the homogeneous fast path)."""
@@ -195,10 +281,12 @@ def plan_batch_groups(store, queries_blocks):
     one incompatible query no longer disables the kernel for the rest
     (reference: the per-goroutine mix, served batch-wise here).
 
-    Three kernel families: unfiltered single-block @recurse takes the
+    Four kernel families: unfiltered single-block @recurse takes the
     dedicated recurse path (`_BatchPlan`, no permutation translation);
-    unweighted `shortest` blocks take the staged lane-BFS
-    (`_ShortestPlan`); everything else — filtered recurse, nested level
+    unweighted `shortest` blocks take the staged lane-BFS and
+    facet-weighted ones the relaxing lane program (`_ShortestPlan`, one
+    group a signature: a weighted block never shares a launch with an
+    unweighted one); everything else — filtered recurse, nested level
     trees, multi-block var chains — tries the level-tree planner
     (engine/treebatch.py)."""
     from dgraph_tpu.engine.treebatch import TreePlan, plan_tree
@@ -230,7 +318,7 @@ def plan_batch_groups(store, queries_blocks):
                                      sig[0], sig[1], sig[2]),
                           [i for i, _ in items]))
     for sig, items in sp_groups.items():
-        if not _kernel_worth(f"shortest:{sig[1]}~d{sig[3]}",
+        if not _kernel_worth(f"{sig[0]}:{sig[1]}~d{sig[3]}",
                              len(items)):
             leftover.extend(i for i, _ in items)
         else:
@@ -274,7 +362,7 @@ def _plan_shape(plan) -> str:
     key."""
     from dgraph_tpu.engine.treebatch import TreePlan
     if isinstance(plan, _ShortestPlan):
-        return f"shortest:{plan.attr}~d{plan.depth}"
+        return f"{plan.family}:{plan.attr}~d{plan.depth}"
     if isinstance(plan, TreePlan):
         return f"tree:*~d{len(plan.stages)}"
     return f"recurse:{plan.attr}~d{plan.depth}"
@@ -404,7 +492,9 @@ def run_batch(store, plan, device_threshold: int) -> list:
     if isinstance(plan, TreePlan):
         return run_tree_batch(store, plan, device_threshold)
     if isinstance(plan, _ShortestPlan):
-        return _run_shortest_batch(store, plan, device_threshold)
+        run = (_run_weighted_batch if plan.weight_key is not None
+               else _run_shortest_batch)
+        return run(store, plan, device_threshold)
 
     from dgraph_tpu.ops.bfs import pack_seed_masks
 
@@ -608,8 +698,6 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
     bit-identical to engine/shortest.py's per-query loop, asserted by
     tests/test_batch.py against LDBC IC13/IC14 shapes."""
     import jax
-
-    from dgraph_tpu.engine.varorder import execution_order
 
     levels: list[np.ndarray] = []      # [n+1, W] per hop, permuted space
     with tracing.span("batch.seed", phase=True,
@@ -824,6 +912,17 @@ def _run_shortest_batch(store, plan: _ShortestPlan,
                                      int(src[q]), int(dst[q]), q,
                                      ahead2.get(q))
                  for q in range(B)]
+    return _render_shortest(store, plan, datas, device_threshold)
+
+
+def _render_shortest(store, plan: _ShortestPlan, datas: list,
+                     device_threshold: int):
+    """Each query of a shortest group rendered around its lane's PathData:
+    the shortest block binds its var, the other blocks run on the host
+    after it. None where a query's blocks have no order to run in."""
+    from dgraph_tpu.engine.varorder import execution_order
+
+    B = len(plan.queries)
     with tracing.span("batch.render", phase=True, queries=B):
         out = []
         for q in range(B):
@@ -996,6 +1095,331 @@ def _shortest_path_data(store, plan, g, rrel, levels, src: int,
     return data
 
 
+# -- weighted shortest: the relaxing lane program -----------------------------
+
+def _run_weighted_batch(store, plan: _ShortestPlan, device_threshold: int):
+    """Execute one weighted kernel group: every lane's source at cost 0,
+    ONE launch that relaxes integer distances over the relation's in-edge
+    lists until every lane's target is settled (ops/bfs.py
+    make_ell_relax), then each lane's path walked back on the host over
+    tight edges, reading from the device only the distances of the
+    in-neighbours of the path's own nodes — byte-identical to
+    engine/shortest.py's host relaxation (tests/test_weighted_lanes.py).
+    None (the per-query host route answers) where the relation turns out
+    to have a hub block or weights the family does not take."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import relax_dtype
+
+    B = len(plan.queries)
+    dist = cost = None
+    open_ = ()
+    with tracing.span("batch.seed", phase=True, queries=B) as sp:
+        g, _dev = _dev_for(store, plan.attr, plan.reverse)
+        rrel = store.rel(plan.attr, not plan.reverse)
+        if g is None or rrel.nnz == 0 or g.dense is not None:
+            return None
+        wts = _weights_for(store, plan.attr, plan.reverse, plan.weight_key)
+        rounds_cap = RELAX_ROUNDS_PER_DEPTH * plan.depth
+        # (dtype, INF) of the distances; None: costs past int32
+        dist_type = wts and relax_dtype(wts.largest, rounds_cap)
+        if not dist_type:
+            return None
+        n = g.n
+        src = store.rank_of(np.asarray(plan.src_uids, np.int64))
+        dst = store.rank_of(np.asarray(plan.dst_uids, np.int64))
+        lanes = _lane_count(B)
+        # lanes needing the program at all: known endpoints, src != dst
+        active = np.zeros(lanes, bool)
+        active[:B] = (src >= 0) & (dst >= 0) & (src != dst)
+        rows = np.full((2, lanes), n, np.int32)
+        rows[0, :B][active[:B]] = g.new_of_old[src[active[:B]]]
+        rows[1, :B][active[:B]] = g.new_of_old[dst[active[:B]]]
+        sp.attrs.update(lanes=lanes, active=int(active.sum()))
+        if active.any():
+            relax, lkey = _relax_for(store, plan, wts, lanes, rounds_cap,
+                                     dist_type)
+            deadline.checkpoint("kernel")
+            METRICS.inc("kernel_group_launches_total", family="weighted")
+            METRICS.inc("kernel_group_queries_total", float(B),
+                        family="weighted")
+            METRICS.inc("kernel_padded_lanes_total", float(lanes - B),
+                        family="weighted")
+            _note_kernel_features(plan.attr, "weighted", lanes, lanes - B,
+                                  plan.depth, B)
+            costprofile.note_max("bucket_mix", len(g.parts))
+    if active.any():
+        with tracing.span("batch.shortest_kernel", attr=plan.attr,
+                          depth=plan.depth, queries=B, lanes=lanes,
+                          padded_lanes=lanes - B,
+                          weight=plan.weight_key) as ksp:
+
+            def _launch():
+                memgov.check_alloc_fault("bfs.ell_relax")
+                with jit_call("bfs.ell_relax", lkey):
+                    return relax(rows[0], rows[1], active)
+
+            with tracing.span("batch.device_wait", phase=True) as sp:
+                # nothing is donated: an allocation failure evicts and
+                # retries once, then sticky-degrades this launch shape
+                dist, rounds, open_, cost = memgov.oom_retry(
+                    "bfs.ell_relax", lkey, _launch)
+                # the dispatch returns at once: the span ends when the
+                # device says how many rounds it ran
+                rounds = int(jax.device_get(rounds))
+                sp.attrs["rounds"] = rounds
+            with tracing.span("batch.fetch", phase=True) as sp:
+                open_, cost = jax.device_get((open_, cost))
+                open_ = np.flatnonzero(open_[:B]).tolist()
+                sp.attrs["bytes"] = int(cost.nbytes + lanes)
+        METRICS.inc("kernel_relax_rounds_total", float(rounds),
+                    family="weighted")
+        # a pulled round reads every stored slot, padding included
+        METRICS.inc("kernel_relaxed_slots_total",
+                    float(rounds * g.padded_edges), family="weighted")
+        costprofile.add_kernel("weighted", execute_us=ksp.dur_us)
+        costprofile.add_tablet_cost(plan.attr, ksp.dur_us)
+        costprofile.add("bytes_gathered", rounds * g.padded_edges * (
+            4 + wts.width + lanes * dist.dtype.itemsize))
+        note_pulls(g, "weighted", rounds)
+
+    with tracing.span("batch.walk_back", phase=True, queries=B) as sp:
+        datas = _weighted_path_datas(store, plan, g, rrel, wts, dist,
+                                     src, dst, cost, dist_type[1], open_,
+                                     device_threshold, sp)
+    return _render_shortest(store, plan, datas, device_threshold)
+
+
+def _tight_walk(src: int, dst: int, cost: int):
+    """One lane's walk back from its target, as engine/shortest.py
+    _weighted_one's `walk` over the tight DAG: the FIRST simple path, a
+    node's tight parents taken in ascending rank and a parent already on
+    the path passed over (zero-weight edges can close a cycle of tight
+    edges). A generator: it yields (rank, that node's distance) whenever
+    it needs a node's tight parents, is sent them as [(rank, distance)],
+    and returns the path's ranks from source to target, or None."""
+    path, on_path = [(dst, cost)], {dst}
+    pending, known = [], {}
+    # graftlint: allow(hot-loop-checkpoint): a turn adds or drops a path
+    # node; the caller's loop of steps holds the checkpoint
+    while path:
+        v, dv = path[-1]
+        if len(pending) < len(path):         # v has just joined the path
+            if v == src:                     # no edge into src is tight
+                break
+            if v not in known:
+                known[v] = yield v, dv
+            pending.append(iter(known[v]))
+        nxt = next((p for p in pending[-1] if p[0] not in on_path), None)
+        if nxt is None:                      # every parent is on the path
+            pending.pop()
+            on_path.discard(path.pop()[0])
+        else:
+            path.append(nxt)
+            on_path.add(nxt[0])
+    return [r for r, _d in reversed(path)] or None
+
+
+def _weighted_path_datas(store, plan, g, rrel, wts, dist, src, dst, cost,
+                         inf: int, open_, device_threshold: int,
+                         sp) -> list:
+    """Every lane's PathData from the program's distances. The lanes walk
+    back in step: a step gathers, in one device call, the distances of
+    the in-neighbours of every walking lane's current node (their rows
+    and lanes as pairs, WALK_FETCH a call) and hands each lane its tight
+    parents, in-edges u → v with dist[u] + w(u, v) == dist[v]. A path of
+    h edges takes h steps, and reads a few dozen in-rows a lane, never
+    the column. A lane the program left open (`open_`) is walked whole on
+    the host, and counted."""
+    from dgraph_tpu.engine.execute import csr_rows
+    from dgraph_tpu.engine.shortest import PathData, shortest_path
+    from dgraph_tpu.ops.bfs import gather_pairs
+
+    B = len(plan.queries)
+    datas = []
+    for q in range(B):
+        sg = plan.queries[q][plan.block_idx[q]]
+        datas.append(PathData(edge_sgs=[c for c in sg.children
+                                        if _expands(store, c)]))
+    for q in open_:
+        ex = Executor(store, device_threshold=device_threshold)
+        datas[q] = shortest_path(
+            ex, plan.queries[q][plan.block_idx[q]], lane_refusal="rounds")
+    paths = {q: [int(src[q])] for q in range(B)
+             if src[q] >= 0 and src[q] == dst[q]}
+    walkers, asks = {}, {}
+    for q in range(B):
+        if cost is not None and q not in open_ and q not in paths \
+                and src[q] >= 0 and dst[q] >= 0 and cost[q] < inf:
+            walkers[q] = _tight_walk(int(src[q]), int(dst[q]), int(cost[q]))
+            asks[q] = next(walkers[q])
+    steps = fetched = 0
+    while asks:
+        deadline.checkpoint("bfs")
+        steps += 1
+        lanes_q = list(asks)
+        at_nodes = np.array([asks[q][0] for q in lanes_q], np.int64)
+        nbrs, seg, pos = csr_rows(rrel, at_nodes)
+        du = np.empty(len(pos), np.int64)
+        rows_all = g.new_of_old[nbrs].astype(np.int32)
+        lane_all = np.asarray(lanes_q, np.int32)[seg]
+        for at in range(0, len(pos), WALK_FETCH):
+            part = slice(at, at + WALK_FETCH)
+            rows = np.full(WALK_FETCH, g.n, np.int32)
+            lane = np.zeros(WALK_FETCH, np.int32)
+            size = len(rows_all[part])
+            rows[:size], lane[:size] = rows_all[part], lane_all[part]
+            with jit_call("bfs.relax_gather",
+                          (g.n, dist.shape[1], str(dist.dtype))):
+                du[part] = np.asarray(gather_pairs(dist, rows, lane))[:size]
+        fetched += len(pos)
+        tight = du + wts.w_in[pos] == np.array(
+            [asks[q][1] for q in lanes_q], np.int64)[seg]
+        # the edges are grouped by lane, as `lanes_q` lists them
+        cuts = np.searchsorted(seg, np.arange(len(lanes_q) + 1)).tolist()
+        for q, a, b in zip(lanes_q, cuts, cuts[1:]):
+            # ascending and distinct, as the host's parent lists are
+            ranks, first = np.unique(nbrs[a:b][tight[a:b]],
+                                     return_index=True)
+            parents = list(zip(ranks.tolist(),
+                               du[a:b][tight[a:b]][first].tolist()))
+            try:
+                asks[q] = walkers[q].send(parents)
+            except StopIteration as done:
+                del asks[q]
+                if done.value:
+                    paths[q] = done.value
+    sp.attrs.update(steps=steps, distances=fetched, host_lanes=len(open_))
+    for q, ranks in paths.items():
+        datas[q].paths = [[(ranks[0], -1)] + [(r, 0) for r in ranks[1:]]]
+        datas[q].weights = [float(cost[q]) if len(ranks) > 1 else 0.0]
+        datas[q].nodes = np.unique(np.array(ranks, np.int32))
+    return datas
+
+
+@dataclasses.dataclass
+class _RelaxWeights:
+    """One relation's facet as the weighted lane program reads it: `w_in`
+    the weights by IN-edge (aligned to the reverse CSR's positions, for
+    the walk-back), `dev` ops/bfs.py ell_weights' blocks on the device,
+    `largest` the largest weight."""
+
+    w_in: np.ndarray
+    dev: tuple
+    largest: int
+
+    @property
+    def width(self) -> int:
+        """The bytes a stored weight takes."""
+        return int(self.w_in.dtype.itemsize)
+
+
+def _facet_by_in_edge(store, attr: str, reverse: bool, wkey: str):
+    """The facet `wkey` of every stored edge of the relation, by IN-edge:
+    aligned to the positions of store.rel(attr, not reverse), whose rows
+    list a node's in-neighbours ascending. An edge without the facet
+    weighs 1 (engine/shortest.py _edge_weights). In the narrowest
+    unsigned type that holds the largest. None where the column is not
+    one of non-negative integers (weighted_refusal names which)."""
+    rel = store.rel(attr, reverse)
+    pd = store.preds.get(attr)
+    col = pd.efacets.get(wkey) if pd is not None else None
+    vals, largest = None, 1
+    if col is not None and len(col.pos):
+        vals = col.int_values()
+        if vals is None or col.int_range()[0] < 0:
+            return None
+        largest = max(col.int_range()[1], 1)
+    wdt = next((dt for dt in (np.uint8, np.uint16, np.uint32)
+                if largest <= np.iinfo(dt).max), None)
+    if wdt is None:
+        return None
+    # the facet lives on the forward posting, by forward position
+    w = np.ones(pd.fwd.nnz if pd is not None and pd.fwd is not None
+                else rel.nnz, wdt)
+    if vals is not None:
+        w[col.pos] = vals
+    if reverse:
+        w = w[store.rev_to_fwd_pos(attr, np.arange(rel.nnz))]
+    # the relation's transpose, sources ascending within a target: the
+    # order of the reverse CSR's rows, and of build_ell's lists
+    return w[np.argsort(rel.indices, kind="stable")]
+
+
+def _weights_for(store, attr: str, reverse: bool, wkey: str):
+    """_RelaxWeights per (snapshot, pred, dir, facet key), built beside
+    the ELL whose slots they are aligned to, uploaded once, carried by
+    carry_kernel_caches with it and dropped with it on a write to the
+    relation. None (cached too) where the column does not qualify."""
+    import jax
+
+    from dgraph_tpu.ops.bfs import ell_weights
+
+    host = _cache_host(store, attr, reverse)
+    # beside the DeviceEll in the cache `batch.ell_dev` governs, under a
+    # key one longer: (pred, dir, facet key)
+    key = (attr, reverse, wkey)
+    cache = getattr(host, "_ell_devs", None)
+    if cache is not None and key in cache:  # hot path: no lock
+        return cache[key]
+    g, _dev = _dev_for(store, attr, reverse)    # takes the lock itself
+    with _cache_lock:
+        cache = host._ell_devs
+        if key not in cache:
+            with tracing.span("batch.build_ell", phase=True, pred=attr,
+                              reverse=reverse, part="weights"):
+                w_in = _facet_by_in_edge(store, attr, reverse, wkey)
+                blocks = None if w_in is None else ell_weights(
+                    g, store.rel(attr, not reverse).indptr, w_in,
+                    w_in.dtype)
+            if blocks is None:
+                cache[key] = None
+            else:
+                with tracing.span("batch.upload_ell", phase=True,
+                                  pred=attr, reverse=reverse,
+                                  part="weights") as sp:
+                    dev = jax.block_until_ready(jax.device_put(blocks))
+                    sp.attrs["bytes"] = memgov.estimate_nbytes(dev)
+                cache[key] = _RelaxWeights(w_in, dev, int(w_in.max()))
+                METRICS.set_gauge("ell_weight_bytes",
+                                  float(sp.attrs["bytes"]), pred=attr,
+                                  reverse=str(reverse), facet=wkey)
+                METRICS.set_gauge("ell_weight_width",
+                                  float(w_in.dtype.itemsize), pred=attr,
+                                  reverse=str(reverse), facet=wkey)
+        out = cache[key]
+    memgov.GOVERNOR.maybe_evict("device")
+    return out
+
+
+def _relax_for(store, plan: _ShortestPlan, wts: _RelaxWeights, lanes: int,
+               rounds_cap: int, dist_type: tuple):
+    """(compiled weighted lane program, its launch key) per (snapshot,
+    pred, dir, facet key, lanes, round cap); `dist_type` is relax_dtype's
+    (dtype, INF) for the weights and the cap."""
+    from dgraph_tpu.ops.bfs import make_ell_relax
+
+    attr, reverse = plan.attr, plan.reverse
+    host = _cache_host(store, attr, reverse)
+    dtype, inf = dist_type
+    key = ("relax", attr, reverse, plan.weight_key, lanes, rounds_cap)
+    lkey = key[1:] + (str(dtype),)
+    fns = getattr(host, "_ell_fns", None)
+    if fns is not None and key in fns:  # hot path: no lock
+        return fns[key], lkey
+    g, dev = _dev_for(store, attr, reverse)
+    with _cache_lock:
+        fns = getattr(host, "_ell_fns", None)
+        if fns is None:
+            fns = host._ell_fns = {}
+            _governed_host_cache(host, "_ell_fns", "batch.kernel",
+                                 "host", lambda v: _KERNEL_NBYTES_EST)
+        if key not in fns:
+            fns[key] = make_ell_relax(dev, wts.dev, g.n, lanes, dtype, inf,
+                                      rounds_cap)
+        return fns[key], lkey
+
+
 # -- per-snapshot kernel caches ----------------------------------------------
 
 # one lock guards cache init/population on every snapshot: concurrent
@@ -1059,7 +1483,7 @@ def _drop_dependent_fns(host, dkey) -> None:
     fns = getattr(host, "_ell_fns", None)
     if not fns:
         return
-    attr, reverse = dkey
+    attr, reverse = dkey[:2]        # a facet's weights: (.., facet key)
     for fkey in [k for k in fns if k[1] == attr and k[2] == reverse]:
         del fns[fkey]
 
@@ -1308,6 +1732,10 @@ def carry_kernel_caches(old_store, new_store, touched) -> int:
             dst_cache[key] = gval
             if key in src_devs:
                 dst_devs[key] = src_devs[key]
+            # the slot-aligned weights go where the ELL goes
+            for wkey, wts in src_devs.items():
+                if len(wkey) == 3 and wkey[:2] == key:
+                    dst_devs.setdefault(wkey, wts)
             for fkey, fn in src_fns.items():
                 if fkey[1] == attr and fkey[2] == key[1]:
                     dst_fns.setdefault(fkey, fn)

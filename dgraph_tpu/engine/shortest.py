@@ -21,7 +21,12 @@ found levels backward over the reverse CSR. That reconstruction pins
 THIS module's semantics bit-for-bit (parent-list order = ascending
 frontier rank, level-order path enumeration, simple-path exclusion,
 min/maxweight counting) — tests/test_batch.py asserts the two paths
-byte-identical, so behavior changes here must update both.
+byte-identical, so behavior changes here must update both. ONE cheapest
+path over one integer facet rides a lane program of its own
+(engine/batch.py _run_weighted_batch: distances relaxed on the device,
+the walk-back over tight edges pinned to `_weighted_one`'s, asserted by
+tests/test_weighted_lanes.py); every weighted block still walked here is
+counted, `weighted_host_fallbacks_total{reason=}`.
 """
 
 from __future__ import annotations
@@ -50,22 +55,23 @@ class PathData:
     weights: list[float] = field(default_factory=list)
 
 
-def shortest_path(ex, sg) -> PathData:
+def shortest_path(ex, sg, lane_refusal: str | None = None) -> PathData:
     """BFS from sg.shortest.from_uid to to_uid over the block's edge preds.
     When an edge block names a facet (`friend @facets(weight)`), edges are
     relaxed by that facet's value instead of uniform cost — reference:
-    query/shortest.go facet-weight relaxation."""
+    query/shortest.go facet-weight relaxation. `lane_refusal`: why the
+    weighted lane route hands this block over, where it does."""
     from dgraph_tpu.utils import tracing
     a = sg.shortest
     with tracing.span("engine.shortest", numpaths=a.numpaths,
                       depth=a.depth) as sp:
-        data = _shortest_path(ex, sg)
+        data = _shortest_path(ex, sg, lane_refusal)
         sp.attrs["paths"] = len(data.paths)
         sp.attrs["nodes"] = int(len(data.nodes))
         return data
 
 
-def _shortest_path(ex, sg) -> PathData:
+def _shortest_path(ex, sg, lane_refusal: str | None = None) -> PathData:
     args = sg.shortest
     store = ex.store
     src = store.rank_of(np.array([args.from_uid], np.int64))[0]
@@ -74,6 +80,15 @@ def _shortest_path(ex, sg) -> PathData:
     if src < 0 or dst < 0:
         return data
     if any(c.facet_keys for c in data.edge_sgs):
+        # every weighted block walked here is one the lane route
+        # (engine/batch.py _run_weighted_batch) did not answer: counted
+        # with its reason, "unbatched" for a block the family would take
+        # that came alone or in a group too small to launch
+        from dgraph_tpu.engine.batch import weighted_refusal
+        from dgraph_tpu.utils.metrics import METRICS
+        METRICS.inc("weighted_host_fallbacks_total",
+                    reason=lane_refusal or weighted_refusal(store, sg)
+                    or "unbatched")
         return _weighted_shortest(ex, sg, data, int(src), int(dst))
     max_depth = args.depth or MAX_PATH_DEPTH
     k = max(1, args.numpaths)

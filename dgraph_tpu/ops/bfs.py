@@ -36,6 +36,7 @@ __all__ = ["EllGraph", "build_ell", "ell_recurse",
            "DeviceEll", "device_ell", "prepare_parts", "make_ell_recurse",
            "out_csr", "push_caps",
            "make_ell_step", "make_ell_tree",
+           "ell_weights", "relax_dtype", "make_ell_relax", "gather_pairs",
            "pack_seed_masks", "unpack_masks"]
 
 
@@ -1091,6 +1092,198 @@ def make_ell_tree(stages, n: int, W: int, word_bits: int = 32):
         return tuple(results)
 
     return functools.partial(tree, held)
+
+
+# ---------------------------------------------------------------------------
+# The weighted lane family: a VALUE a lane and node, not a bit.
+#
+# dist[n + 1, lanes] holds, for every lane, the cheapest cost found so far
+# from the lane's source to every node (INF elsewhere; row n the sentinel,
+# always INF). One round is the min-plus form of _ell_hop:
+#     dist'[v] = min(dist[v], min over stored in-slots (u, w) of dist[u] + w)
+# over the same lists, with one weight a stored slot beside its index
+# (ell_weights). A gathered row is `lanes` distances, 128 bytes of int16
+# for 64 lanes where a mask row is 8: the first program whose pace the
+# gather's WIDTH may set. Integer arithmetic, so every cost is exact.
+
+
+def relax_dtype(max_weight: int, rounds: int):
+    """(dtype, INF) of the narrowest signed distance type in which
+    `rounds` rounds over weights up to `max_weight` cannot overflow: a
+    finite distance is at most rounds * max_weight, under INF, and INF +
+    max_weight is still inside the type (a round adds before it clamps).
+    None where even int32 does not hold it."""
+    import numpy as np
+    for dt in (np.int16, np.int32):
+        inf = int(np.iinfo(dt).max) - max(int(max_weight), 1)
+        if rounds * max(int(max_weight), 1) < inf:
+            return np.dtype(dt), inf
+    return None
+
+
+def ell_weights(g: EllGraph, indptr, w_in, dtype):
+    """The weights of `g`'s stored slots, block for block: (parts, tiles),
+    parts[i] an array of the shape of g.parts[i]'s block (None for the
+    zero class) and tiles one of g.tiles' shape (None without a tail),
+    slot [r, k] the weight of the in-edge whose source that slot of `g`
+    names. `indptr` and `w_in` are the relation's IN-edges by target
+    (old ranks), sources ascending within a row: build_ell's own order
+    (its stable transpose), so the k-th in-edge of a row is its k-th
+    slot. Padding slots weigh 0 (their source is the sentinel, at INF).
+    For a `g` without a hub block: one takes edges out of the lists."""
+    import numpy as np
+    assert g.dense is None, "the hub block has no min-plus product"
+    indptr = np.asarray(indptr, np.int64)
+    off, parts = 0, []
+    for kind, e, rows in g.parts:
+        nodes = g.perm_order[off:off + rows]
+        off += rows
+        parts.append(None if kind == "zero" else w_in[
+            indptr[nodes][:, None] + np.arange(e.shape[1])].astype(dtype))
+    tiles = None
+    if g.tiles is not None:
+        nodes = g.perm_order[off:]
+        deg = indptr[nodes + 1] - indptr[nodes]
+        seg_tile = g.tiles.shape[1]
+        tile_start = np.cumsum(-(-deg // seg_tile)) - -(-deg // seg_tile)
+        within = np.arange(int(deg.sum())) - np.repeat(
+            np.cumsum(deg) - deg, deg)
+        tiles = np.zeros(g.tiles.shape, dtype)
+        tiles.reshape(-1)[np.repeat(tile_start * seg_tile, deg) + within] \
+            = w_in[np.repeat(indptr[nodes], deg) + within]
+    return parts, tiles
+
+
+# bytes of gathered distance rows a turn of _min_plus may hold: XLA:TPU
+# materialises every gathered row before it combines them (68 M slots of
+# 128 bytes would be 8.75 GB a round), so a block is relaxed RELAX_CHUNK
+# bytes of rows a turn of a loop; a turn of half a million gathers costs
+# the loop nothing
+RELAX_CHUNK = 64 << 20
+
+
+def _min_plus(dist, e, w, inf, extra: int = 0):
+    """out[i] = min_k dist[e[i, k]] + w[i, k], the min-plus form of
+    _chain_or, as many rows of the block a turn as gather RELAX_CHUNK
+    bytes, each turn written into its place of the result. `w` None adds
+    nothing (the second level combines partials). `extra` rows of INF
+    follow the block's (the sentinel the second level's padding reads).
+    Up to CHAIN_MAX wide a turn is an unrolled chain of K row gathers,
+    844 ms a round over `knows` on the v5e where one [rows, K] gather and
+    a reduce take 926 (PR 46); wider (the widest second-level combines,
+    whose rows are few), the reduce. Not clamped: the caller does, once."""
+    rows, K = e.shape
+    dt = dist.dtype
+
+    def some(e_c, w_c):
+        if K <= CHAIN_MAX:
+            acc = None
+            for k in range(K):
+                got = dist[e_c[:, k]]
+                if w_c is not None:
+                    got = got + w_c[:, k].astype(dt)[:, None]
+                acc = got if acc is None else jnp.minimum(acc, got)
+            return acc
+        got = dist[e_c]
+        if w_c is not None:
+            got = got + w_c.astype(dt)[:, :, None]
+        return got.min(axis=1, initial=inf)
+
+    def rows_of(x, at, size):
+        return None if x is None else lax.dynamic_slice_in_dim(x, at, size)
+
+    ch = max(1, RELAX_CHUNK // (K * dist.shape[1] * dt.itemsize))
+    whole = rows // ch if rows >= 2 * ch else 0
+    out = jnp.full((rows + extra, dist.shape[1]), inf, dt)
+    if whole:
+        out = lax.fori_loop(
+            0, whole,
+            lambda i, out: lax.dynamic_update_slice_in_dim(
+                out, some(rows_of(e, i * ch, ch), rows_of(w, i * ch, ch)),
+                i * ch, 0),
+            out)
+    at = whole * ch
+    if at < rows:
+        out = lax.dynamic_update_slice_in_dim(
+            out, some(rows_of(e, at, rows - at), rows_of(w, at, rows - at)),
+            at, 0)
+    return out
+
+
+def make_ell_relax(dev: DeviceEll, weights, n: int, lanes: int, dtype,
+                   inf: int, max_rounds: int):
+    """Compile the weighted lane program over a DeviceEll and its
+    slot-aligned weights (`weights`: ell_weights' (parts, tiles), on the
+    device): fn(src_rows, dst_rows, active) → (dist, rounds, open, cost).
+
+    `src_rows`/`dst_rows` int32[lanes] are the lanes' endpoints in the
+    permuted space and `active` bool[lanes] marks the lanes that ride
+    (the others stay at INF everywhere). The distances start at 0 on each
+    active lane's source, INF elsewhere, and the program runs pulled
+    rounds under ONE while_loop: a round reads every stored slot's index
+    and weight once and gathers one distance row a slot. A lane stays
+    open while a round improved some node to a cost no more than its
+    target's: label-correcting, and exact for non-negative weights, since
+    once no such node improves every node that cheap, the target among
+    them, holds its true cost (engine/shortest.py _weighted_one prunes
+    the same way). The program stops when no lane is open, or at
+    `max_rounds`: `open` bool[lanes] then names the lanes whose answer is
+    not settled, which the caller sends to the host. `rounds` int32 is
+    the rounds run, `cost` [lanes] the targets' distances, and `dist`
+    [n + 1, lanes] stays on the device for the walk-back's gathers.
+    The index blocks and the weights ride as arguments (_as_arguments)."""
+    dtype = jnp.dtype(dtype)
+    prepared = prepare_parts(dev, 1)
+    assert prepared["dense"] is None and all(
+        kind != "pallas" for kind, _e, _r in prepared["parts"])
+    held, rebuild = _as_arguments((prepared, weights))
+    lane_ids = jnp.arange(lanes)
+
+    @jax.jit
+    def relax(arrays, src_rows, dst_rows, active):
+        prepared, (w_parts, w_tiles) = rebuild(arrays)
+
+        def pull(dist):
+            outs = []
+            for (kind, e, rows), w in zip(prepared["parts"], w_parts):
+                outs.append(jnp.full((rows, lanes), inf, dtype)
+                            if kind == "zero"
+                            else _min_plus(dist, e, w, inf))
+            if prepared["tiles"] is not None:
+                # row M: the INF partial that the combines' padding reads
+                partials = _min_plus(dist, prepared["tiles"][1], w_tiles,
+                                     inf, extra=1)
+                for t2 in prepared["lvl2"]:
+                    outs.append(_min_plus(partials, t2, None, inf))
+            outs.append(jnp.full((1, lanes), inf, dtype))    # sentinel
+            return jnp.minimum(jnp.concatenate(outs, axis=0), inf)
+
+        def more(carry):
+            _dist, rounds, open_ = carry
+            return (rounds < max_rounds) & open_.any()
+
+        def round_(carry):
+            dist, rounds, open_ = carry
+            new = jnp.minimum(dist, pull(dist))
+            cost = new[dst_rows, lane_ids]
+            better = (new < dist) & (new <= cost[None, :])
+            return new, rounds + 1, better.any(axis=0) & active
+
+        dist0 = jnp.full((n + 1, lanes), inf, dtype).at[
+            jnp.where(active, src_rows, n), lane_ids].set(
+            jnp.where(active, 0, inf).astype(dtype))
+        dist, rounds, open_ = lax.while_loop(
+            more, round_, (dist0, jnp.int32(0), active))
+        return dist, rounds, open_, dist[dst_rows, lane_ids]
+
+    return functools.partial(relax, held)
+
+
+@jax.jit
+def gather_pairs(dist, rows, lanes):
+    """dist[rows[i], lanes[i]]: the distances the weighted walk-back reads
+    (engine/batch.py), as many a call as the caller pads to."""
+    return dist[rows, lanes]
 
 
 def ell_recurse(g: EllGraph, mask0, depth: int, count_edges: bool = True):

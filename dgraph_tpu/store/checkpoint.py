@@ -98,13 +98,31 @@ def save_predicate(dirname: str, pred: str, pd) -> dict:
             vals = np.array([str(v) for v in vals], dtype=np.str_)
         fname = f"{slug}.val.{lslug}.vals.npy"
         crcs[fname] = vault.save_np(os.path.join(dirname, fname), vals)
-    if pd.efacets or pd.vfacets:
+    # a TYPED edge-facet column (FacetCol.vals of a numeric dtype: a bulk
+    # loader's, one value an edge of a 68 M-edge relation) persists as
+    # array segments like the CSR it is aligned to, never as JSON; its
+    # positions are left out where every edge has the facet, in order
+    typed = sorted(k for k, col in pd.efacets.items()
+                   if col.vals.dtype != object)
+    for i, k in enumerate(typed):
+        col = pd.efacets[k]
+        dense = pd.fwd is not None and len(col.pos) == pd.fwd.nnz
+        for part, arr in (("vals", col.vals),
+                          ("pos", None if dense else col.pos)):
+            if arr is not None:
+                fname = f"{slug}.efacet.{i}.{part}.npy"
+                crcs[fname] = vault.save_np(
+                    os.path.join(dirname, fname), arr)
+        meta.setdefault("efacets_typed", []).append(
+            {"key": k, "dense": dense})
+    if len(typed) < len(pd.efacets) or pd.vfacets:
         # facets ride in a JSON sidecar (they are sparse; the reference
         # persists them inside each posting — same durability contract)
         fdoc = {
             "efacets": {k: {"pos": col.pos.tolist(),
                             "vals": [enc_scalar(v) for v in col.vals]}
-                        for k, col in pd.efacets.items()},
+                        for k, col in pd.efacets.items()
+                        if k not in typed},
             "vfacets": {k: {str(r): enc_scalar(v)
                             for r, v in m.items()}
                         for k, m in pd.vfacets.items()},
@@ -299,6 +317,12 @@ def load_predicate(dirname: str, pred: str, meta: dict,
             vals[:] = [rows[i] for i in range(len(rows))]
         pd.vals[lang] = ValueColumn(
             subj=_load(f"{slug}.val.{lslug}.subj.npy"),
+            vals=vals)
+    for i, ent in enumerate(meta.get("efacets_typed", ())):
+        vals = _load(f"{slug}.efacet.{i}.vals.npy")
+        pd.efacets[ent["key"]] = FacetCol(
+            pos=(np.arange(len(vals), dtype=np.int64) if ent["dense"]
+                 else _load(f"{slug}.efacet.{i}.pos.npy")),
             vals=vals)
     if meta.get("facets"):
         fname = f"{slug}.facets.json"

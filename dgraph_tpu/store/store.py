@@ -120,7 +120,10 @@ class FacetCol:
     hop kernel's `edge_pos` output gathers from (ops/hop.py)."""
 
     pos: np.ndarray   # sorted int64 positions into fwd.indices
-    vals: np.ndarray  # object array of facet values
+    # facet values: an object array (what a mutation folds to), or one
+    # typed column (int/float/bool dtype) where a bulk loader hands the
+    # values over as an array: 68 M Python objects are never made
+    vals: np.ndarray
 
     def _locate(self, positions: np.ndarray):
         """(clamped indexes, hit mask) for edge positions — the one
@@ -133,8 +136,12 @@ class FacetCol:
     def get(self, positions: np.ndarray) -> list:
         """Facet values at edge positions; None where absent."""
         idx_c, hit = self._locate(positions)
-        return [self.vals[i] if h else None
-                for i, h in zip(idx_c.tolist(), hit.tolist())]
+        if not len(self.pos):
+            return [None] * len(hit)
+        vals = self.vals[idx_c]
+        if vals.dtype != object:       # typed column: Python scalars out
+            vals = vals.tolist()
+        return [v if h else None for v, h in zip(vals, hit.tolist())]
 
     def numeric_at(self, positions: np.ndarray):
         """(values float64, hit mask) at edge positions — the vectorized
@@ -144,9 +151,10 @@ class FacetCol:
         parse here: the per-value path treats them as weight 1, and the
         two paths must agree). The float cast computes once."""
         if not hasattr(self, "_num"):
-            if all(isinstance(v, (bool, int, float, np.integer,
-                                  np.floating, np.bool_))
-                   for v in self.vals):
+            if self.vals.dtype.kind in "biuf" or all(
+                    isinstance(v, (bool, int, float, np.integer,
+                                   np.floating, np.bool_))
+                    for v in self.vals):
                 self._num = self.vals.astype(np.float64)
             else:
                 self._num = None
@@ -154,6 +162,36 @@ class FacetCol:
             return None
         idx_c, hit = self._locate(positions)
         return self._num[idx_c], hit
+
+    def int_values(self) -> np.ndarray | None:
+        """The column as integers, aligned with `pos`, where EVERY value
+        is an integer (a bool is not: it is a flag, and the host's
+        relaxation reads it as a float); else None. A typed integer
+        column is handed over as it is, whatever its width; an object
+        column is cast to int64, once. What the weighted lane program's
+        slot-aligned weights are built from (engine/batch.py)."""
+        if not hasattr(self, "_int"):
+            vals, self._int = self.vals, None
+            if vals.dtype.kind in "iu":
+                self._int = vals
+            elif vals.dtype == object and all(
+                    isinstance(v, (int, np.integer))
+                    and not isinstance(v, (bool, np.bool_)) for v in vals):
+                try:
+                    self._int = np.array(vals.tolist(), np.int64)
+                except OverflowError:
+                    pass
+        return self._int
+
+    def int_range(self) -> tuple | None:
+        """(least, largest) of int_values(); None where it has none, or
+        the column is empty. Computed once: a planner asks a query."""
+        if not hasattr(self, "_int_range"):
+            vals = self.int_values()
+            self._int_range = (
+                (int(vals.min()), int(vals.max()))
+                if vals is not None and len(vals) else None)
+        return self._int_range
 
 
 @dataclass
